@@ -37,45 +37,27 @@ import jax
 # src/repr/src/diff.rs). Enable x64 before any array is created.
 jax.config.update("jax_enable_x64", True)
 
-# Persistent compilation cache: TPU compile time for lax.sort grows
-# superlinearly in array size (measured: 2.5s @ 4k rows, 27s @ 16k on
-# v5e), so steps at large capacity tiers are expensive to compile but
-# sub-millisecond to run. Caching compiled executables across processes
-# makes dataflow installation (the CREATE MATERIALIZED VIEW analog)
-# pay that cost once per (plan, capacity signature) per machine.
+# Persistent compilation cache: compile time for lax.sort grows
+# superlinearly in array size, so steps at large capacity tiers are
+# expensive to compile but cheap to run. Caching compiled executables
+# across processes makes dataflow installation (the CREATE MATERIALIZED
+# VIEW analog) pay that cost once per (plan, capacity signature).
 #
-# The cache directory is keyed by a HOST FINGERPRINT (CPU feature set):
-# XLA:CPU emits ahead-of-time machine code, and loading an executable
-# compiled on a machine with different vector extensions is undefined —
-# observed as both "could lead to SIGILL" loader warnings and, worse,
-# silently wrong kernel results when a foreign-host cache was reused.
-
-
-def _host_fingerprint() -> str:
-    import hashlib
-    import platform
-
-    parts = [platform.machine()]
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.startswith("flags"):
-                    parts.append(" ".join(sorted(line.split()[2:])))
-                    break
-    except OSError:
-        pass
-    return hashlib.sha1("|".join(parts).encode()).hexdigest()[:12]
-
-
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.environ.get(
-        "MATERIALIZE_TPU_COMPILE_CACHE",
-        os.path.expanduser(
-            f"~/.cache/materialize_tpu_xla/{_host_fingerprint()}"
+# Placement: where JAX_COMPILATION_CACHE_DIR is set, JAX itself reads it
+# and this package sets nothing. Otherwise the cache is one fixed
+# directory inside the checkout (the path is part of nothing's identity,
+# but a cache that moves never hits): environmentd, its replicas and the
+# tests all share it. The installed JAX already keys CPU entries by the
+# host's CPU feature list (the serialized CPU topology is hashed into
+# the cache key), so no per-host subdirectory is needed.
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update(
+        "jax_compilation_cache_dir",
+        os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache",
         ),
-    ),
-)
+    )
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 __version__ = "0.1.0"

@@ -113,8 +113,7 @@ class ProgramBank:
             with open(path, "rb") as f:
                 entry = pickle.load(f)
         except FileNotFoundError:
-            with self._lock:
-                self.stats["misses"] += 1
+            self._count("misses")
             return None
         except Exception:
             # Truncated/corrupt pickle: drop the entry, fall back.
@@ -130,9 +129,7 @@ class ProgramBank:
                 # Version/platform skew: not corruption — another
                 # deployment (or a future upgrade rollback) may still
                 # want it. Skip, don't unlink.
-                with self._lock:
-                    self.stats["misses"] += 1
-                    self.stats["errors"] += 1
+                self._count("misses", "errors")
                 return None
         try:
             from jax.experimental import serialize_executable
@@ -143,8 +140,8 @@ class ProgramBank:
         except Exception:
             self._damaged(path)
             return None
+        self._count("hits")
         with self._lock:
-            self.stats["hits"] += 1
             self.stats["seconds_recovered"] += float(
                 meta.get("seconds", 0.0)
             )
@@ -198,17 +195,37 @@ class ProgramBank:
                 f.write(blob)
             os.replace(tmp, path)
         except Exception:
-            with self._lock:
-                self.stats["errors"] += 1
+            self._count("errors")
             return False
-        with self._lock:
-            self.stats["stores"] += 1
+        self._count("stores")
         return True
 
-    def _damaged(self, path: str) -> None:
+    def _count(self, *keys: str) -> None:
+        """Bump counters in ``stats`` AND in this process's /metrics
+        registry (``mz_program_bank_<key>_total``): a replica's
+        families ship to the controller, so its bank's behaviour —
+        above all ``errors`` — is visible from the server
+        (mz_metrics), not only inside the replica process."""
+        from ..utils.metrics import REGISTRY
+
         with self._lock:
-            self.stats["misses"] += 1
-            self.stats["errors"] += 1
+            for k in keys:
+                self.stats[k] += 1
+        for k in keys:
+            REGISTRY.get_or_create(
+                "counter", f"mz_program_bank_{k}_total",
+                f"program bank {k} in this process",
+            ).inc()
+
+    def note_error(self) -> None:
+        """A banked program the caller had to give up on (the AOT
+        compile failed, or the runtime refused the loaded executable)
+        and route through the plain jit instead: counted, so the
+        fallback is seen."""
+        self._count("errors")
+
+    def _damaged(self, path: str) -> None:
+        self._count("misses", "errors")
         try:
             os.unlink(path)
         except OSError:

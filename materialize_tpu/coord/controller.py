@@ -492,6 +492,9 @@ class ReplicaClient:
         )
         self.sessions = 0  # established sessions (reconnects = n-1)
         self.fenced = 0  # HelloRejects observed (newer epoch exists)
+        # What the replica said it computes on at its last HelloOk
+        # ({platform, kind, count}); None until the first session.
+        self.device: dict | None = None
         self._thread = threading.Thread(target=self._run, daemon=True)
         self._thread.start()
 
@@ -503,6 +506,7 @@ class ReplicaClient:
                 "reconnects": max(self.sessions - 1, 0),
                 "fenced": self.fenced,
                 "connected": self.connected.is_set(),
+                "device": self.device,
             }
 
     def send(self, cmd: dict) -> None:
@@ -571,6 +575,7 @@ class ReplicaClient:
                 _lockcheck.shared_write("controller.replica_stats")
                 self.sessions += 1
                 reconnect = self.sessions > 1
+                self.device = resp.get("device")
             if reconnect:
                 retry_mod.reconnects_total().inc()
             # Rehydration: replay the compacted history. The replica
@@ -1088,7 +1093,8 @@ class ComputeController:
     def replica_states(self) -> list[dict]:
         """The mz_cluster_replicas rows' source: per replica its
         connection state, lifecycle state (active|draining), and how
-        many reads routed to it."""
+        many reads routed to it, and the device it reported at
+        HelloOk (None before its first session)."""
         with self._lock:
             _lockcheck.shared_read("controller.replicas")
             items = sorted(self.replicas.items())
@@ -1100,6 +1106,7 @@ class ComputeController:
                 "connected": rc.connected.is_set(),
                 "state": "draining" if n in draining else "active",
                 "routed": routed.get(n, 0),
+                "device": rc.stats()["device"],
             }
             for n, rc in items
         ]

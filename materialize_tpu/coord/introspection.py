@@ -205,6 +205,13 @@ INTROSPECTION_SCHEMAS: dict[str, Schema] = {
             # Reads routed to this replica (the per-replica routing
             # distribution bench.py --serve reports).
             Column("routed", I),
+            # The device the replica reported at HelloOk, as its own
+            # JAX sees it (empty/0 before the first session): which
+            # platform serves this replica's answers is visible, so a
+            # replica on the CPU is never mistaken for one on the chip.
+            Column("platform", S),
+            Column("device_kind", S),
+            Column("devices", I),
         ]
     ),
     # Every autoscaler decision with its triggering evidence
@@ -682,6 +689,9 @@ def snapshot(coord, name: str) -> list[tuple]:
                 int(s["connected"]),
                 _enc(s["state"]),
                 int(s["routed"]),
+                _enc((s["device"] or {}).get("platform", "")),
+                _enc((s["device"] or {}).get("kind", "")),
+                int((s["device"] or {}).get("count", 0)),
             )
             for s in coord.controller.replica_states()
         ]

@@ -77,6 +77,19 @@ if _so is not None:
 
 NATIVE = _lib is not None
 
+# Which path is live, where an operator can see it: the gauge ships
+# with every process's /metrics families (mz_metrics shows the
+# coordinator's and, labeled replica=<name>, each replica's), so a
+# deployment running the pure-Python fallbacks is not mistaken for a
+# slow one.
+from ..utils.metrics import REGISTRY as _REGISTRY  # noqa: E402
+
+_REGISTRY.get_or_create(
+    "gauge", "mz_native_kernels",
+    "1 when the C++ host kernels (libmtnative) are loaded, 0 when "
+    "this process runs their pure-Python fallbacks",
+).set(1.0 if NATIVE else 0.0)
+
 
 def crc32c(data: bytes) -> int:
     if NATIVE:

@@ -18,9 +18,9 @@ scatters; 8.3s at 2M rows). This form costs ~0.15s at the same shape.
 Round-6 fusion (`merge_sorted_cached`): lanes travel ROW-STACKED
 (``[cap, L]`` uint64 — PERF_NOTES design rule "move rows, not
 columns"), the binary search gathers one lane-row per iteration
-instead of one gather per lane (ops/search.lex_searchsorted_2d or the
-Pallas kernel, ops/merge_pallas.py, behind the ``fused_merge``
-dyncfg), and the merged run's lanes come out of the SAME src gather
+instead of one gather per lane (ops/search.lex_searchsorted_2d,
+behind the ``fused_merge`` dyncfg), and the merged run's lanes come
+out of the SAME src gather
 that moves the rows — so spine folds maintain their cached run lanes
 without ever re-hashing columns (arrangement/spine.py lane cache).
 """
@@ -59,11 +59,9 @@ def merge_insertion_points(
     — the sorted-merge inner loop, implementation selected by the
     ``fused_merge`` dyncfg (all choices agree bit-for-bit):
 
-      'pallas'  — the VMEM-resident Pallas kernel (interpret mode
-                  off-TPU), when the shapes fit its budget;
-      'lax'     — fused binary search, one row-gather per iteration;
-      'auto'    — pallas on TPU when it fits, lax otherwise;
-      'unfused' — the legacy per-lane gather search (baseline).
+      'lax' / 'auto' — fused binary search, one row-gather per
+                       iteration, on every backend;
+      'unfused'      — the legacy per-lane gather search (baseline).
     """
     mode = FUSED_MERGE(COMPUTE_CONFIGS)
     if mode == "unfused":
@@ -73,15 +71,6 @@ def merge_insertion_points(
             unstack_lanes(a_lanes_2d), a_count,
             unstack_lanes(b_lanes_2d), side="right",
         )
-    if mode in ("pallas", "auto"):
-        from .merge_pallas import pallas_available, pallas_search_right
-
-        if pallas_available(
-            a_lanes_2d.shape, b_lanes_2d.shape, force=(mode == "pallas")
-        ):
-            return pallas_search_right(
-                a_lanes_2d, a_count, b_lanes_2d, b_count
-            )
     return lex_searchsorted_2d(
         a_lanes_2d, a_count, b_lanes_2d, side="right"
     )

@@ -1,62 +1,16 @@
-"""JAX version compatibility for the SPMD layer.
+"""SPMD-layer helpers tied to the installed JAX.
 
-``shard_map`` moved over JAX releases: top-level ``jax.shard_map``
-(with the ``check_vma`` kwarg) is the current API, while older builds
-ship it as ``jax.experimental.shard_map.shard_map`` (kwarg
-``check_rep``) — and some container builds carry neither. The render
-layer and the sharded tests resolve it HERE once, so a missing API
-degrades to a clean skip/raise instead of an AttributeError mid-build
-(ISSUE 5: the 7 container-only failures were exactly that).
+``shard_map`` is ``jax.shard_map`` (kwarg ``check_vma``): the installed
+JAX is the only one supported, so it is resolved directly.
+``force_host_devices`` gives the CPU platform N virtual devices so the
+multi-chip SPMD paths run without TPU hardware.
 """
 
 from __future__ import annotations
 
 import jax
 
-
-def _resolve():
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    try:
-        from jax.experimental.shard_map import shard_map as esm
-    except ImportError:
-        return None
-
-    def shim(f, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-        # The experimental API spells the replication check `check_rep`.
-        if check_vma is not None and "check_rep" not in kwargs:
-            kwargs["check_rep"] = check_vma
-        return esm(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            **kwargs,
-        )
-
-    return shim
-
-
-#: The resolved shard_map callable, or None on JAX builds without one.
-shard_map = _resolve()
-
-HAS_SHARD_MAP = shard_map is not None
-
-#: Why sharded paths are unavailable (skip reason for tests).
-MISSING_REASON = (
-    "this JAX build has neither jax.shard_map nor "
-    "jax.experimental.shard_map"
-)
-
-
-def require_shard_map():
-    """Raise a clear error where a sharded dataflow is about to build
-    on a JAX without shard_map (callers that can skip should check
-    HAS_SHARD_MAP instead)."""
-    if shard_map is None:
-        raise NotImplementedError(MISSING_REASON)
-    return shard_map
+shard_map = jax.shard_map
 
 
 def force_host_devices(env=None, n: int = 8) -> None:

@@ -48,6 +48,7 @@ from __future__ import annotations
 
 import hashlib
 import os
+import threading
 import time as _time
 from collections import deque
 from dataclasses import dataclass, field
@@ -63,6 +64,7 @@ class CompileRecord:
     tier: str  # tier vector: capacity/shape signature of this compile
     seconds: float
     # "miss" (first sight, compiled) | "hit" (recompiled a known key)
+    # | "xla_hit" (the AOT compile was a load from the XLA persistent cache)
     # | "bank_hit" (served from the program bank — no XLA compile;
     # seconds is the deserialize wall, attrs["recovered_seconds"] the
     # compile wall it skipped)
@@ -305,14 +307,17 @@ def expr_fingerprint(obj) -> str:
     return hashlib.blake2b(payload, digest_size=8).hexdigest()
 
 
-def tier_vector(args: tuple) -> str:
+def tier_vector(args: tuple, static: str = "") -> str:
     """Tier vector of one call signature: a digest of every array
     leaf's (shape, dtype) plus the total operand bytes — the program
-    bank key's second half. Computed ONLY when a compile actually
-    happened (never on the steady-state dispatch path)."""
+    bank key's second half. ``static`` carries the capacity tiers a
+    program bakes in at TRACE time (they show in no argument's shape):
+    without it a program regrown after an overflow would share its
+    key — and be served from the bank as — its smaller twin."""
     import jax
 
     h = hashlib.blake2b(digest_size=6)
+    h.update(static.encode())
     total = 0
     for leaf in jax.tree_util.tree_leaves(args):
         shape = getattr(leaf, "shape", None)
@@ -328,6 +333,27 @@ def tier_vector(args: tuple) -> str:
     return f"{h.hexdigest()}:{total}"
 
 
+# Executables the XLA persistent compilation cache served to THIS thread
+# (jax.monitoring fires the hit event synchronously in the compiling
+# thread). `_resolve_route` reads the counter around its AOT compile.
+_xla_cache_hits = threading.local()
+_xla_cache_listener_installed = False
+
+
+def _watch_xla_cache_hits() -> None:
+    global _xla_cache_listener_installed
+    if _xla_cache_listener_installed:
+        return
+    _xla_cache_listener_installed = True
+    import jax.monitoring
+
+    def on_event(name, **_kw):
+        if name == "/jax/compilation_cache/cache_hits":
+            _xla_cache_hits.n = getattr(_xla_cache_hits, "n", 0) + 1
+
+    jax.monitoring.register_event_listener(on_event)
+
+
 class LedgeredJit:
     """A ``jax.jit`` wrapper that records actual compiles. With no
     bank configured (the default) the hot path costs two C attribute
@@ -338,15 +364,18 @@ class LedgeredJit:
     and first sight of a tier goes bank-lookup-then-AOT-compile."""
 
     __slots__ = (
-        "fn", "kind", "name", "fingerprint", "ledger", "_routes",
+        "fn", "kind", "name", "fingerprint", "ledger", "static",
+        "_routes",
     )
 
-    def __init__(self, fn, kind, name, fingerprint, ledger=None):
+    def __init__(self, fn, kind, name, fingerprint, ledger=None,
+                 static: str = ""):
         self.fn = fn
         self.kind = kind
         self.name = name
         self.fingerprint = fingerprint
         self.ledger = ledger if ledger is not None else LEDGER
+        self.static = static
         self._routes = {}
 
     def __call__(self, *args, **kwargs):
@@ -370,14 +399,14 @@ class LedgeredJit:
                 self.kind,
                 self.name,
                 self.fingerprint,
-                tier_vector(args),
+                tier_vector(args, self.static),
                 _time.perf_counter() - t0,
             )
         return out
 
     # -- program-bank dispatch (ISSUE 16) ---------------------------------
     def _banked_call(self, b, args, kwargs):
-        tier = tier_vector(args)
+        tier = tier_vector(args, self.static)
         route = self._routes.get(tier)
         if route is None:
             route = self._resolve_route(b, tier, args, kwargs)
@@ -391,7 +420,9 @@ class LedgeredJit:
         except Exception:
             # A resolved executable the runtime won't accept (layout
             # or structure drift) must degrade to a recompile, never
-            # to an error or a wrong result.
+            # to an error or a wrong result. Counted: the fallback
+            # must be visible (mz_program_bank_errors_total).
+            b.note_error()
             self._routes[tier] = False
             return self._plain_call(args, kwargs)
 
@@ -412,19 +443,34 @@ class LedgeredJit:
         # hand for both dispatch and the write-back (calling the jit
         # would compile internally and keep the Compiled out of
         # reach).
+        _watch_xla_cache_hits()
+        hits0 = getattr(_xla_cache_hits, "n", 0)
         try:
             compiled = self.fn.lower(*args, **kwargs).compile()
         except Exception:
+            # Unbankable (or uncompilable: the plain call that follows
+            # raises the compiler's own error). Counted either way.
+            b.note_error()
             return False
         secs = _time.perf_counter() - t0
+        xla_hit = getattr(_xla_cache_hits, "n", 0) != hits0
         self.ledger.record(
             self.kind, self.name, self.fingerprint, tier, secs,
+            # A load from the XLA persistent cache is not a cold
+            # compile: the ledger says so ("xla_hit").
+            cache="xla_hit" if xla_hit else None,
             bank="miss",
         )
-        b.store(
-            self.kind, self.fingerprint, tier, compiled,
-            seconds=secs, name=self.name,
-        )
+        if not xla_hit:
+            b.store(
+                self.kind, self.fingerprint, tier, compiled,
+                seconds=secs, name=self.name,
+            )
+        # else: the "compile" was a load from the XLA persistent cache.
+        # Such an executable is NOT exported: serializing a rehydrated
+        # executable damages it on the installed XLA:CPU (its kernels
+        # go missing: "Function ... not found" at the next dispatch),
+        # and the XLA cache already holds it for the next process.
         return compiled
 
     def lower(self, *args, **kwargs):
@@ -435,6 +481,7 @@ class LedgeredJit:
 
 
 def ledger_jit(fn, kind: str, name: str, fingerprint: str,
-               ledger=None) -> LedgeredJit:
-    """Wrap an already-jitted callable so its compiles hit the ledger."""
-    return LedgeredJit(fn, kind, name, fingerprint, ledger)
+               ledger=None, static: str = "") -> LedgeredJit:
+    """Wrap an already-jitted callable so its compiles hit the ledger.
+    ``static``: trace-time capacity tiers (see ``tier_vector``)."""
+    return LedgeredJit(fn, kind, name, fingerprint, ledger, static)

@@ -34,7 +34,10 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# Both modes are host work: pin this process AND the gate children it
+# starts to the CPU, whatever was inherited, so a run on a machine with
+# a chip never has two processes wanting it.
+os.environ["JAX_PLATFORMS"] = "cpu"
 # The sharding gates (--bench, ISSUE 9) render the bench configs SPMD
 # over an 8-virtual-device CPU mesh; force the device count before the
 # jax backend initializes.
@@ -365,7 +368,9 @@ print(json.dumps({
 """
 
 
-def _run_bank_script(bank_dir: str, xla_cache_dir: str):
+def _run_gate_child(script: str, bank_dir: str, xla_cache_dir: str):
+    """Run a program-bank gate script in a fresh interpreter and parse
+    the JSON object on its last stdout line."""
     import json
     import subprocess
     import sys
@@ -374,10 +379,12 @@ def _run_bank_script(bank_dir: str, xla_cache_dir: str):
     # rehydrated from a warm host cache cannot be re-serialized (the
     # payload fails deserialization), so a warm host cache would make
     # the cold run's stores fail verification and the gate flake.
+    # JAX reads the variable itself; the package then sets no cache
+    # directory in code.
     env = dict(os.environ)
-    env["MATERIALIZE_TPU_COMPILE_CACHE"] = xla_cache_dir
+    env["JAX_COMPILATION_CACHE_DIR"] = xla_cache_dir
     out = subprocess.run(
-        [sys.executable, "-c", _BANK_GATE_SCRIPT, bank_dir],
+        [sys.executable, "-c", script, bank_dir],
         capture_output=True, text=True, timeout=600, env=env,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     )
@@ -408,8 +415,8 @@ def run_bank_roundtrip_gate(gate) -> int:
     bank_dir = tempfile.mkdtemp(prefix="bank-gate-")
     xla_cache = tempfile.mkdtemp(prefix="bank-gate-xla-")
     try:
-        cold = _run_bank_script(bank_dir, xla_cache)
-        warm = _run_bank_script(bank_dir, xla_cache)
+        cold = _run_gate_child(_BANK_GATE_SCRIPT, bank_dir, xla_cache)
+        warm = _run_gate_child(_BANK_GATE_SCRIPT, bank_dir, xla_cache)
         if cold["bank"]["stores"] == 0:
             findings.append(LintFinding(
                 "bank-roundtrip", "export",
@@ -451,6 +458,60 @@ def run_bank_roundtrip_gate(gate) -> int:
     return 1 if findings else 0
 
 
+# Two rung-mate DDLs (state_cap 300 vs 400, both snapping to 512)
+# through one bank, in a fresh interpreter (see _run_gate_child).
+_QUANT_GATE_SCRIPT = r"""
+import json, sys
+from collections import defaultdict
+import numpy as np
+from materialize_tpu.compile.bank import configure_bank, get_bank
+from materialize_tpu.expr import relation as mir
+from materialize_tpu.plan.decisions import quantize_cap
+from materialize_tpu.render.dataflow import Dataflow
+from materialize_tpu.repr.batch import Batch
+from materialize_tpu.repr.schema import Column, ColumnType, Schema
+
+configure_bank(sys.argv[1])
+sch = Schema(
+    (Column("k", ColumnType.INT64), Column("v", ColumnType.INT64))
+)
+
+
+def run_once(cap):
+    rng = np.random.default_rng(11)
+    df = Dataflow(mir.Get("src", sch), name=f"quant-{cap}", state_cap=cap)
+    t0 = df.time
+    for i in range(3):
+        n = 16
+        k = rng.integers(0, 32, n).astype(np.int64)
+        v = rng.integers(0, 8, n).astype(np.int64)
+        d = rng.choice(np.asarray([1, 1, -1]), n).astype(np.int64)
+        df.run_steps([{"src": Batch.from_numpy(
+            sch, [k, v], np.uint64(t0 + i), d, capacity=64
+        )}])
+    acc = defaultdict(int)
+    for r in df.peek():
+        acc[tuple(int(c) for c in r[:-2])] += int(r[-1])
+    return sorted([*key, n] for key, n in acc.items() if n != 0)
+
+
+rows_a = run_once(300)
+entries_after_a = get_bank().snapshot()["entries"]
+hits_before = get_bank().stats["hits"]
+rows_b = run_once(400)
+snap = get_bank().snapshot()
+print(json.dumps({
+    "rungs": [quantize_cap(300), quantize_cap(400)],
+    "rows_a": rows_a,
+    "rows_b": rows_b,
+    "entries_after_a": entries_after_a,
+    "entries": snap["entries"],
+    "hits_before": hits_before,
+    "hits": snap["hits"],
+}))
+"""
+
+
 def run_tier_quantization_gate(gate) -> int:
     """Tier-quantization gate (ISSUE 16 satellite): two DDLs whose
     requested capacities differ only WITHIN one pow2 rung (state_cap
@@ -461,88 +522,36 @@ def run_tier_quantization_gate(gate) -> int:
     import shutil
     import tempfile
 
-    import numpy as np
-
     from materialize_tpu.analysis import LintFinding
-    from materialize_tpu.compile.bank import configure_bank, get_bank
-    from materialize_tpu.expr import relation as mir
-    from materialize_tpu.plan.decisions import quantize_cap
-    from materialize_tpu.render.dataflow import Dataflow
-    from materialize_tpu.repr.batch import Batch
-    from materialize_tpu.repr.schema import Column, ColumnType, Schema
 
     findings = []
     bank_dir = tempfile.mkdtemp(prefix="quant-gate-")
-    # Cold, gate-private XLA persistent cache for the in-process
-    # compiles: executables rehydrated from a warm host cache cannot
-    # be re-serialized, so their stores would fail verification and
-    # the key-sharing check would flake (see run_bank_roundtrip_gate).
-    import jax
-
     xla_cache = tempfile.mkdtemp(prefix="quant-gate-xla-")
-    old_cache = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", xla_cache)
     try:
-        if quantize_cap(300) != quantize_cap(400):
+        r = _run_gate_child(_QUANT_GATE_SCRIPT, bank_dir, xla_cache)
+        if r["rungs"][0] != r["rungs"][1]:
             findings.append(LintFinding(
                 "tier-quantization", "menu",
                 f"300 and 400 landed on different rungs "
-                f"({quantize_cap(300)} vs {quantize_cap(400)}): the "
+                f"({r['rungs'][0]} vs {r['rungs'][1]}): the "
                 "pow2 menu no longer coalesces size-only DDL "
                 "differences",
             ))
-        configure_bank(bank_dir)
-        sch = Schema((Column("k", ColumnType.INT64),
-                      Column("v", ColumnType.INT64)))
-
-        def run_once(cap: int):
-            rng = np.random.default_rng(11)
-            df = Dataflow(
-                mir.Get("src", sch), name=f"quant-{cap}",
-                state_cap=cap,
-            )
-            t0 = df.time
-            for i in range(3):
-                n = 16
-                k = rng.integers(0, 32, n).astype(np.int64)
-                v = rng.integers(0, 8, n).astype(np.int64)
-                d = rng.choice(
-                    np.asarray([1, 1, -1]), n
-                ).astype(np.int64)
-                df.run_steps([{"src": Batch.from_numpy(
-                    sch, [k, v], np.uint64(t0 + i), d, capacity=64
-                )}])
-            from collections import defaultdict
-
-            acc: dict = defaultdict(int)
-            for r in df.peek():
-                acc[tuple(int(c) for c in r[:-2])] += int(r[-1])
-            return sorted(
-                (*key, n) for key, n in acc.items() if n != 0
-            )
-        try:
-            rows_a = run_once(300)
-            entries_after_a = get_bank().snapshot()["entries"]
-            hits_before = get_bank().stats["hits"]
-            rows_b = run_once(400)
-            snap = get_bank().snapshot()
-        finally:
-            configure_bank(None)
-        if rows_a != rows_b:
+        if r["rows_a"] != r["rows_b"]:
             findings.append(LintFinding(
                 "tier-quantization", "equivalence",
                 "same churn through the two rung-mates produced "
                 "different net rows",
             ))
-        if snap["entries"] != entries_after_a:
+        if r["entries"] != r["entries_after_a"]:
             findings.append(LintFinding(
                 "tier-quantization", "key-sharing",
                 f"the second DDL grew the bank from "
-                f"{entries_after_a} to {snap['entries']} entries: "
+                f"{r['entries_after_a']} to {r['entries']} entries: "
                 "capacities within one pow2 rung no longer share "
                 "bank keys",
             ))
-        if snap["hits"] == hits_before:
+        if r["hits"] == r["hits_before"]:
             findings.append(LintFinding(
                 "tier-quantization", "reuse",
                 "the second DDL served no bank hits despite "
@@ -557,7 +566,6 @@ def run_tier_quantization_gate(gate) -> int:
             f"tier quantization gate failed to run: {e!r}",
         )]
     finally:
-        jax.config.update("jax_compilation_cache_dir", old_cache)
         shutil.rmtree(bank_dir, ignore_errors=True)
         shutil.rmtree(xla_cache, ignore_errors=True)
     gate("tier-quantization", None, findings, 0)
@@ -1123,11 +1131,7 @@ def run_sharding_gates(gate, budgets: dict) -> int:
     import jax
 
     from materialize_tpu.analysis import LintFinding
-    from materialize_tpu.parallel import compat
 
-    if not compat.HAS_SHARD_MAP:
-        print(f"sharding gates: skipped ({compat.MISSING_REASON})")
-        return 0
     if len(jax.devices()) < 8:
         print(
             "sharding gates: skipped "

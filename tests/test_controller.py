@@ -322,10 +322,6 @@ class TestSpmdReplica:
         """A replica whose data plane runs SPMD over a 4-device mesh
         (shard_map + all_to_all exchange) serves the same results as a
         single-device one, through the full controller + persist path."""
-        from materialize_tpu.parallel import compat as _compat
-
-        if not _compat.HAS_SHARD_MAP:
-            pytest.skip(_compat.MISSING_REASON)
         port = _free_port()
         loc = PersistLocation(
             str(tmp_path / "blob"), str(tmp_path / "consensus.db")
@@ -387,6 +383,14 @@ class TestSubprocessReplica:
 
         proc = spawn()
         try:
+            # The replica says which device it computes on (PR 24):
+            # spawned with JAX_PLATFORMS=cpu it names the cpu, on its
+            # "listening" line and at HelloOk.
+            line = ""
+            while "listening" not in line:
+                line = proc.stdout.readline().decode()
+                assert line, "replica exited before listening"
+            assert "platform=cpu" in line and "devices=1" in line, line
             persist = PersistClient(FileBlob(blob), SqliteConsensus(cons))
             w = persist.open_writer("kv", KV)
             ctl = ComputeController()
@@ -396,6 +400,10 @@ class TestSubprocessReplica:
             ctl.wait_frontier("mv1", 0, timeout=120)
             rows, _ = ctl.peek("mv1", as_of=0, timeout=120)
             assert as_multiset(rows) == {(1, 1): 1, (2, 2): 1}
+            (state,) = ctl.replica_states()
+            assert state["device"] == {
+                "platform": "cpu", "kind": "cpu", "count": 1,
+            }
             # Hard-kill and respawn on the same port: controller
             # reconnects and replays history; MV resumes from its shard.
             proc.kill()
@@ -409,6 +417,28 @@ class TestSubprocessReplica:
         finally:
             proc.kill()
             proc.wait()
+
+
+    def test_more_workers_than_devices_refuses_at_boot(self, tmp_path):
+        """A device-count misconfiguration is permanent: the replica
+        process exits non-zero at boot, naming it, instead of serving
+        (environmentd turns that exit into its own)."""
+        env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
+        env.pop("XLA_FLAGS", None)  # one CPU device
+        proc = subprocess.run(
+            [
+                sys.executable, "-m", "materialize_tpu.coord.replica",
+                "--port", str(_free_port()),
+                "--blob", str(tmp_path / "blob"),
+                "--consensus", str(tmp_path / "consensus.db"),
+                "--workers", "4",
+            ],
+            env=env, capture_output=True, text=True, timeout=120,
+            cwd=os.path.dirname(os.path.dirname(__file__)),
+        )
+        assert proc.returncode != 0
+        assert "--workers 4 exceeds available devices (1)" in proc.stderr
 
 
 class TestOracle:

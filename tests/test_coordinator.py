@@ -5,6 +5,7 @@ SURVEY.md §3.1/§3.2/§3.3)."""
 
 import socket
 import threading
+import time
 
 import pytest
 
@@ -60,6 +61,25 @@ def cluster(tmp_path):
 
 
 class TestCoordinator:
+    def test_mz_cluster_replicas_shows_the_device(self, cluster):
+        """Which device serves a replica's answers is visible from the
+        server (PR 24): the in-process replica of this CPU suite
+        reports the forced 8-device cpu platform at HelloOk."""
+        import jax
+
+        coord = cluster()
+        deadline = time.monotonic() + 30
+        rows = []
+        while time.monotonic() < deadline:
+            rows = coord.execute(
+                "SELECT name, platform, device_kind, devices "
+                "FROM mz_cluster_replicas WHERE connected = 1"
+            ).rows
+            if rows and rows[0][1]:
+                break
+            time.sleep(0.05)
+        assert rows == [("r0", "cpu", "cpu", len(jax.devices()))]
+
     def test_counter_mv_end_to_end(self, cluster):
         coord = cluster()
         assert coord.execute(

@@ -86,12 +86,6 @@ class TestErrorStream:
 
     def test_sharded_error_stream(self, eight_devices=None):
         import jax
-        import pytest
-
-        from materialize_tpu.parallel import compat as _compat
-
-        if not _compat.HAS_SHARD_MAP:
-            pytest.skip(_compat.MISSING_REASON)
 
         from materialize_tpu.parallel.mesh import make_mesh
 

@@ -6,21 +6,12 @@ analog of the reference's multi-process cluster tests without a cluster
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from jax.sharding import PartitionSpec as P
-
-from materialize_tpu.parallel import compat as _compat
-
-# The whole module exercises shard_map-backed SPMD paths; on JAX
-# builds without any shard_map API it must SKIP, not error
-# (materialize_tpu/parallel/compat.py).
-pytestmark = pytest.mark.skipif(
-    not _compat.HAS_SHARD_MAP, reason=_compat.MISSING_REASON
-)
 
 from materialize_tpu.expr import relation as mir
 from materialize_tpu.expr.relation import AggregateExpr, AggregateFunc
 from materialize_tpu.expr.scalar import col
+from materialize_tpu.parallel import compat as _compat
 from materialize_tpu.parallel.exchange import exchange, shard_of
 from materialize_tpu.parallel.mesh import make_mesh, worker_sharding
 from materialize_tpu.render.dataflow import Dataflow, ShardedDataflow

@@ -72,8 +72,8 @@ def _mk() -> Dataflow:
 # re-serialize an executable that was itself rehydrated from the XLA
 # persistent cache (or JIT-compiled earlier in the same process), and
 # store verification (ProgramBank.store) rejects those payloads —
-# which would leave nothing to serve when the host cache under
-# ~/.cache/materialize_tpu_xla is warm from earlier runs.
+# which would leave nothing to serve when the checkout's .jax_cache
+# is warm from earlier runs.
 _EXPORT_SCRIPT = """\
 import json, sys
 
@@ -102,7 +102,7 @@ def exported_bank(tmp_path_factory):
     bank_dir = str(tmp_path_factory.mktemp("bank-export") / "bank")
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
-    env["MATERIALIZE_TPU_COMPILE_CACHE"] = str(
+    env["JAX_COMPILATION_CACHE_DIR"] = str(
         tmp_path_factory.mktemp("xla-cache")
     )
     proc = subprocess.run(
@@ -113,6 +113,41 @@ def exported_bank(tmp_path_factory):
     report = json.loads(proc.stdout.strip().splitlines()[-1])
     assert report["stores"] > 0, report
     return bank_dir, report
+
+
+@pytest.mark.parametrize("placed", [True, False])
+def test_compile_cache_placement(placed, tmp_path):
+    """Where the package puts the XLA persistent cache (PR 24): with
+    JAX_COMPILATION_CACHE_DIR set it sets NO directory in code (JAX
+    reads the variable itself); without it the directory is
+    <checkout>/.jax_cache — never the home directory, a temporary name
+    or anything that moves between runs. A child interpreter that only
+    imports the package reports its config."""
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["HOME"] = str(tmp_path / "home")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if placed:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "xla")
+    out = subprocess.run(
+        [
+            sys.executable, "-c",
+            "import jax, materialize_tpu; "
+            "print(jax.config.jax_compilation_cache_dir)",
+        ],
+        cwd=repo, env=env, capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    want = (
+        str(tmp_path / "xla") if placed
+        else os.path.join(repo, ".jax_cache")
+    )
+    assert out.stdout.strip().splitlines()[-1] == want
+    assert not (tmp_path / "home").exists()  # nothing under HOME
 
 
 def _copy_bank(src: str, tmp_path) -> str:

@@ -34,12 +34,7 @@ from jax.sharding import PartitionSpec as P
 
 from materialize_tpu.parallel import compat as _compat
 
-pytestmark = [
-    pytest.mark.analysis,
-    pytest.mark.skipif(
-        not _compat.HAS_SHARD_MAP, reason=_compat.MISSING_REASON
-    ),
-]
+pytestmark = pytest.mark.analysis
 
 from materialize_tpu.analysis.shard_prop import (
     CROSS_WORKER,

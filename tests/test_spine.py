@@ -1,7 +1,7 @@
 """Two-run amortized spine: correctness against a multiset oracle.
 
-The Spine is the big-state arrangement form (VERDICT round-2 item 1:
-per-step insert cost must not be linear in state size). These tests pin
+The Spine is the big-state arrangement form (per-step insert cost must
+not be linear in state size). These tests pin
 its semantics: base ⊎ tail multiset sum, host-scheduled compaction,
 overflow growth, and join/dataflow integration at state sizes well past
 the tail tier.
