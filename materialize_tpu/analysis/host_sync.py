@@ -82,6 +82,27 @@ RECORDER_PATH = (
     ("materialize_tpu.utils.trace", "Tracer.record"),
     ("materialize_tpu.utils.trace", "Tracer._append"),
     ("materialize_tpu.utils.trace", "Tracer.span"),
+    # Phase spans of the maintenance path (ISSUE 27): opened, timed
+    # and closed once or many times a committed span. The phases wrap
+    # syncs on purpose (span.readback); the recorder never syncs.
+    ("materialize_tpu.utils.trace", "Tracer.open"),
+    ("materialize_tpu.utils.trace", "Tracer.within"),
+    ("materialize_tpu.utils.trace", "Tracer.close"),
+    ("materialize_tpu.utils.trace", "Tracer.phase"),
+    ("materialize_tpu.utils.trace", "_Phase.__enter__"),
+    ("materialize_tpu.utils.trace", "_Phase.add"),
+    ("materialize_tpu.utils.trace", "_Phase.__exit__"),
+    ("materialize_tpu.storage.persist.machine", "Tally.mark"),
+    ("materialize_tpu.storage.persist.machine", "Tally.since"),
+    ("materialize_tpu.storage.persist.operators", "persist_phase"),
+    (
+        "materialize_tpu.storage.persist.operators",
+        "MaintainedView._open_span",
+    ),
+    (
+        "materialize_tpu.storage.persist.operators",
+        "MaintainedView._close_span",
+    ),
     ("materialize_tpu.utils.compile_ledger", "LedgeredJit.__call__"),
     ("materialize_tpu.utils.compile_ledger", "CompileLedger.record"),
     ("materialize_tpu.utils.compile_ledger", "tier_vector"),

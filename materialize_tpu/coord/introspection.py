@@ -153,6 +153,10 @@ INTROSPECTION_SCHEMAS: dict[str, Schema] = {
             Column("level", S),
             Column("start_us", I),
             Column("duration_us", I),
+            # The record's attributes as JSON text ("" for none): a
+            # phase's counts (reloads, state_bytes, rows, ...), a
+            # span's lower/upper/ticks, a source tick's parts.
+            Column("attrs", S),
         ]
     ),
     "mz_compile_log": Schema(
@@ -635,19 +639,29 @@ def snapshot(coord, name: str) -> list[tuple]:
     if name == "mz_trace_spans":
         from ..utils.trace import TRACER
 
-        # Hot read path: the ring holds up to 4096 spans and every
-        # snapshot re-renders all of them, ~15x the cost of listing
-        # the ring. A completed SpanRecord is immutable, so cache the
-        # rendered row on the record — stamped with the dict epoch,
-        # since a rebalance relabels the three string codes.
+        # Hot read path: the rings hold up to 16,384 spans each and
+        # every snapshot re-renders all of them, ~15x the cost of
+        # listing the rings. A completed SpanRecord is immutable, so
+        # cache the rendered row on the record — stamped with the dict
+        # epoch, since a rebalance relabels the four string codes.
         epoch = GLOBAL_DICT.epoch
         enc = GLOBAL_DICT.encode
+        records = TRACER.records()
+        fresh = {
+            id(r): r.attrs_json()
+            for r in records
+            if r.__dict__.get("_row", (None,))[0] != epoch
+        }
+        # Attribute texts are one long-common-prefix family, which
+        # one-at-a-time inserts pack into a sliver of a label gap
+        # (repr/schema.py encode_bulk): insert the new ones together.
+        GLOBAL_DICT.encode_bulk(fresh.values())
         rows = []
         append = rows.append
-        for r in TRACER.records():
-            cached = r.__dict__.get("_row")
-            if cached is not None and cached[0] == epoch:
-                append(cached[1])
+        for r in records:
+            attrs = fresh.get(id(r))
+            if attrs is None:
+                append(r._row[1])
                 continue
             row = (
                 r.trace_id,
@@ -658,6 +672,7 @@ def snapshot(coord, name: str) -> list[tuple]:
                 enc(r.level),
                 int(r.start * 1e6),
                 int(r.duration * 1e6),
+                enc(attrs),
             )
             r._row = (epoch, row)
             append(row)

@@ -97,8 +97,11 @@ def recv_frame(sock: socket.socket) -> bytes:
     return payload
 
 
-def send_msg(sock: socket.socket, msg: Any) -> None:
-    send_frame(sock, pickle.dumps(msg, protocol=pickle.HIGHEST_PROTOCOL))
+def send_msg(sock: socket.socket, msg: Any) -> int:
+    """Send one message; returns the bytes of its frame's payload."""
+    data = pickle.dumps(msg, protocol=pickle.HIGHEST_PROTOCOL)
+    send_frame(sock, data)
+    return len(data)
 
 
 def recv_msg(sock: socket.socket) -> Any:

@@ -1215,6 +1215,10 @@ class ReplicaWorker:
             )
 
     def _report_frontiers(self, conn) -> bool:
+        from ..utils.trace import TRACER
+
+        t_wall = _time.time()
+        t0 = _time.perf_counter()
         changed = {}
         records = {}
         epochs = {}
@@ -1277,7 +1281,6 @@ class ReplicaWorker:
         spans, compiles, metrics = [], [], None
         if self._ship_observability:
             from ..utils.compile_ledger import LEDGER
-            from ..utils.trace import TRACER
 
             spans = TRACER.drain_shippable()
             compiles = LEDGER.drain_shippable()
@@ -1327,7 +1330,7 @@ class ReplicaWorker:
         if (changed or donation or sharding or recovery or spans
                 or compiles or metrics or freshness or swaps
                 or compactions):
-            ctp.send_msg(
+            sent = ctp.send_msg(
                 conn,
                 ctp.frontiers(
                     changed, records, epochs, self.replica_id,
@@ -1337,6 +1340,15 @@ class ReplicaWorker:
                     freshness=freshness, swaps=swaps,
                     compactions=compactions,
                 ),
+            )
+            # Only a report that was sent is recorded: the worker
+            # loop calls this every turn, and most turns send nothing.
+            # The record ships with the next report that has other
+            # news, or each report would cause one more.
+            TRACER.record(
+                "replica.report_frontiers", t_wall,
+                _time.perf_counter() - t0, ship_alone=False,
+                bytes=sent, spans_shipped=len(spans),
             )
             return True
         return False
@@ -1409,6 +1421,12 @@ def main() -> None:
     from ..utils.trace import TRACER
 
     TRACER.process = f"replica:{args.replica_id}"
+    # ... and, being the process that holds the chip, put every phase
+    # of the maintenance path on the profiler's clock too: with no
+    # profiler session open an annotation is a flag test.
+    import jax.profiler
+
+    TRACER.annotate = jax.profiler.TraceAnnotation
     # The replica is the process that owns the accelerator: take the
     # platform JAX_PLATFORMS names (as JAX itself reads it) NOW, so a
     # missing or busy chip ends this process with JAX's error before

@@ -44,6 +44,8 @@ from ..storage.generator.tpch import (
     TpchGenerator,
 )
 from ..storage.persist import PersistClient, WriteHandle
+from ..storage.persist.machine import TALLY
+from ..utils.trace import TRACER
 
 COUNTER_SCHEMA = Schema([Column("counter", ColumnType.INT64)])
 
@@ -382,6 +384,7 @@ class GeneratorSource:
         self.tick_interval = tick_interval
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
+        self._slept_ms = 0.0  # the runner's sleep before the next tick
         # Ingest-loop health (the freshness plane's mz_source_statuses
         # source): running / stalled (a tick raised; the loop retries
         # next interval) / stopped, with the transition wallclock and
@@ -456,8 +459,11 @@ class GeneratorSource:
                 )
         time = np.full(len(diff), lower, np.uint64)
         w.compare_and_append(cols, nulls, time, diff, lower, upper)
+        return len(diff)
 
-    def _append_all(self, batches: dict, t: int) -> None:
+    def _append_all(self, batches: dict, t: int) -> int:
+        """Append one tick to every subsource; returns the rows."""
+        rows = 0
         for sub, w in self.writers.items():
             if w.upper > t:
                 continue  # already durable (resume after partial crash)
@@ -475,7 +481,8 @@ class GeneratorSource:
                     t + 1,
                 )
             else:
-                self._append_batch(w, b, t, t + 1)
+                rows += self._append_batch(w, b, t, t + 1)
+        return rows
 
     def _set_status(self, status: str, error: str = "") -> None:
         if status != self.status or error != self.last_error:
@@ -484,10 +491,39 @@ class GeneratorSource:
             self.last_error = error
 
     def tick_once(self) -> int:
-        """Advance every subsource by one tick; returns the new frontier."""
+        """Advance every subsource by one tick; returns the new
+        frontier. Records one ``source.tick`` (doc/observability.md):
+        ``t`` joins it to the shard's upper, ``work_ms`` is the sum of
+        its five parts, ``encode_ms`` being what is none of the others
+        (batches to columns, part encoding) and ``compact_ms`` the
+        writers' compaction duty: under ``compaction_mode = 'inline'``
+        the merge of a whole shard, every few ticks."""
         t = self.t
-        self._append_all(self.adapter.tick(t, t), t)
+        mark = TALLY.mark()
+        wall = _time.time()
+        t0 = _time.perf_counter()
+        batches = self.adapter.tick(t, t)
+        t1 = _time.perf_counter()
+        rows = self._append_all(batches, t)
+        work = _time.perf_counter() - t0
         self.t = t + 1
+        did = TALLY.since(mark, "source")
+        write_ms = did.get("write_ms", 0.0)
+        cas_ms = did.get("cas_ms", 0.0)
+        compact_ms = did.get("compact_ms", 0.0)
+        generate_ms = (t1 - t0) * 1e3
+        TRACER.record(
+            "source.tick", wall, work, source=self.name, t=t,
+            work_ms=work * 1e3, generate_ms=generate_ms,
+            encode_ms=(
+                work * 1e3 - generate_ms - write_ms - cas_ms - compact_ms
+            ),
+            write_ms=write_ms, cas_ms=cas_ms, compact_ms=compact_ms,
+            reloads=did.get("reloads", 0),
+            state_bytes=did.get("state_bytes", 0),
+            cas_attempts=did.get("cas_attempts", 0), rows=rows,
+            slept_ms=self._slept_ms,
+        )
         return self.t
 
     def start(self) -> None:
@@ -507,7 +543,9 @@ class GeneratorSource:
                 else:
                     if self.status == "stalled":
                         self._set_status("running")
+                t0 = _time.perf_counter()
                 _time.sleep(self.tick_interval)
+                self._slept_ms = (_time.perf_counter() - t0) * 1e3
 
         self._thread = threading.Thread(target=run, daemon=True)
         self._thread.start()

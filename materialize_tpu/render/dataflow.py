@@ -42,6 +42,7 @@ from ..arrangement.spine import (
     arrange,
     compact_level,
     compact_spine,
+    device_nbytes,
     insert,
     insert_tail,
 )
@@ -62,6 +63,7 @@ from ..parallel.exchange import exchange
 from ..parallel.mesh import WORKER_AXIS, worker_sharding
 from ..repr.batch import Batch, capacity_tier
 from ..repr.schema import DIFF_DTYPE, TIME_DTYPE, Schema
+from ..utils.trace import TRACER
 
 
 # The span program nests cumulative scans (reduce-window lowerings)
@@ -1627,46 +1629,52 @@ class _DataflowBase:
             # read. The contract still holds on any backend when on.
             record = LEDGER.record if sanitizer_enabled() else None
         deltas, flags_or, cflags_or = [], None, None
-        for p in packed:
-            args = (
-                tuple(self.states),
-                self.output,
-                self.err_output,
-                p,
-                self._time_dev,
-            )
-            if env is not None:
-                out, new_states, new_output, new_err, new_t, fl = (
-                    step_fn(*args, env)
+        programs = 0
+        with TRACER.phase("span.dispatch") as ph:
+            for p in packed:
+                args = (
+                    tuple(self.states),
+                    self.output,
+                    self.err_output,
+                    p,
+                    self._time_dev,
                 )
-            else:
-                out, new_states, new_output, new_err, new_t, fl = (
-                    step_fn(*args)
-                )
-            self.states = list(new_states)
-            self.output = new_output
-            self.err_output = new_err
-            self._time_dev = new_t
-            self._time += 1  # direct: keep the device carry live
-            if record is not None:
-                record(
-                    tuple(args[part_arg[part]] for part in donate),
-                    f"{self.name}.run_steps step t={self._time - 1} "
-                    f"(donated {','.join(donate)})",
-                )
-            deltas.append(out)
-            flags_or = self._or_acc(flags_or, fl)
-            self._compact_tick += 1
-            if self._compact_tick % self._compact_every == 0:
-                cflags_or = self._or_acc(
-                    cflags_or,
-                    self._dispatch_compact(
-                        min(
-                            self._due_levels(self._compact_tick),
-                            self._max_compact_level(),
-                        )
-                    ),
-                )
+                if env is not None:
+                    out, new_states, new_output, new_err, new_t, fl = (
+                        step_fn(*args, env)
+                    )
+                else:
+                    out, new_states, new_output, new_err, new_t, fl = (
+                        step_fn(*args)
+                    )
+                self.states = list(new_states)
+                self.output = new_output
+                self.err_output = new_err
+                self._time_dev = new_t
+                self._time += 1  # direct: keep the device carry live
+                if record is not None:
+                    record(
+                        tuple(args[part_arg[part]] for part in donate),
+                        f"{self.name}.run_steps step "
+                        f"t={self._time - 1} "
+                        f"(donated {','.join(donate)})",
+                    )
+                deltas.append(out)
+                flags_or = self._or_acc(flags_or, fl)
+                self._compact_tick += 1
+                programs += 1
+                if self._compact_tick % self._compact_every == 0:
+                    programs += 1
+                    cflags_or = self._or_acc(
+                        cflags_or,
+                        self._dispatch_compact(
+                            min(
+                                self._due_levels(self._compact_tick),
+                                self._max_compact_level(),
+                            )
+                        ),
+                    )
+            ph.add(programs=programs)
         return deltas, flags_or, cflags_or
 
     def _read_flags(self, flags_or, keys: list) -> np.ndarray:
@@ -1786,8 +1794,23 @@ class _DataflowBase:
             if donate is True
             else tuple(donate or ())
         )
-        packed = [self._pack_inputs(i) for i in inputs_list]
-        env = self._build_env()
+        with TRACER.phase("span.upload") as ph:
+            packed = [self._pack_inputs(i) for i in inputs_list]
+            env = self._build_env()
+            if ph:
+                # what packing itself put on the device (a sharded
+                # dataflow deals rows across workers here; a batch
+                # passed through was counted where it was built)
+                ph.add(
+                    bytes=device_nbytes(
+                        [
+                            b
+                            for p, i in zip(packed, inputs_list)
+                            for k, b in p.items()
+                            if b is not i.get(k)
+                        ]
+                    )
+                )
         if parts:
             from ..analysis.donation import guard_read
 
@@ -1833,7 +1856,8 @@ class _DataflowBase:
             deltas, flags, cflags = self._dispatch_span(
                 packed, env, donate=parts
             )
-            over = self._overflowed_keys(flags, cflags)
+            with TRACER.phase("span.readback"):
+                over = self._overflowed_keys(flags, cflags)
             if over:
                 self._restore(ck)
                 for k in over:

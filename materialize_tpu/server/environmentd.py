@@ -468,6 +468,15 @@ class Environment:
         if self._down:
             return report
         self._down = True
+        # The flight recorder (doc/observability.md): where
+        # MZ_TRACE_DUMP_DIR names a directory, the span rings (this
+        # process's and what the replicas shipped) are written there
+        # before anything is stopped. Unset: nothing.
+        dump_dir = os.environ.get("MZ_TRACE_DUMP_DIR")
+        if dump_dir and os.path.isdir(dump_dir):
+            from ..utils.trace import TRACER
+
+            TRACER.dump(os.path.join(dump_dir, "spans.jsonl"))
         self.autoscaler.stop()
         # In-process thread replicas stop via their worker handle (the
         # subprocess ones get the terminate -> kill loop below).

@@ -25,7 +25,13 @@ from .location import (
     ExternalDurabilityError,
     retry_external as _retry,
 )
-from .machine import CompactionRace, Fenced, Machine, UpperMismatch
+from .machine import (
+    TALLY,
+    CompactionRace,
+    Fenced,
+    Machine,
+    UpperMismatch,
+)
 from .pubsub import PUBSUB
 
 
@@ -190,11 +196,17 @@ class WriteHandle:
             keys = (key,)
         else:
             keys = ()
-        self.machine.compare_and_append(
-            keys, lower, upper, n, self.epoch, n_bytes=nbytes
-        )
+        t0 = _time.perf_counter()
+        try:
+            self.machine.compare_and_append(
+                keys, lower, upper, n, self.epoch, n_bytes=nbytes
+            )
+        finally:
+            TALLY.cas_ms += (_time.perf_counter() - t0) * 1e3
         if self.auto_compaction:
+            t0 = _time.perf_counter()
             self._maybe_request_compaction()
+            TALLY.compact_ms += (_time.perf_counter() - t0) * 1e3
 
     def _maybe_request_compaction(self) -> None:
         """The writer's entire compaction duty under ISSUE 20: when the
@@ -242,12 +254,17 @@ class WriteHandle:
         from ...repr.schema import GLOBAL_DICT
 
         dict_epoch = GLOBAL_DICT.epoch
+        t0 = _time.perf_counter()
         data = encode_part(self.schema, cols, nulls, time, diff)
         self._part_seq += 1
         key = (
             f"{self.machine.shard}/part-e{self.epoch}-{self._part_seq}"
         )
+        t1 = _time.perf_counter()
         _retry(lambda: self.machine.blob.set(key, data))
+        TALLY.encode_ms += (t1 - t0) * 1e3
+        TALLY.write_ms += (_time.perf_counter() - t1) * 1e3
+        TALLY.part_bytes += len(data)
         # Write-through to the hot tier: the freshest span is exactly
         # what readers fetch next, so it must never pay a rehydration.
         cache = getattr(self.machine, "part_cache", None)
@@ -293,6 +310,7 @@ class ReadHandle:
             for k in b.keys:
                 ent = cache.get(k) if cache is not None else None
                 if ent is not None:
+                    TALLY.cache_hits += 1
                     sch, cols, nulls, time, diff = ent[:5]
                 else:
                     data = _retry(lambda k=k: self.machine.blob.get(k))
@@ -301,6 +319,8 @@ class ReadHandle:
                             f"part {k} swapped out by a concurrent "
                             "compaction"
                         )
+                    TALLY.parts_read += 1
+                    TALLY.part_bytes += len(data)
                     from ...repr.schema import GLOBAL_DICT
 
                     dict_epoch = GLOBAL_DICT.epoch
@@ -397,6 +417,7 @@ class ReadHandle:
             assert lo >= st.since or lo >= hi, (
                 f"fetch lo {lo} below since {st.since}"
             )
+            TALLY.batches_listed += len(st.batches)
             batches = [
                 b for b in st.batches if b.upper > lo and b.lower < hi
             ]

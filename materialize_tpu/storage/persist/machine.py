@@ -10,6 +10,8 @@ the background duties (``internal/compact.rs``, ``internal/gc.rs``).
 
 from __future__ import annotations
 
+import operator
+import threading
 import time as _time
 from dataclasses import replace
 
@@ -23,8 +25,81 @@ from .location import (
     VersionedData,
     retry_external,
 )
+from ...utils.metrics import REGISTRY
 from .pubsub import PUBSUB
 from .state import HollowBatch, ShardState
+
+
+class Tally(threading.local):
+    """What persist did on this thread, counted where it happens: every
+    field only grows. A caller that wants the cost of a stretch of work
+    (a phase of a span, a source tick) takes ``mark()`` before it and
+    ``since(mark)`` after; persist itself knows nothing of who asks."""
+
+    FIELDS = (
+        "reloads",  # Machine.reload(): a consensus head read
+        "state_bytes",  # ... and the bytes of ShardState it decoded
+        "cas_attempts",  # consensus compare-and-sets tried
+        "batches_listed",  # HollowBatches a fetch filtered through
+        "parts_read",  # parts got from blob and decoded
+        "cache_hits",  # parts served by the hot tier instead
+        "part_bytes",  # encoded bytes of parts read from or written to blob
+        "encode_ms",  # WriteHandle: encoding a part,
+        "write_ms",  # its blob write,
+        "cas_ms",  # the state transition (reloads + CaS),
+        "compact_ms",  # and its compaction duty: under compaction_mode
+        # = 'inline' the merge of the whole shard, on the writer's path
+    )
+    reloads = state_bytes = cas_attempts = batches_listed = 0
+    parts_read = cache_hits = part_bytes = 0
+    encode_ms = write_ms = cas_ms = compact_ms = 0.0
+
+    def mark(self) -> tuple:
+        return _TALLY_FIELDS(self)
+
+    def since(self, mark: tuple, shard_kind: str) -> dict:
+        """The fields that grew since ``mark``. ``shard_kind`` (source
+        or sink, which only the caller knows) labels the cumulative
+        counters this feeds."""
+        now = _TALLY_FIELDS(self)
+        if now == mark:
+            return {}
+        got = {
+            f: n - m for f, n, m in zip(self.FIELDS, now, mark) if n != m
+        }
+        for name, field, help_ in _COUNTERS:
+            if field in got:
+                REGISTRY.get_or_create(
+                    "counter_vec", name, help_, label="shard"
+                ).inc(shard_kind, got[field])
+        return got
+
+
+_TALLY_FIELDS = operator.attrgetter(*Tally.FIELDS)
+TALLY = Tally()
+
+# Cumulative operator-facing counters, per process (a replica's ship on
+# the metrics_report_ms piggyback): persist work on the maintenance
+# path, i.e. what a Tally.since() saw. (name, tally field, help)
+_COUNTERS = (
+    (
+        "mz_persist_state_reloads_total", "reloads",
+        "consensus head reads (full ShardState decodes) on the "
+        "maintenance path",
+    ),
+    (
+        "mz_persist_state_decoded_bytes_total", "state_bytes",
+        "bytes of ShardState decoded by those reloads",
+    ),
+    (
+        "mz_persist_parts_read_total", "parts_read",
+        "batch parts read from blob and decoded on the maintenance path",
+    ),
+    (
+        "mz_persist_cas_attempts_total", "cas_attempts",
+        "consensus compare-and-sets attempted on the maintenance path",
+    ),
+)
 
 
 class Fenced(RuntimeError):
@@ -79,6 +154,8 @@ class Machine:
     def reload(self) -> ShardState:
         head = self.consensus.head(self.shard)
         assert head is not None
+        TALLY.reloads += 1
+        TALLY.state_bytes += len(head.data)
         self._state = ShardState.from_bytes(head.data)
         return self._state
 
@@ -98,6 +175,7 @@ class Machine:
             if new is None:
                 return result
             new = replace(new, seqno=st.seqno + 1)
+            TALLY.cas_attempts += 1
             if self.consensus.compare_and_set(
                 self.shard, st.seqno, VersionedData(new.seqno, new.to_bytes())
             ):

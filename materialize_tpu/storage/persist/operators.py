@@ -20,14 +20,17 @@ rebuilds == 0 for fingerprint-unchanged dataflows).
 from __future__ import annotations
 
 import time as _time
+from contextlib import contextmanager
 
 import numpy as np
 
+from ...arrangement.spine import device_nbytes
 from ...render.dataflow import Dataflow
 from ...repr.batch import Batch, capacity_tier
 from ...repr.schema import Schema
+from ...utils.trace import TRACER
 from .client import PersistClient, ReadHandle, WriteHandle
-from .machine import Fenced, UpperMismatch
+from .machine import TALLY, Fenced, UpperMismatch
 
 
 class SinkConflict(RuntimeError):
@@ -42,6 +45,19 @@ class AsOfError(RuntimeError):
     dedicated ``CompactionRace``, no longer blanket ValueError, so a
     real codec/caller bug surfaces instead of retrying forever — and a
     bad user timestamp must fail immediately."""
+
+
+@contextmanager
+def persist_phase(name: str, shard_kind: str):
+    """A phase of the open span (utils/trace.py) whose counts are what
+    persist tallied on this thread while it ran: reloads, state bytes,
+    parts, compare-and-sets. ``shard_kind``: ``source`` or ``sink``."""
+    mark = TALLY.mark()
+    with TRACER.phase(name) as ph:
+        try:
+            yield ph
+        finally:
+            ph.add(**TALLY.since(mark, shard_kind))
 
 
 def updates_to_batch(
@@ -105,12 +121,17 @@ class ShardSource:
         """Chunk [frontier, target), forwarded to target-1. Caller must
         have confirmed target <= shard upper."""
         assert self.frontier is not None and target > self.frontier - 1
-        _sch, cols, nulls, time, diff = self.reader.fetch(
-            self.frontier, target
-        )
-        batch = updates_to_batch(
-            self.schema, cols, nulls, time, diff, target - 1
-        )
+        with persist_phase("span.fetch", "source") as ph:
+            _sch, cols, nulls, time, diff = self.reader.fetch(
+                self.frontier, target
+            )
+            ph.add(rows=len(diff))
+        with TRACER.phase("span.upload") as ph:
+            batch = updates_to_batch(
+                self.schema, cols, nulls, time, diff, target - 1
+            )
+            if ph:
+                ph.add(bytes=device_nbytes(batch))
         self.frontier = target
         return batch
 
@@ -406,6 +427,10 @@ class IndexSource:
         self.frontier = frontier
 
     def fetch_to(self, target: int) -> Batch:
+        with TRACER.phase("span.fetch"):
+            return self._fetch_to(target)
+
+    def _fetch_to(self, target: int) -> Batch:
         assert self.frontier is not None and target > self.frontier - 1
         parts = self._take_until(target)
         self.frontier = target
@@ -892,12 +917,25 @@ class MaintainedView:
         sink/materialized_view_v2.rs)."""
         if self.writer is None:
             return
-        cols = batch.to_columns()
-        data_cols, diff = cols[:-2], cols[-1]
+        with TRACER.phase("span.readback") as ph:
+            # the device-to-host copy: waits for the step that made it
+            cols = batch.to_columns()
+            data_cols, diff = cols[:-2], cols[-1]
+            n = len(diff)
+            nulls = [
+                None if nl is None else np.asarray(nl)[:n]
+                for nl in batch.nulls
+            ]
+            if ph:
+                ph.add(bytes=device_nbytes(batch))
+        with persist_phase("span.append", "sink") as ph:
+            ph.add(rows=n)
+            self._append_columns(data_cols, nulls, diff, lower, upper, t)
+
+    def _append_columns(
+        self, data_cols, nulls, diff, lower: int, upper: int, t: int
+    ) -> None:
         n = len(diff)
-        nulls = [
-            None if nl is None else np.asarray(nl)[:n] for nl in batch.nulls
-        ]
         if self._sink_finalizes:
             data_cols = self._finalize_sink_columns(
                 [np.asarray(c) for c in data_cols], nulls, diff
@@ -967,6 +1005,51 @@ class MaintainedView:
         frontier-joined progress. Returns False if the inputs did not
         advance within the timeout."""
         self.sync_spans()
+        span = self._open_span(self.upper)
+        with TRACER.within(span):
+            if not self._step_tick(timeout):
+                return False
+        self._close_span(span, 1)
+        return True
+
+    def _open_span(self, lower: int):
+        """The record of one committed span (doc/observability.md):
+        the phases timed while it is the context are its children. A
+        span that commits nothing is never closed and records nothing."""
+        return TRACER.open(
+            "span", dataflow=getattr(self.df, "name", "") or "df",
+            lower=lower,
+        )
+
+    def _close_span(self, span, ticks: int, replayed: bool = False):
+        TRACER.close(
+            span, upper=self._upper, ticks=ticks, epoch=self.span_epoch,
+            replayed=replayed,
+        )
+
+    def _wait_for_inputs(self, frontier: int, timeout: float):
+        """The least upper beyond ``frontier`` over every input, or
+        None if one of them did not get there in time."""
+        target = None
+        for s in self.sources.values():
+            with persist_phase("span.wait", "source"):
+                upper = s.reader.wait_for_upper(frontier, timeout)
+            if upper is None:
+                return None
+            target = upper if target is None else min(target, upper)
+        return target
+
+    def _commit_tick(self, t: int, out: Batch, lower: int) -> None:
+        """One tick's validated delta: durable, then visible."""
+        with TRACER.phase("span.readback"):
+            out = self.df.gather_delta(out)  # no-op on single-device
+        self._append(out, lower, t + 1, t)
+        with TRACER.phase("span.publish"):
+            self._publish(t, out)
+            self._record_history(t, out)
+        self._upper = t + 1
+
+    def _step_tick(self, timeout: float) -> bool:
         lower = self.upper
         if not self.sources:
             # A source-less (pure constant) dataflow: one step at time 0
@@ -979,21 +1062,13 @@ class MaintainedView:
                 )
             arrived = _time.monotonic()
             self.df.time = 0
-            out = self.df.step({})
-            out = self.df.gather_delta(out)
-            self._append(out, 0, 1, 0)
-            self._publish(0, out)
-            self._record_history(0, out)
-            self._upper = 1
+            self._commit_tick(0, self.df.step({}), 0)
             self._dispatched = 1
             self._record_freshness(1, arrived)
             return True
-        target = None
-        for s in self.sources.values():
-            upper = s.reader.wait_for_upper(lower, timeout)  # > lower
-            if upper is None:
-                return False
-            target = upper if target is None else min(target, upper)
+        target = self._wait_for_inputs(lower, timeout)  # > lower
+        if target is None:
+            return False
         # One timestamp per steady-state step: chunk boundaries are then
         # DETERMINISTIC across active-active replicas, so racing sink
         # appends are byte-identical and losing a race is always safe.
@@ -1013,12 +1088,7 @@ class MaintainedView:
                 self.df.capture_basic_multisets()
             )
         self.df.time = t
-        out = self.df.step(polled)
-        out = self.df.gather_delta(out)  # no-op on single-device
-        self._append(out, lower, target, t)
-        self._publish(t, out)
-        self._record_history(t, out)
-        self._upper = target
+        self._commit_tick(t, self.df.step(polled), lower)
         self._dispatched = target
         self._record_freshness(target, arrived)
         return True
@@ -1132,15 +1202,9 @@ class MaintainedView:
         ticks: list = []
         for k in range(max_ticks):
             want = lower + k
-            target = None
-            for s in self.sources.values():
-                upper = s.reader.wait_for_upper(
-                    want, timeout if k == 0 else 0.0
-                )
-                if upper is None:
-                    target = None
-                    break
-                target = upper if target is None else min(target, upper)
+            target = self._wait_for_inputs(
+                want, timeout if k == 0 else 0.0
+            )
             if target is None:
                 break
             target = min(target, want + 1)
@@ -1157,30 +1221,31 @@ class MaintainedView:
         per-tick durable appends from validated deltas."""
         self.sync_spans()
         lower = self.upper
-        ticks = self._gather_ready_ticks(lower, max_ticks, timeout)
-        if not ticks:
-            return False
-        arrived = _time.monotonic()
-        if self.df.time != ticks[0][0]:
-            self.df.time = ticks[0][0]
-        deltas = self.df.run_steps(
-            [inp for _, inp in ticks],
-            defer_check=True,
-            donate=self._span_donation(),
-        )
-        if self.df.check_flags():
-            deltas = self.df.replayed_deltas
-        lo = lower
-        for (t, _), out in zip(ticks, deltas):
-            out = self.df.gather_delta(out)
-            self._append(out, lo, t + 1, t)
-            self._publish(t, out)
-            self._record_history(t, out)
-            lo = t + 1
-            self._upper = lo
-        self._dispatched = lo
-        self.span_epoch += 1
-        self._record_freshness(lo, arrived)
+        span = self._open_span(lower)
+        with TRACER.within(span):
+            ticks = self._gather_ready_ticks(lower, max_ticks, timeout)
+            if not ticks:
+                return False
+            arrived = _time.monotonic()
+            if self.df.time != ticks[0][0]:
+                self.df.time = ticks[0][0]
+            deltas = self.df.run_steps(
+                [inp for _, inp in ticks],
+                defer_check=True,
+                donate=self._span_donation(),
+            )
+            with TRACER.phase("span.readback"):
+                replayed = self.df.check_flags()
+            if replayed:
+                deltas = self.df.replayed_deltas
+            lo = lower
+            for (t, _), out in zip(ticks, deltas):
+                self._commit_tick(t, out, lo)
+                lo = t + 1
+            self._dispatched = lo
+            self.span_epoch += 1
+            self._record_freshness(lo, arrived)
+        self._close_span(span, len(ticks), replayed)
         return True
 
     def _step_span_pipelined(
@@ -1193,7 +1258,9 @@ class MaintainedView:
         from ...utils.dyncfg import COMPUTE_CONFIGS, SPAN_WINDOW_SPANS
 
         lower = self._dispatched
-        ticks = self._gather_ready_ticks(lower, max_ticks, timeout)
+        span = self._open_span(lower)
+        with TRACER.within(span):
+            ticks = self._gather_ready_ticks(lower, max_ticks, timeout)
         if not ticks:
             # No new input: drain the in-flight span so the committed
             # frontier (and peeks waiting on it) still progresses.
@@ -1217,30 +1284,23 @@ class MaintainedView:
             self.df.time = ticks[0][0]
         # Our own dispatch must not self-sync through the registered
         # span barrier (that would serialize the double buffer).
-        from ...utils.trace import TRACER
-
-        t_wall = _time.time()  # host-sync: ok(pure host clock read)
-        t0 = _time.perf_counter()
         self._barrier.in_dispatch = True
         try:
-            deltas = self.df.run_steps(
-                [inp for _, inp in ticks],
-                defer_check=True,
-                donate=self._span_donation(),
-            )
+            with TRACER.within(span):
+                deltas = self.df.run_steps(
+                    [inp for _, inp in ticks],
+                    defer_check=True,
+                    donate=self._span_donation(),
+                )
         finally:
             self._barrier.in_dispatch = False
-        if TRACER.enabled("debug"):
-            TRACER.record(
-                "view.span.dispatch", t_wall,
-                _time.perf_counter() - t0, level="debug",
-                ticks=len(ticks),
-            )
         snap = self.df.flags_snapshot()
         entries = [(t, out) for (t, _), out in zip(ticks, deltas)]
         self._window_ticks.extend(entries)
         prev = self._inflight_span
-        self._inflight_span = (snap, entries, ticks[-1][0] + 1, arrived)
+        self._inflight_span = (
+            snap, entries, ticks[-1][0] + 1, arrived, span,
+        )
         self._dispatched = ticks[-1][0] + 1
         if prev is not None:
             self._commit_span(prev)
@@ -1251,29 +1311,21 @@ class MaintainedView:
         publish the span's deltas (device handoff), record history,
         and advance the committed frontier; an overflow triggers the
         whole-window rollback+replay."""
-        from ...utils.trace import TRACER
-
-        snap, entries, target, arrived = handle
-        t_wall = _time.time()  # host-sync: ok(pure host clock read)
-        t0 = _time.perf_counter()
-        if self.df.read_flags_snapshot(snap):
-            self._recover_window()
-            return
-        for t, out in entries:
-            self._publish(t, out)
-            self._record_history(t, out)
-            self._upper = t + 1
-        self.span_epoch += 1
-        self._record_freshness(target, arrived)
-        if TRACER.enabled("debug"):
-            # The span-commit cadence record (ISSUE 12): boundary
-            # readback wait + publish, at DEBUG so the default level
-            # keeps the per-span path recorder-free.
-            TRACER.record(
-                "view.span.commit", t_wall,
-                _time.perf_counter() - t0, level="debug",
-                ticks=len(entries), epoch=self.span_epoch,
-            )
+        snap, entries, target, arrived, span = handle
+        with TRACER.within(span):
+            with TRACER.phase("span.readback"):
+                replayed = self.df.read_flags_snapshot(snap)
+            if replayed:
+                self._recover_window()
+            else:
+                with TRACER.phase("span.publish"):
+                    for t, out in entries:
+                        self._publish(t, out)
+                        self._record_history(t, out)
+                        self._upper = t + 1
+                self.span_epoch += 1
+                self._record_freshness(target, arrived)
+        self._close_span(span, len(entries), replayed)
 
     def _record_freshness(self, frontier: int, arrived: float) -> None:
         """Committed-span-boundary lag recording: wallclock_lag_ms =

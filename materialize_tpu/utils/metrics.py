@@ -52,6 +52,33 @@ class Counter(_Metric):
             return [(self.name, {}, self._value)]
 
 
+class CounterVec(_Metric):
+    """A counter family with one label: one sample a label value."""
+
+    kind = "counter"
+
+    def __init__(self, name, help_="", label="", registry=None):
+        super().__init__(name, help_, registry)
+        self.label = label
+        self._values: dict[str, float] = {}
+
+    def inc(self, label_value: str, amount: float = 1.0) -> None:
+        with self._lock:
+            self._values[label_value] = (
+                self._values.get(label_value, 0.0) + amount
+            )
+
+    def value(self, label_value: str) -> float:
+        return self._values.get(label_value, 0.0)
+
+    def samples(self):
+        with self._lock:
+            return [
+                (self.name, {self.label: k}, v)
+                for k, v in sorted(self._values.items())
+            ]
+
+
 class Gauge(_Metric):
     kind = "gauge"
 
@@ -218,6 +245,9 @@ class MetricsRegistry:
 
     def counter(self, name, help_="") -> Counter:
         return Counter(name, help_, registry=self)
+
+    def counter_vec(self, name, help_="", label="") -> CounterVec:
+        return CounterVec(name, help_, label=label, registry=self)
 
     def gauge(self, name, help_="") -> Gauge:
         return Gauge(name, help_, registry=self)

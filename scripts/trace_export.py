@@ -13,7 +13,9 @@ Two input shapes (ISSUE 12 tentpole d):
   python scripts/trace_export.py --spans SPANS.json [-o out...]
       Convert a span-record dump (the ``mz_trace_spans`` shape: a
       JSON array of {trace_id, span_id, parent_id, process, name,
-      start_us, duration_us, ...}) into one row per process.
+      start_us, duration_us, ...}, or one such object a line, as the
+      flight recorder writes ``$MZ_TRACE_DUMP_DIR/spans.jsonl``) into
+      one row per process.
 
 The conversion functions are importable (bench.py --trace uses
 ``bench_trace_to_chrome`` to emit its perfetto file next to the JSON;
@@ -146,22 +148,7 @@ def spans_to_chrome(spans: list) -> dict:
 
 def tracer_records_to_chrome(records) -> dict:
     """utils.trace.SpanRecord objects -> Chrome trace object."""
-    return spans_to_chrome(
-        [
-            {
-                "name": r.name,
-                "process": r.process,
-                "start_us": r.start * 1e6,
-                "duration_us": r.duration * 1e6,
-                "trace_id": r.trace_id,
-                "span_id": r.span_id,
-                "parent_id": r.parent_id,
-                "level": r.level,
-                "attrs": r.attrs,
-            }
-            for r in records
-        ]
-    )
+    return spans_to_chrome([r.to_json() for r in records])
 
 
 def validate_chrome_trace(obj: dict) -> list[str]:
@@ -195,6 +182,17 @@ def write_chrome_trace(path: str, obj: dict) -> str:
     return path
 
 
+def load_json_or_lines(path: str):
+    """A JSON document, or one JSON object a line (the flight
+    recorder's ``spans.jsonl``) as a list."""
+    with open(path) as f:
+        text = f.read()
+    try:
+        return json.loads(text)
+    except ValueError:
+        return [json.loads(ln) for ln in text.splitlines() if ln.strip()]
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("input", help="bench --trace JSON (default) or "
@@ -203,8 +201,9 @@ def main(argv=None) -> int:
                     help="input is an mz_trace_spans-shaped array")
     ap.add_argument("-o", "--output", default=None)
     args = ap.parse_args(argv)
-    with open(args.input) as f:
-        data = json.load(f)
+    data = load_json_or_lines(args.input)
+    if args.spans and isinstance(data, dict):
+        data = [data]  # a dump of one line
     if args.spans or isinstance(data, list):
         chrome = spans_to_chrome(data)
     else:

@@ -76,15 +76,21 @@ class TestTracer:
             pass
         assert any(r.name == "d2" for r in tr.records())
 
-    def test_remote_parent_propagation(self):
+    def test_adopt_propagates_the_shipped_context(self):
         tr = Tracer()
         with tr.span("client") as cid:
-            shipped = tr.current_span()
-        with tr.remote_parent(shipped):
+            shipped = tr.context()
+        assert shipped["s"] == cid
+        with tr.adopt(shipped):
             with tr.span("server"):
+                pass
+        with tr.adopt(None):  # nothing shipped: a pass-through
+            with tr.span("orphan"):
                 pass
         recs = {r.name: r for r in tr.records()}
         assert recs["server"].parent_id == cid
+        assert recs["server"].trace_id == shipped["t"]
+        assert recs["orphan"].parent_id is None
 
 
 class TestIntrospectionSql:

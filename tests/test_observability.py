@@ -561,7 +561,7 @@ class TestIntrospectionConcurrency:
                 for vals in snapshot(coord, "mz_metrics"):
                     assert isinstance(vals[-1], float)
                 for vals in snapshot(coord, "mz_trace_spans"):
-                    assert vals[-1] >= 0  # duration_us
+                    assert vals[-2] >= 0  # duration_us (before attrs)
                 reads += 1
             assert reads >= 10, reads
             # ...then one full SQL read through the renderer too.
@@ -730,3 +730,455 @@ class TestTraceExport:
         assert {"stmt", "inner"} <= names
         # json-serializable end to end
         json.dumps(chrome)
+
+
+# ---------------------------------------------------------------------------
+# phase spans of the maintenance path (ISSUE 27)
+# ---------------------------------------------------------------------------
+
+PHASES_SINKED = {
+    "span.wait", "span.fetch", "span.upload", "span.dispatch",
+    "span.readback", "span.append", "span.publish",
+}
+
+
+def _kv_schema():
+    from materialize_tpu.repr.schema import Column, ColumnType, Schema
+
+    return Schema(
+        [Column("k", ColumnType.INT64), Column("v", ColumnType.INT64)]
+    )
+
+
+def _kv_tick(t: int):
+    import numpy as np
+
+    return (
+        [np.asarray([t], np.int64), np.asarray([10], np.int64)],
+        [None, None],
+        np.full(1, t, np.uint64),
+        np.asarray([1], np.int64),
+    )
+
+
+def _kv_view(ticks: int, sink: str | None = "out", name: str = "mv"):
+    """A view over one shard holding ``ticks`` un-compacted one-row
+    batches, hydrated; its writer, to add more."""
+    from materialize_tpu.expr import relation as mir
+    from materialize_tpu.render.dataflow import Dataflow
+    from materialize_tpu.storage.persist import (
+        MaintainedView,
+        MemBlob,
+        MemConsensus,
+        PersistClient,
+    )
+
+    kv = _kv_schema()
+    c = PersistClient(MemBlob(), MemConsensus())
+    w = c.open_writer("kv", kv)
+    for t in range(ticks):
+        w.compare_and_append(*_kv_tick(t), t, t + 1)
+    view = MaintainedView(
+        c, Dataflow(mir.Get("kv", kv), name=name), {"kv": ("kv", kv)}, sink
+    )
+    return view, w
+
+
+def _append_ticks(w, lo: int, n: int) -> None:
+    for t in range(lo, lo + n):
+        w.compare_and_append(*_kv_tick(t), t, t + 1)
+
+
+@pytest.fixture
+def tracer():
+    """The process tracer, emptied, at ``info``, with no annotate hook;
+    restored afterwards (sibling tests share it)."""
+    saved = (TRACER.level, TRACER.annotate)
+    TRACER.set_level("info")
+    TRACER.annotate = None
+    TRACER.clear()
+    yield TRACER
+    TRACER.set_level(saved[0])
+    TRACER.annotate = saved[1]
+
+
+def _spans_of(dataflow: str) -> list:
+    """(span record, {phase name: child record}) of one dataflow."""
+    recs = TRACER.records()
+    out = []
+    for r in recs:
+        if r.name == "span" and r.attrs.get("dataflow") == dataflow:
+            kids = {c.name: c for c in recs if c.parent_id == r.span_id}
+            out.append((r, kids))
+    return out
+
+
+class TestPhaseAccumulator:
+    def test_one_record_a_phase_with_summed_counts(self):
+        tr = Tracer()
+        sp = tr.open("parent", lower=3)
+        with tr.within(sp):
+            for rows in (2, 5, 7):
+                with tr.phase("parent.work", calls=1) as ph:
+                    ph.add(rows=rows)
+            with tr.phase("parent.other"):
+                pass
+        tr.close(sp, upper=4)
+        recs = {r.name: r for r in tr.records()}
+        assert set(recs) == {"parent", "parent.work", "parent.other"}
+        assert recs["parent"].attrs == {"lower": 3, "upper": 4}
+        work = recs["parent.work"]
+        assert work.parent_id == recs["parent"].span_id
+        assert work.attrs == {"calls": 3, "rows": 14, "n": 3}
+        assert recs["parent.other"].attrs == {"n": 1}
+        assert work.duration <= recs["parent"].duration
+        assert work.start >= recs["parent"].start
+
+    def test_a_span_never_closed_records_nothing(self):
+        tr = Tracer()
+        sp = tr.open("parent")
+        with tr.within(sp):
+            with tr.phase("parent.work"):
+                pass
+        assert tr.records() == []
+
+    def test_a_phase_outside_a_span_is_nothing(self):
+        tr = Tracer()
+        calls = []
+        tr.annotate = calls.append
+        with tr.phase("lonely", rows=1) as ph:
+            assert not ph
+            ph.add(rows=2)
+        with tr.adopt({"t": 1, "s": 2}):  # a context, not a span
+            with tr.phase("lonely") as ph:
+                assert not ph
+        assert tr.records() == [] and calls == []
+
+    def test_a_record_of_the_shipping_waits_for_company(self):
+        tr = Tracer()
+        tr.enable_ship()
+        tr.record("report", 0.0, 0.0, ship_alone=False)
+        assert tr.drain_shippable() == []  # no message of its own
+        tr.record("news", 0.0, 0.0)
+        assert [w[2] for w in tr.drain_shippable()] == ["report", "news"]
+        assert tr.drain_shippable() == []
+
+    def test_rings_keep_16384(self):
+        from materialize_tpu.utils.trace import RING_CAPACITY
+
+        assert RING_CAPACITY == 16384
+        tr = Tracer()
+        tr.enable_ship()
+        assert tr._buf.maxlen == 16384
+        assert tr._ingested.maxlen == 16384
+        assert tr._ship.maxlen == 16384
+        for i in range(16384 + 10):
+            tr.record("r", 0.0, 0.0)
+        assert len(tr.records()) == 16384
+
+
+class TestMaintenancePhases:
+    def test_sinked_span_has_every_phase_child(self, tracer):
+        view, w = _kv_view(4)
+        assert view.upper == 4
+        _append_ticks(w, 4, 3)
+        tracer.clear()
+        assert view._step_span_sync(8, 1.0)
+        spans = _spans_of("mv")
+        assert len(spans) == 1  # one record a committed span
+        span, kids = spans[0]
+        assert span.level == "info"
+        assert span.attrs["lower"] == 4
+        assert span.attrs["upper"] == 7 == view.upper
+        assert span.attrs["ticks"] == 3
+        assert span.attrs["epoch"] == view.span_epoch
+        assert span.attrs["replayed"] is False
+        assert set(kids) == PHASES_SINKED
+        assert sum(k.duration for k in kids.values()) <= span.duration
+        for k in kids.values():
+            assert k.start >= span.start
+            assert k.attrs["n"] >= 1
+        assert kids["span.fetch"].attrs["rows"] == 3
+        assert kids["span.append"].attrs["rows"] == 3
+        assert kids["span.append"].attrs["cas_attempts"] == 3
+        assert kids["span.append"].attrs["part_bytes"] > 0
+        assert kids["span.dispatch"].attrs["programs"] >= 3
+        assert kids["span.upload"].attrs["bytes"] > 0
+        assert kids["span.readback"].attrs["bytes"] > 0
+        # nothing ready: no span is committed, none is recorded
+        tracer.clear()
+        assert not view._step_span_sync(8, 0.0)
+        assert _spans_of("mv") == []
+
+    @pytest.mark.parametrize("path", ["sync", "pipelined", "tick"])
+    def test_every_stepping_path_emits_the_same_names(self, tracer, path):
+        sink = None if path == "pipelined" else "out"
+        view, w = _kv_view(2, sink=sink, name=path)
+        _append_ticks(w, 2, 2)
+        tracer.clear()
+        if path == "tick":
+            assert view.step(1.0)
+        else:
+            assert view.step_span(timeout=1.0)
+            view.sync_spans()
+        names = {r.name for r in TRACER.records()}
+        spans = _spans_of(path)
+        assert len(spans) == 1
+        span, kids = spans[0]
+        assert span.attrs["upper"] == view.upper
+        # an index view appends nothing; every other phase is there
+        want = PHASES_SINKED - ({"span.append"} if sink is None else set())
+        assert set(kids) == want
+        assert names == want | {"span"}
+
+    def test_fetch_counts_grow_with_the_shards_age(self, tracer):
+        view, w = _kv_view(4)
+
+        def one_span(lo: int) -> dict:
+            _append_ticks(w, lo, 2)
+            tracer.clear()
+            assert view._step_span_sync(8, 1.0)
+            ((_span, kids),) = _spans_of("mv")
+            return kids["span.fetch"].attrs
+
+        young = one_span(4)
+        assert young["reloads"] >= 2 and young["state_bytes"] > 0
+        assert young["batches_listed"] >= 2 * 5
+        # 200 ticks later, nothing having merged the source's batches
+        _append_ticks(w, 6, 200)
+        while view.upper < 206:
+            assert view._step_span_sync(8, 1.0)
+        old = one_span(206)
+        assert old["reloads"] == young["reloads"]
+        assert old["state_bytes"] > 10 * young["state_bytes"]
+        assert old["batches_listed"] >= young["batches_listed"] + 2 * 200
+
+    def test_annotate_hook_once_an_occurrence(self, tracer):
+        view, w = _kv_view(2)
+        _append_ticks(w, 2, 2)
+        entered = []
+
+        class Annotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                entered.append(self.name)
+
+            def __exit__(self, *exc):
+                return False
+
+        tracer.annotate = Annotation
+        tracer.clear()
+        assert view._step_span_sync(8, 1.0)
+        ((_span, kids),) = _spans_of("mv")
+        assert all(n.startswith("mz:span.") for n in entered)
+        for name, k in kids.items():
+            assert entered.count("mz:" + name) == k.attrs["n"]
+        assert len(entered) == sum(k.attrs["n"] for k in kids.values())
+        # at 'off' nothing is annotated and nothing recorded
+        _append_ticks(w, 4, 2)
+        entered.clear()
+        tracer.clear()
+        tracer.set_level("off")
+        assert view._step_span_sync(8, 1.0)
+        assert entered == [] and TRACER.records() == []
+        # and with no hook set, phases record without it
+        tracer.set_level("info")
+        tracer.annotate = None
+        _append_ticks(w, 6, 1)
+        assert view._step_span_sync(8, 1.0)
+        assert entered == [] and len(_spans_of("mv")) == 1
+
+    def test_persist_counters_by_shard_kind(self, tracer):
+        from materialize_tpu.utils.metrics import REGISTRY
+
+        def value(name, kind):
+            m = REGISTRY.get(name)
+            return 0.0 if m is None else m.value(kind)
+
+        names = (
+            "mz_persist_state_reloads_total",
+            "mz_persist_state_decoded_bytes_total",
+            "mz_persist_cas_attempts_total",
+        )
+        view, w = _kv_view(2)
+        _append_ticks(w, 2, 2)
+        before = {(n, k): value(n, k) for n in names
+                  for k in ("source", "sink")}
+        assert view._step_span_sync(8, 1.0)
+        ((_span, kids),) = _spans_of("mv")
+        src = kids["span.wait"].attrs["reloads"] + kids[
+            "span.fetch"].attrs["reloads"]
+        assert value(names[0], "source") - before[names[0], "source"] >= src
+        assert value(names[0], "sink") - before[names[0], "sink"] >= 2
+        assert value(names[1], "source") > before[names[1], "source"]
+        assert value(names[2], "sink") - before[names[2], "sink"] >= 2
+        text = REGISTRY.expose_text()
+        assert 'mz_persist_state_reloads_total{shard="source"}' in text
+        assert REGISTRY.get("mz_persist_parts_read_total") is not None or (
+            kids["span.fetch"].attrs.get("parts_read", 0) == 0
+        )
+
+    def test_source_tick_one_record_a_tick(self, tracer):
+        from materialize_tpu.coord.sources import GeneratorSource
+        from materialize_tpu.storage.persist import (
+            MemBlob,
+            MemConsensus,
+            PersistClient,
+        )
+
+        src = GeneratorSource(
+            PersistClient(MemBlob(), MemConsensus()), "gen", "counter",
+            {}, "gen", tick_interval=None,
+        )
+        first = src.t
+        tracer.clear()
+        for _ in range(3):
+            src.tick_once()
+        recs = [r for r in TRACER.records() if r.name == "source.tick"]
+        assert [r.attrs["t"] for r in recs] == [first, first + 1, first + 2]
+        for r in recs:
+            a = r.attrs
+            assert a["source"] == "gen"
+            parts = (a["generate_ms"] + a["encode_ms"] + a["write_ms"]
+                     + a["cas_ms"] + a["compact_ms"])
+            assert parts == pytest.approx(a["work_ms"])
+            assert a["work_ms"] == pytest.approx(r.duration * 1e3)
+            assert min(a["generate_ms"], a["encode_ms"], a["write_ms"],
+                       a["cas_ms"], a["compact_ms"]) >= 0
+            assert a["reloads"] >= 1 and a["state_bytes"] > 0
+            assert a["cas_attempts"] >= 1 and a["rows"] >= 1
+            assert a["slept_ms"] == 0.0  # ticked by hand: no sleep
+        # at 'off' a tick records nothing
+        tracer.set_level("off")
+        tracer.clear()
+        src.tick_once()
+        assert TRACER.records() == []
+
+    def test_recorder_functions_are_host_sync_clean(self):
+        from materialize_tpu.analysis.host_sync import (
+            RECORDER_PATH,
+            _resolve,
+            lint_function,
+        )
+
+        new = {
+            "Tracer.open", "Tracer.within", "Tracer.close",
+            "Tracer.phase", "_Phase.__enter__", "_Phase.add",
+            "_Phase.__exit__", "Tally.mark", "Tally.since",
+            "persist_phase", "MaintainedView._open_span",
+            "MaintainedView._close_span",
+        }
+        assert new <= {qn for _mod, qn in RECORDER_PATH}
+        for mod, qn in RECORDER_PATH:
+            if qn in new:
+                assert lint_function(_resolve(mod, qn), where=qn) == []
+
+
+class TestFlightRecorder:
+    @staticmethod
+    def _environment(tmp_path):
+        from materialize_tpu.server.environmentd import Environment
+
+        return Environment(
+            str(tmp_path / "envd"), n_replicas=1, tick_interval=None,
+            in_process_replicas=True,
+        )
+
+    def test_dump_written_on_the_stop_path(self, tmp_path, monkeypatch):
+        dump_dir = tmp_path / "dump"
+        dump_dir.mkdir()
+        monkeypatch.setenv("MZ_TRACE_DUMP_DIR", str(dump_dir))
+        env = self._environment(tmp_path)
+        try:
+            with TRACER.span("dump.marker", rows=7):
+                pass
+        finally:
+            env.shutdown()
+        path = dump_dir / "spans.jsonl"
+        lines = [json.loads(ln) for ln in path.read_text().splitlines()]
+        marker = [r for r in lines if r["name"] == "dump.marker"]
+        assert marker and marker[-1]["attrs"] == {"rows": 7}
+        assert set(marker[-1]) == {
+            "trace_id", "span_id", "parent_id", "process", "name",
+            "level", "start_us", "duration_us", "attrs",
+        }
+        # ... in the shape scripts/trace_export.py --spans takes
+        sys.path.insert(0, os.path.join(REPO, "scripts"))
+        import trace_export
+
+        out = tmp_path / "spans.chrome.json"
+        assert trace_export.main(
+            ["--spans", str(path), "-o", str(out)]
+        ) == 0
+        chrome = json.loads(out.read_text())
+        assert trace_export.validate_chrome_trace(chrome) == []
+        events = [e for e in chrome["traceEvents"]
+                  if e["name"] == "dump.marker"]
+        assert events and events[-1]["args"]["rows"] == 7
+
+    def test_no_dump_when_the_variable_is_unset(self, tmp_path, monkeypatch):
+        monkeypatch.delenv("MZ_TRACE_DUMP_DIR", raising=False)
+        monkeypatch.chdir(tmp_path)
+        env = self._environment(tmp_path)
+        env.shutdown()
+        assert not any(
+            f == "spans.jsonl" for _r, _d, fs in os.walk(tmp_path) for f in fs
+        )
+
+    def test_mz_trace_spans_serves_attrs(self, tmp_path, tracer):
+        coord, cleanup = _make_coord(tmp_path, with_replica=False)
+        try:
+            sp = tracer.open("span", dataflow="shown", lower=1)
+            with tracer.within(sp):
+                with tracer.phase("span.fetch", reloads=2) as ph:
+                    ph.add(state_bytes=4096)
+            tracer.close(sp, upper=2)
+            rows = coord.execute(
+                "SELECT name, attrs FROM mz_trace_spans "
+                "WHERE name = 'span.fetch' OR name = 'span'"
+            ).rows
+            got = {name: json.loads(attrs) for name, attrs in rows}
+            assert got["span"] == {
+                "dataflow": "shown", "lower": 1, "upper": 2,
+            }
+            assert got["span.fetch"] == {
+                "n": 1, "reloads": 2, "state_bytes": 4096,
+            }
+            # a record without attributes serves the empty text
+            tracer.record("bare", 0.0, 0.0)
+            assert coord.execute(
+                "SELECT attrs FROM mz_trace_spans WHERE name = 'bare'"
+            ).rows == [("",)]
+        finally:
+            coord.shutdown()
+            for fn in cleanup:
+                fn()
+
+    def test_report_frontiers_recorded_only_when_sent(self, tmp_path, tracer):
+        coord, cleanup = _make_coord(tmp_path)
+        try:
+            coord.execute("CREATE TABLE rft (a INT)")
+            coord.execute("INSERT INTO rft VALUES (1)")
+            coord.execute(
+                "CREATE MATERIALIZED VIEW rfmv AS SELECT a FROM rft"
+            )
+            assert coord.execute("SELECT * FROM rfmv").rows == [(1,)]
+            sent = [
+                r for r in TRACER.records()
+                if r.name == "replica.report_frontiers"
+            ]
+            assert sent and all(r.attrs["bytes"] > 0 for r in sent)
+            assert all("spans_shipped" in r.attrs for r in sent)
+            # an idle replica's loop turns send nothing: no new record
+            _time.sleep(0.3)
+            n = len([r for r in TRACER.records()
+                     if r.name == "replica.report_frontiers"])
+            _time.sleep(0.3)
+            assert n == len([r for r in TRACER.records()
+                             if r.name == "replica.report_frontiers"])
+        finally:
+            coord.shutdown()
+            for fn in cleanup:
+                fn()
