@@ -63,6 +63,29 @@ DEFAULT_HOT_PATH = (
         "materialize_tpu.storage.persist.operators",
         "MaintainedView._step_span_pipelined",
     ),
+    # The sinked span's gather for the span after it runs between the
+    # dispatch and the flags readback: a d2h read there would wait for
+    # the device and put the gather back in series with it.
+    (
+        "materialize_tpu.storage.persist.operators",
+        "MaintainedView._prefetch_ticks",
+    ),
+    (
+        "materialize_tpu.storage.persist.operators",
+        "MaintainedView._gather_ready_ticks",
+    ),
+    (
+        "materialize_tpu.storage.persist.operators",
+        "MaintainedView._wait_for_inputs",
+    ),
+    (
+        "materialize_tpu.storage.persist.operators",
+        "ShardSource.fetch_to",
+    ),
+    (
+        "materialize_tpu.storage.persist.operators",
+        "updates_to_batch",
+    ),
     (
         "materialize_tpu.storage.persist.operators",
         "MaintainedView._record_history",

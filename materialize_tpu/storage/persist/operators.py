@@ -566,6 +566,12 @@ class MaintainedView:
         self._inflight_span = None
         self._window_ticks: list = []
         self.span_epoch = 0
+        # Input ticks a sinked span gathered for the span AFTER it
+        # while the device ran (_step_span_sync): [(t, {name: batch},
+        # arrival stamp)], contiguous from `upper` once that span has
+        # committed. The sources' frontiers run ahead of `upper` by
+        # these, so every stepping entry consumes them first.
+        self._kept: list = []
         # Register as the dataflow's span barrier: any df-level state
         # read sequences through sync_spans() automatically.
         self._barrier = _ViewSpanBarrier(self)
@@ -712,6 +718,7 @@ class MaintainedView:
     def expire(self) -> None:
         """Release this view's shard read holds (must be called when the
         view is dropped or replaced, or the holds pin compaction forever)."""
+        self._kept = []
         for s in self.sources.values():
             try:
                 s.reader.expire()
@@ -1005,11 +1012,12 @@ class MaintainedView:
         frontier-joined progress. Returns False if the inputs did not
         advance within the timeout."""
         self.sync_spans()
+        prefetched = 1 if self._kept else 0
         span = self._open_span(self.upper)
         with TRACER.within(span):
             if not self._step_tick(timeout):
                 return False
-        self._close_span(span, 1)
+        self._close_span(span, 1, prefetched=prefetched)
         return True
 
     def _open_span(self, lower: int):
@@ -1021,10 +1029,15 @@ class MaintainedView:
             lower=lower,
         )
 
-    def _close_span(self, span, ticks: int, replayed: bool = False):
+    def _close_span(
+        self, span, ticks: int, replayed: bool = False,
+        prefetched: int = 0,
+    ):
+        """``prefetched``: how many of the span's ticks the span before
+        it had gathered (``_kept``) while the device ran."""
         TRACER.close(
             span, upper=self._upper, ticks=ticks, epoch=self.span_epoch,
-            replayed=replayed,
+            replayed=replayed, prefetched_ticks=prefetched,
         )
 
     def _wait_for_inputs(self, frontier: int, timeout: float):
@@ -1066,31 +1079,18 @@ class MaintainedView:
             self._dispatched = 1
             self._record_freshness(1, arrived)
             return True
-        target = self._wait_for_inputs(lower, timeout)  # > lower
-        if target is None:
+        ticks, _ = self._take_ready_ticks(lower, 1, timeout)
+        if not ticks:
             return False
-        # One timestamp per steady-state step: chunk boundaries are then
-        # DETERMINISTIC across active-active replicas, so racing sink
-        # appends are byte-identical and losing a race is always safe.
-        # (Backlogs are collapsed by hydrate's snapshot, not here; a
-        # correction-buffer sink, correction_v2.rs, would lift this.)
-        target = min(target, lower + 1)
-        polled = {
-            name: s.fetch_to(target) for name, s in self.sources.items()
-        }
-        # Freshness arrival stamp: taken AFTER the fetch completes, so
-        # the recorded lag is the maintenance delay this view adds, not
-        # time spent waiting for input to exist (coord/freshness.py).
-        arrived = _time.monotonic()
-        t = target - 1
+        ((t, polled, arrived),) = ticks
         if self._sink_finalizes:
             self._pre_step_multisets = (
                 self.df.capture_basic_multisets()
             )
         self.df.time = t
         self._commit_tick(t, self.df.step(polled), lower)
-        self._dispatched = target
-        self._record_freshness(target, arrived)
+        self._dispatched = t + 1
+        self._record_freshness(t + 1, arrived)
         return True
 
     # -- pipelined span stepping (ISSUE 7: the async control plane) --------
@@ -1099,13 +1099,18 @@ class MaintainedView:
     # synchronous overflow check) and leaves the device idle while the
     # host fetches the next chunk. step_span() processes up to
     # span_max_ticks READY micro-batches as one deferred dispatch
-    # train and commits them with ONE boundary readback — overlapped,
-    # for index (sink-less) views, with the NEXT span's ingest and
-    # dispatch: the commit readback for span K runs after span K+1 is
-    # already queued on device (double buffering, at most one span in
-    # flight ahead of the committed frontier). Peeks, AS OF reads, and
-    # subscriber snapshots sequence against COMMITTED span boundaries
-    # via sync_spans() — they can never observe a half-applied carry.
+    # train and commits them with ONE boundary readback. What overlaps
+    # with the device's work on span K differs by the view's kind.
+    # Index (sink-less) views: span K+1's ingest AND dispatch — the
+    # commit readback for span K runs after span K+1 is already queued
+    # on device (double buffering, at most one span in flight ahead of
+    # the committed frontier). Sinked views: span K+1's ingest only
+    # (wait, fetch, upload: _prefetch_ticks) — K's flags are read and
+    # its deltas appended before K+1 is dispatched, so commit order
+    # and what a rollback undoes are the per-tick path's. Peeks, AS OF
+    # reads, and subscriber snapshots sequence against COMMITTED span
+    # boundaries via sync_spans() — they can never observe a
+    # half-applied carry.
 
     # -- donation decision (ISSUE 8: the prover-gated span train) ----------
 
@@ -1169,12 +1174,13 @@ class MaintainedView:
     def step_span(
         self, max_ticks: int | None = None, timeout: float = 0.0
     ) -> bool:
-        """Span-batched stepping. Sinked views commit synchronously at
-        the span boundary (durability needs the deltas host-side
-        anyway); index views pipeline (deferred commit). Views the
-        span protocol cannot cover — pure constants, basic-aggregate
-        sinks (per-step multiset captures), SPMD dataflows (host
-        gathers per tick) — fall back to the per-tick step."""
+        """Span-batched stepping. Sinked and SPMD views commit
+        synchronously at the span boundary (durability needs the
+        deltas host-side, SPMD gathers them per tick) and gather the
+        next span's inputs while the device runs this one; index views
+        pipeline (deferred commit). Views the span protocol cannot
+        cover — pure constants, basic-aggregate sinks (per-step
+        multiset captures) — fall back to the per-tick step."""
         from ...render.dataflow import Dataflow as _SingleDevice
         from ...utils.dyncfg import COMPUTE_CONFIGS, SPAN_MAX_TICKS
 
@@ -1196,9 +1202,10 @@ class MaintainedView:
         self, lower: int, max_ticks: int, timeout: float
     ) -> list:
         """Up to max_ticks consecutive one-timestamp input chunks
-        beyond ``lower``: [(t, {name: batch})]. Only the FIRST tick
-        may wait ``timeout``; later ticks take whatever is already
-        ready (the span covers the backlog, it never stalls on it)."""
+        beyond ``lower``: [(t, {name: batch}, arrival stamp)]. Only
+        the FIRST tick may wait ``timeout``; later ticks take whatever
+        is already ready (the span covers the backlog, it never stalls
+        on it)."""
         ticks: list = []
         for k in range(max_ticks):
             want = lower + k
@@ -1207,45 +1214,86 @@ class MaintainedView:
             )
             if target is None:
                 break
+            # One timestamp per steady-state step: chunk boundaries are
+            # then DETERMINISTIC across active-active replicas, so
+            # racing sink appends are byte-identical and losing a race
+            # is always safe. (Backlogs are collapsed by hydrate's
+            # snapshot, not here; a correction-buffer sink,
+            # correction_v2.rs, would lift this.)
             target = min(target, want + 1)
             polled = {
                 name: s.fetch_to(target)
                 for name, s in self.sources.items()
             }
-            ticks.append((target - 1, polled))
+            # Freshness arrival stamp: taken AFTER the fetch completes,
+            # so the recorded lag is the maintenance delay this view
+            # adds, not time spent waiting for input to exist
+            # (coord/freshness.py). It stays with the tick: one kept
+            # for the next span is no fresher for having waited here.
+            ticks.append((target - 1, polled, _time.monotonic()))
         return ticks
 
+    def _take_ready_ticks(
+        self, lower: int, max_ticks: int, timeout: float
+    ) -> tuple:
+        """The next span's ticks: what the span before it kept, topped
+        up from the sources to ``max_ticks`` (which wait ``timeout``
+        only when nothing is kept), and how many of them were kept."""
+        ticks = self._kept[:max_ticks]
+        self._kept = self._kept[max_ticks:]
+        kept = len(ticks)
+        assert not ticks or ticks[0][0] == lower, (ticks[0][0], lower)
+        ticks += self._gather_ready_ticks(
+            lower + kept, max_ticks - kept, 0.0 if kept else timeout
+        )
+        return ticks, kept
+
+    def _prefetch_ticks(self, lower: int, max_ticks: int) -> None:
+        """Gather the ticks beyond ``lower`` that are ready NOW and keep
+        them for the next span. Called with this span dispatched and
+        its flags unread: reading source shards and putting batches on
+        the device touches neither the carry nor the sink, and the
+        host would otherwise only wait for the device. Never waits."""
+        have = len(self._kept)
+        self._kept += self._gather_ready_ticks(
+            lower + have, max_ticks - have, 0.0
+        )
+
     def _step_span_sync(self, max_ticks: int, timeout: float) -> bool:
-        """Sinked span: dispatch every ready tick asynchronously, ONE
+        """Sinked span: dispatch every ready tick asynchronously,
+        gather the next span's ready ticks while the device works, ONE
         flags readback (check_flags — replays on overflow), then the
         per-tick durable appends from validated deltas."""
         self.sync_spans()
         lower = self.upper
         span = self._open_span(lower)
         with TRACER.within(span):
-            ticks = self._gather_ready_ticks(lower, max_ticks, timeout)
+            ticks, prefetched = self._take_ready_ticks(
+                lower, max_ticks, timeout
+            )
             if not ticks:
                 return False
-            arrived = _time.monotonic()
             if self.df.time != ticks[0][0]:
                 self.df.time = ticks[0][0]
             deltas = self.df.run_steps(
-                [inp for _, inp in ticks],
+                [inp for _, inp, _ in ticks],
                 defer_check=True,
                 donate=self._span_donation(),
             )
+            # The device runs this span; the host gathers the next.
+            self._prefetch_ticks(ticks[-1][0] + 1, max_ticks)
             with TRACER.phase("span.readback"):
                 replayed = self.df.check_flags()
             if replayed:
                 deltas = self.df.replayed_deltas
             lo = lower
-            for (t, _), out in zip(ticks, deltas):
+            for (t, _, _), out in zip(ticks, deltas):
                 self._commit_tick(t, out, lo)
                 lo = t + 1
             self._dispatched = lo
             self.span_epoch += 1
-            self._record_freshness(lo, arrived)
-        self._close_span(span, len(ticks), replayed)
+            self._record_freshness(lo, ticks[-1][2])
+        self._close_span(span, len(ticks), replayed, prefetched)
         return True
 
     def _step_span_pipelined(
@@ -1288,14 +1336,14 @@ class MaintainedView:
         try:
             with TRACER.within(span):
                 deltas = self.df.run_steps(
-                    [inp for _, inp in ticks],
+                    [inp for _, inp, _ in ticks],
                     defer_check=True,
                     donate=self._span_donation(),
                 )
         finally:
             self._barrier.in_dispatch = False
         snap = self.df.flags_snapshot()
-        entries = [(t, out) for (t, _), out in zip(ticks, deltas)]
+        entries = [(t, out) for (t, _, _), out in zip(ticks, deltas)]
         self._window_ticks.extend(entries)
         prev = self._inflight_span
         self._inflight_span = (

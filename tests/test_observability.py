@@ -893,6 +893,7 @@ class TestMaintenancePhases:
         assert span.attrs["ticks"] == 3
         assert span.attrs["epoch"] == view.span_epoch
         assert span.attrs["replayed"] is False
+        assert span.attrs["prefetched_ticks"] == 0
         assert set(kids) == PHASES_SINKED
         assert sum(k.duration for k in kids.values()) <= span.duration
         for k in kids.values():
@@ -926,10 +927,31 @@ class TestMaintenancePhases:
         assert len(spans) == 1
         span, kids = spans[0]
         assert span.attrs["upper"] == view.upper
+        assert span.attrs["prefetched_ticks"] == 0
         # an index view appends nothing; every other phase is there
         want = PHASES_SINKED - ({"span.append"} if sink is None else set())
         assert set(kids) == want
         assert names == want | {"span"}
+
+    def test_span_times_the_gather_for_the_span_after_it(self, tracer):
+        view, w = _kv_view(2)
+        _append_ticks(w, 2, 6)
+        tracer.clear()
+        assert view._step_span_sync(3, 1.0)
+        assert view._step_span_sync(3, 1.0)
+        (first, kids1), (second, kids2) = _spans_of("mv")
+        assert (first.attrs["ticks"], second.attrs["ticks"]) == (3, 3)
+        assert first.attrs["prefetched_ticks"] == 0
+        assert second.attrs["prefetched_ticks"] == 3
+        # the first span fetched and uploaded its own three one-row
+        # ticks and, with its steps dispatched, the second's; the
+        # second found nothing ready beyond its own
+        assert kids1["span.fetch"].attrs["rows"] == 6
+        assert kids1["span.fetch"].attrs["n"] == 6
+        assert "span.fetch" not in kids2
+        assert kids2["span.wait"].attrs["n"] == 1
+        assert kids2["span.append"].attrs["rows"] == 3
+        assert view.upper == 8 and view._kept == []
 
     def test_fetch_counts_grow_with_the_shards_age(self, tracer):
         view, w = _kv_view(4)
