@@ -161,6 +161,41 @@ class Constant(RelationExpr):
     def schema(self):
         return self._schema
 
+    def __reduce__(self):
+        # string fields are codes of this process's dictionary: the
+        # strings travel (see expr/scalar.py Literal.__reduce__)
+        from ..repr.schema import GLOBAL_DICT, ColumnType
+
+        at = tuple(
+            i for i, c in enumerate(self._schema.columns)
+            if c.ctype is ColumnType.STRING
+        )
+        if at and self.rows:
+            # an undecodable code is a KeyError here: it cannot travel
+            rows = _map_fields(self.rows, at, GLOBAL_DICT.decode)
+            return (_string_constant, (rows, self._schema, at))
+        return (Constant, (self.rows, self._schema))
+
+
+def _map_fields(rows: tuple, at: tuple, fn) -> tuple:
+    """``rows`` with ``fn`` applied to the non-NULL fields at ``at``."""
+    return tuple(
+        (
+            tuple(
+                fn(v) if i in at and v is not None else v
+                for i, v in enumerate(row)
+            ),
+            diff,
+        )
+        for row, diff in rows
+    )
+
+
+def _string_constant(rows: tuple, schema: Schema, at: tuple) -> "Constant":
+    from ..repr.schema import GLOBAL_DICT
+
+    return Constant(_map_fields(rows, at, GLOBAL_DICT.encode), schema)
+
 
 @dataclass(frozen=True)
 class Get(RelationExpr):

@@ -114,6 +114,31 @@ class Literal(ScalarExpr):
     def typ(self, schema):
         return Column("literal", self.ctype, self.value is None, self.scale)
 
+    def __reduce__(self):
+        # A STRING literal's value is a code of THIS process's
+        # dictionary (repr/schema.py); a plan is pickled to reach a
+        # replica, which may be another process with a dictionary of
+        # its own (coord/protocol.py). The string travels, and is
+        # encoded again where it lands: `c_mktsegment = 'BUILDING'`
+        # compared another string's code, or none, on a subprocess
+        # replica before PR 30.
+        if self.ctype is ColumnType.STRING and self.value is not None:
+            from ..repr.schema import GLOBAL_DICT
+
+            # a code no string was given cannot travel: KeyError here,
+            # never another process's string for the same number
+            return (
+                _string_literal,
+                (GLOBAL_DICT.decode(self.value), self.scale),
+            )
+        return (Literal, (self.value, self.ctype, self.scale))
+
+
+def _string_literal(text: str, scale: int = 0) -> Literal:
+    from ..repr.schema import GLOBAL_DICT
+
+    return Literal(GLOBAL_DICT.encode(text), ColumnType.STRING, scale)
+
 
 class UnaryFunc:
     NOT = "not"

@@ -13,10 +13,48 @@ that sorts valid rows first.
 
 from __future__ import annotations
 
+from contextlib import contextmanager
+
 import jax
 import jax.numpy as jnp
 
 from ..repr.batch import Batch
+
+
+# The TPU compiler's own sort is cheap to compile up to 2^13 rows (16 s
+# for three u64 keys and an index, described v5e) and costly beyond:
+# 63 s at 2^14, 290 s at 2^15, 349 s at 2^17, growing with the number
+# of key operands (1,309 s for nine keys at 2^17, batched or not): the
+# compile-time cliff of ROADMAP A3. Above ``SORT_DIRECT_MAX`` rows
+# ``sort_perm`` sorts blocks of ``SORT_BLOCK`` rows in a loop (one
+# small sort compiled once) and merges them pairwise by binary search:
+# 7.6 s at 2^17 rows. The merges gather row by row and are slow to RUN
+# on the chip (Q3's ticks took 1.6 s each with three such sorts of
+# 49,152 mostly empty rows), so a batch whose valid rows fit one block
+# sorts that block alone. Programs whose sorts all fit
+# ``SORT_DIRECT_MAX`` are what they were, and so is every program on
+# another backend (XLA:CPU's sort compiles fast and runs faster than
+# the merges: the suite's Q9 and Q21 took 8 minutes each through them).
+SORT_DIRECT_MAX = 8192
+SORT_BLOCK = 2048
+
+# Whether the program being traced sorts large batches in blocks: a
+# dataflow whose snapshot forced snapshot-size join arrangements
+# (``presize_for_snapshot``) says so while it traces its step. The
+# blocked sorts' code is large (Q15's hydration program grew by 241 MB
+# and its warm set-up by 32 s with them, past ``setup_s``'s bound), so
+# a program that compiles without them keeps the compiler's sort.
+_in_blocks = False
+
+
+@contextmanager
+def sorting_in_blocks(on: bool):
+    global _in_blocks
+    was, _in_blocks = _in_blocks, on
+    try:
+        yield
+    finally:
+        _in_blocks = was
 
 
 def sort_perm(lanes, count, capacity: int) -> jnp.ndarray:
@@ -24,9 +62,85 @@ def sort_perm(lanes, count, capacity: int) -> jnp.ndarray:
     padding rows last. Stable."""
     idx = jnp.arange(capacity, dtype=jnp.int32)
     invalid = (idx >= count).astype(jnp.uint64)  # valid=0 sorts first
-    operands = [invalid] + [l for l in lanes] + [idx]
-    out = jax.lax.sort(operands, num_keys=len(operands) - 1, is_stable=True)
-    return out[-1]
+    keys = [invalid] + [l for l in lanes]
+    # The cliff is the TPU compiler's; elsewhere its sort is the fast one
+    if (
+        capacity <= SORT_DIRECT_MAX
+        or not _in_blocks
+        or jax.default_backend() != "tpu"
+    ):
+        out = jax.lax.sort(keys + [idx], num_keys=len(keys), is_stable=True)
+        return out[-1]
+    return _large_sort_perm(keys, idx, count, capacity)
+
+
+def _large_sort_perm(keys: list, idx, count, capacity: int) -> jnp.ndarray:
+    """``sort_perm`` above ``SORT_DIRECT_MAX`` rows on a TPU."""
+
+    def head():
+        # the valid rows are a prefix: all of them lie in one block,
+        # the rows after it are padding and stay where they are
+        out = jax.lax.sort(
+            [k[:SORT_BLOCK] for k in keys] + [idx[:SORT_BLOCK]],
+            num_keys=len(keys), is_stable=True,
+        )
+        return jnp.concatenate([out[-1], idx[SORT_BLOCK:]])
+
+    # A large batch with few valid rows is every tick's case (a join
+    # site's output at its tier): it pays for the rows it holds.
+    return jax.lax.cond(
+        count <= SORT_BLOCK, head,
+        lambda: _blocked_sort_perm(keys, capacity),
+    )
+
+
+def _merge_sorted_pair(xk, xi, yk, yi):
+    """Two sorted runs of equal length (key lanes, row index) into
+    one; on equal keys the rows of the first run come first."""
+    from .search import lex_searchsorted
+
+    n = xi.shape[0]
+    at = jnp.arange(n, dtype=jnp.int32)
+    # a row's place: its own rank plus the other run's rows before it
+    px = at + lex_searchsorted(yk, n, xk, "left")
+    py = at + lex_searchsorted(xk, n, yk, "right")
+    keys = tuple(
+        jnp.zeros(2 * n, x.dtype).at[px].set(x).at[py].set(y)
+        for x, y in zip(xk, yk)
+    )
+    return keys, jnp.zeros(2 * n, xi.dtype).at[px].set(xi).at[py].set(yi)
+
+
+def _blocked_sort_perm(keys: list, capacity: int) -> jnp.ndarray:
+    """``sort_perm`` for many rows: ``keys[0]`` is the validity lane.
+    Rows are padded to a power-of-two number of blocks with rows that
+    sort after every real one, each block is sorted by the compiler's
+    sort, and sorted blocks are merged pairwise, level by level."""
+    blocks = 1
+    while blocks * SORT_BLOCK < capacity:
+        blocks *= 2
+    pad = blocks * SORT_BLOCK - capacity
+    idx = jnp.arange(blocks * SORT_BLOCK, dtype=jnp.int32)
+    keys = tuple(
+        jnp.pad(k, (0, pad), constant_values=2 if j == 0 else 0).reshape(
+            blocks, SORT_BLOCK
+        )
+        for j, k in enumerate(keys)
+    )
+
+    def sort_block(block):
+        out = jax.lax.sort(block, num_keys=len(block) - 1, is_stable=True)
+        return tuple(out[:-1]), out[-1]
+
+    keys, idx = jax.lax.map(
+        sort_block, keys + (idx.reshape(blocks, SORT_BLOCK),)
+    )
+    while idx.shape[0] > 1:
+        keys, idx = jax.vmap(_merge_sorted_pair)(
+            tuple(k[0::2] for k in keys), idx[0::2],
+            tuple(k[1::2] for k in keys), idx[1::2],
+        )
+    return idx[0][:capacity]
 
 
 def apply_perm(batch: Batch, perm: jnp.ndarray) -> Batch:

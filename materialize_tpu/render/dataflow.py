@@ -148,6 +148,14 @@ class _RenderContext:
         )
         self.slots: list[_StateSlot] = []
         self.operators: list = []  # parallel to slots: op configs
+        # (slot, part) -> (name, join site): the ONE Get that feeds a
+        # join arrangement through stateless operators only, whose
+        # rows bound the arrangement's, and the site its rows are
+        # probed at (presize_for_snapshot).
+        self.source_fed: dict = {}
+        # Set once presize_for_snapshot has grown an arrangement: the
+        # step's large sorts are then traced in blocks (ops/sort.py).
+        self.sort_in_blocks = False
         self.num_shards = num_shards
         self.axis_name = axis_name
         # Per-destination send-slot capacity for exchanges; grown on
@@ -208,6 +216,14 @@ class _RenderContext:
         ovf = dict(ovf)
         ovf[("x", site)] = overflow
         return routed, ovf
+
+
+def _sole_get(expr: mir.RelationExpr) -> str | None:
+    """The name of the one Get under Filter/Map/Project only: what
+    reaches the consumer is at most that collection's rows."""
+    while isinstance(expr, (mir.Filter, mir.Map, mir.Project)):
+        expr = expr.input
+    return expr.name if isinstance(expr, mir.Get) else None
 
 
 def _build(expr: mir.RelationExpr, ctx: _RenderContext):
@@ -513,6 +529,10 @@ def _build_join_delta(expr: mir.Join, ctx: _RenderContext):
     )
     jsite = ctx.new_join_site()
     inners = [_build(i, ctx) for i in expr.inputs]
+    for p, (j, _key) in enumerate(op.arr_specs):
+        fed_by = _sole_get(expr.inputs[j])
+        if fed_by is not None:
+            ctx.source_fed[(slot, p)] = (fed_by, jsite)
     ex_sites = {}
     for p in range(len(op.arr_specs)):
         ex_sites[("ins", p)] = ctx.new_exchange_site()
@@ -583,6 +603,12 @@ def _build_join_linear(expr: mir.Join, ctx: _RenderContext):
         lsite = ctx.new_exchange_site()
         rsite = ctx.new_exchange_site()
         stages.append((op, slot, jsite, lsite, rsite, left_key, right_key))
+        # part 0 arranges the accumulated left side (an input only at
+        # the first stage), part 1 the input this stage brings in
+        for p, j in ((0, 0), (1, i)) if i == 1 else ((1, i),):
+            fed_by = _sole_get(expr.inputs[j])
+            if fed_by is not None:
+                ctx.source_fed[(slot, p)] = (fed_by, jsite)
         acc_schema = op.out_schema
     if len(all_consumed) != len(expr.equivalences):
         # An intra-input equality (all members in one input) would be
@@ -1112,6 +1138,69 @@ class _DataflowBase:
             [jnp.asarray(ovf[k]).astype(jnp.bool_).reshape(()) for k in keys]
         )
 
+    # state_capacity_bytes()'s value, until a tier is regrown
+    _reserved_bytes: int | None = None
+
+    def state_capacity_bytes(self) -> int:
+        """Bytes of device memory the operator state and the output
+        spine RESERVE: capacities x stored row widths (columns, time,
+        diff, cached sort lanes), whatever the live row counts. Shapes
+        off the avals, never a device read (safe at span close,
+        analysis/host_sync.py); known at render and kept until
+        ``_grow_for`` regrows a tier."""
+        if self._reserved_bytes is None:
+            self._reserved_bytes = device_nbytes((self.states, self.output))
+        return self._reserved_bytes
+
+    def presize_for_snapshot(self, rows: dict) -> int:
+        """Before the one step that hydrates a snapshot: every join
+        arrangement that ONE input feeds through stateless operators
+        (``source_fed``) goes, in one hop, to the tier that holds all
+        ``rows[input]`` rows: the ingest tier the step's batch passes
+        through and every run it is folded into (ROADMAP A2). The
+        doubling ladder would get there one overflow and one compile
+        of the hydration-size step program at a time; this bound is at
+        most the rows the filters in between remove too large, and
+        depends on the snapshot's size alone, so every run of a
+        deployment compiles the same programs. A tier that holds its
+        input already is left as it is.
+
+        What such an arrangement's rows match at their join site has
+        no bound in the inputs' sizes. The site's tier starts where
+        every snapshot-size delta is cut before it meets a sort
+        (``out_delta_cap``), if the input is that large, and keeps the
+        ladder from there; as do the states that a join or a reduce
+        feeds.
+
+        How many arrangements were grown."""
+        from ..plan.decisions import quantize_cap
+
+        grown = 0
+        for (slot, part), (name, jsite) in sorted(
+            self._ctx.source_fed.items()
+        ):
+            if name not in rows:
+                continue
+            target = quantize_cap(rows[name])
+            spine = self.states[slot][part]
+            # the ingest tier (the slot ring, else run 0), then every
+            # run a fold targets
+            runs = list(enumerate(spine.runs_b))
+            tiers = [("tail", (spine.slots or spine.runs_b)[0])] + (
+                runs if spine.slots else runs[1:]
+            )
+            small = [w for w, b in tiers if b.capacity < target]
+            for which in small:
+                self._grow_for(("state", slot, (part, which)), target)
+            grown += bool(small)
+            self._ctx.sort_in_blocks |= bool(small)
+            probe = min(target, self._ctx.out_delta_cap)
+            if self._ctx.join_caps[jsite] < probe:
+                self._grow_for(("join", jsite), probe)
+        if grown:
+            self._remake_jit()  # the programs' key says how they sort
+        return grown
+
     def _static_tiers(self) -> str:
         """The capacity tiers this dataflow's programs bake in at trace
         time (grown by ``_grow_for`` + ``_remake_jit``, visible in no
@@ -1119,6 +1208,7 @@ class _DataflowBase:
         c = self._ctx
         return repr(
             (c.join_caps, c.slot_cap, c.letrec_caps, c.out_delta_cap)
+            + (("sort_in_blocks",) if c.sort_in_blocks else ())
         )
 
     def _grow_for(self, key, target: int | None = None) -> None:
@@ -1145,6 +1235,7 @@ class _DataflowBase:
                 "capacity tiers doubled after a device overflow flag "
                 "(each rolls back and replays the step or span)",
             ).inc()
+        self._reserved_bytes = None
         if key[0] == "state":
             _, slot, part = key
             parts = list(self.states[slot])
@@ -1157,7 +1248,9 @@ class _DataflowBase:
         elif key[0] == "out":
             self.output = self._grow_spine(self.output, key[1], target)
         elif key[0] == "join":
-            self._ctx.join_caps[key[1]] *= 2
+            self._ctx.join_caps[key[1]] = (
+                target or self._ctx.join_caps[key[1]] * 2
+            )
             self._remake_jit()
         elif key[0] == "x":
             self._ctx.slot_cap *= 2
@@ -2429,17 +2522,19 @@ class Dataflow(_DataflowBase):
 
     def _step_core_inner(self, states, output, err_output, inputs, time):
         from ..expr import errors as _errors
+        from ..ops.sort import sorting_in_blocks
 
-        with _errors.step_scope() as err_parts:
-            out, upd, ovf = self._run(states, inputs, time)
+        with sorting_in_blocks(self._ctx.sort_in_blocks):
+            with _errors.step_scope() as err_parts:
+                out, upd, ovf = self._run(states, inputs, time)
+            # The delta is what sinks/subscribers see: consolidate so
+            # union-produced +/- pairs at the same time cancel.
+            out = consolidate(out, include_time=True)
+            out, shrink_ovf = shrink(out, self._ctx.out_delta_cap)
+            new_output, out_ovf = insert_tail(output, out)
         new_states = list(states)
         for k, v in upd.items():
             new_states[k] = v
-        # The delta is what sinks/subscribers see: consolidate so
-        # union-produced +/- pairs at the same time cancel.
-        out = consolidate(out, include_time=True)
-        out, shrink_ovf = shrink(out, self._ctx.out_delta_cap)
-        new_output, out_ovf = insert_tail(output, out)
         ovf = dict(ovf)
         ovf[("outd",)] = shrink_ovf
         ovf[("out", "tail")] = out_ovf

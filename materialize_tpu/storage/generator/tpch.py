@@ -99,6 +99,7 @@ ORDERS_SCHEMA = Schema(
         Column("o_totalprice", ColumnType.DECIMAL, scale=2),
         Column("o_orderdate", ColumnType.DATE),
         Column("o_orderpriority", ColumnType.STRING),
+        Column("o_shippriority", ColumnType.INT32),
     ]
 )
 
@@ -131,6 +132,7 @@ CUSTOMER_SCHEMA = Schema(
         Column("c_custkey", ColumnType.INT64),
         Column("c_nationkey", ColumnType.INT64),
         Column("c_name", ColumnType.STRING),
+        Column("c_mktsegment", ColumnType.STRING),
     ]
 )
 
@@ -157,6 +159,8 @@ _NATIONS = [
     "UNITED STATES",
 ]
 _REGIONS = ["AFRICA", "AMERICA", "ASIA", "EUROPE", "MIDDLE EAST"]
+# TPC-H clause 4.2.3: c_mktsegment is one of these five, uniform.
+_SEGMENTS = ["AUTOMOBILE", "BUILDING", "FURNITURE", "MACHINERY", "HOUSEHOLD"]
 _NATION_REGION = [0, 1, 1, 1, 4, 0, 3, 3, 2, 2, 4, 4, 2, 4, 0, 0, 0, 1,
                   2, 3, 4, 2, 3, 3, 1]
 
@@ -293,6 +297,8 @@ class TpchGenerator:
             totalprice.astype(np.int64),
             orderdate.astype(np.int32),
             prio,
+            # TPC-H clause 4.2.3: o_shippriority is 0 on every order
+            np.zeros(len(orderkeys), np.int32),
         ]
 
     # -- static dimension tables -------------------------------------------
@@ -331,7 +337,15 @@ class TpchGenerator:
         names = GLOBAL_DICT.encode_many(
             [f"Customer#{k:09d}" for k in keys]
         )
-        return [keys, nation.astype(np.int64), names]
+        # a counter hash of the key, not a draw from `rng`: the columns
+        # above stay what they were before this one was added
+        segment = GLOBAL_DICT.encode_many(_SEGMENTS)[
+            self._draw(
+                0, len(_SEGMENTS), np.uint64(self.seed * 1_000_003),
+                keys.astype(np.uint64), 31,
+            )
+        ].astype(np.int64)
+        return [keys, nation.astype(np.int64), names, segment]
 
     def nation_table(self):
         names = GLOBAL_DICT.encode_many(_NATIONS)

@@ -28,6 +28,7 @@ from ...arrangement.spine import device_nbytes
 from ...render.dataflow import Dataflow
 from ...repr.batch import Batch, capacity_tier
 from ...repr.schema import Schema
+from ...utils.metrics import REGISTRY
 from ...utils.trace import TRACER
 from .client import PersistClient, ReadHandle, WriteHandle
 from .machine import TALLY, Fenced, UpperMismatch
@@ -572,6 +573,13 @@ class MaintainedView:
         # committed. The sources' frontiers run ahead of `upper` by
         # these, so every stepping entry consumes them first.
         self._kept: list = []
+        self._capacity_gauge = REGISTRY.get_or_create(
+            "gauge_vec", "mz_dataflow_state_capacity_bytes",
+            "bytes of device memory a maintained view's operator state "
+            "and output spine reserve (capacities x stored row widths), "
+            "as of its last committed span",
+            label="dataflow",
+        )
         # Register as the dataflow's span barrier: any df-level state
         # read sequences through sync_spans() automatically.
         self._barrier = _ViewSpanBarrier(self)
@@ -780,6 +788,15 @@ class MaintainedView:
             for name, s in self.sources.items():
                 b, _ = s.snapshot(as_of)
                 inputs[name] = b
+            # The one step is compiled for these batches' capacities:
+            # an arrangement that one of them feeds is given that tier
+            # now, not one overflow and one compile at a time.
+            caps = {name: b.capacity for name, b in inputs.items()}
+            with TRACER.phase("hydrate.presize") as ph:
+                ph.add(
+                    arrangements=self.df.presize_for_snapshot(caps),
+                    snapshot_capacity=sum(caps.values()),
+                )
             self.df.time = as_of
             self.df.step(inputs)
             out = self.result_batch()
@@ -1034,10 +1051,17 @@ class MaintainedView:
         prefetched: int = 0,
     ):
         """``prefetched``: how many of the span's ticks the span before
-        it had gathered (``_kept``) while the device ran."""
+        it had gathered (``_kept``) while the device ran. The bytes
+        the view's operator state and output spine reserve on the
+        device ride along (shapes, no device read)."""
+        reserved = self.df.state_capacity_bytes()
         TRACER.close(
             span, upper=self._upper, ticks=ticks, epoch=self.span_epoch,
             replayed=replayed, prefetched_ticks=prefetched,
+            state_capacity_bytes=reserved,
+        )
+        self._capacity_gauge.set(
+            getattr(self.df, "name", "") or "df", reserved
         )
 
     def _wait_for_inputs(self, frontier: int, timeout: float):

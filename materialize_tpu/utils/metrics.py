@@ -105,6 +105,15 @@ class Gauge(_Metric):
             return [(self.name, {}, self._value)]
 
 
+class GaugeVec(CounterVec):
+    """A gauge family with one label: one sample a label value."""
+
+    kind = "gauge"
+
+    def set(self, label_value: str, v: float) -> None:
+        self._values[label_value] = float(v)
+
+
 class Histogram(_Metric):
     kind = "histogram"
 
@@ -251,6 +260,9 @@ class MetricsRegistry:
 
     def gauge(self, name, help_="") -> Gauge:
         return Gauge(name, help_, registry=self)
+
+    def gauge_vec(self, name, help_="", label="") -> GaugeVec:
+        return GaugeVec(name, help_, label=label, registry=self)
 
     def histogram(self, name, help_="", buckets=None) -> Histogram:
         return Histogram(name, help_, buckets=buckets, registry=self)

@@ -117,32 +117,38 @@ def q9_mir() -> mir.RelationExpr:
     li, pt, sp = LINEITEM_SCHEMA, PART_SCHEMA, SUPPLIER_SCHEMA
     ps, od, na = PARTSUPP_SCHEMA, ORDERS_SCHEMA, NATION_SCHEMA
     i = li.index_of
-    # Global column offsets: lineitem 0..12, part 13..15, supplier 16..18,
-    # partsupp 19..21, orders 22..27, nation 28..30.
+    # Global column offsets, from the schemas' lengths (a column
+    # appended to one relation shifts every relation after it).
+    inputs = (("lineitem", li), ("part", pt), ("supplier", sp),
+              ("partsupp", ps), ("orders", od), ("nation", na))
+    base, at = {}, 0
+    for name, sch in inputs:
+        base[name] = at
+        at += sch.arity
+
+    def g(name: str, column: str):
+        return col(base[name] + dict(inputs)[name].index_of(column))
+
     joined = mir.Join(
-        (
-            mir.Get("lineitem", li),
-            mir.Get("part", pt),
-            mir.Get("supplier", sp),
-            mir.Get("partsupp", ps),
-            mir.Get("orders", od),
-            mir.Get("nation", na),
-        ),
+        tuple(mir.Get(name, sch) for name, sch in inputs),
         equivalences=(
-            (col(i("l_suppkey")), col(16), col(20)),  # = s_suppkey = ps_suppkey
-            (col(i("l_partkey")), col(13), col(19)),  # = p_partkey = ps_partkey
-            (col(i("l_orderkey")), col(22)),          # = o_orderkey
-            (col(17), col(28)),                       # s_nationkey = n_nationkey
+            (col(i("l_suppkey")), g("supplier", "s_suppkey"),
+             g("partsupp", "ps_suppkey")),
+            (col(i("l_partkey")), g("part", "p_partkey"),
+             g("partsupp", "ps_partkey")),
+            (col(i("l_orderkey")), g("orders", "o_orderkey")),
+            (g("supplier", "s_nationkey"), g("nation", "n_nationkey")),
         ),
     )
     one = lit(100, ColumnType.DECIMAL, 2)  # 1.00
-    amount = col(i("l_extendedprice")) * (one - col(i("l_discount"))) - col(
-        21
+    amount = col(i("l_extendedprice")) * (one - col(i("l_discount"))) - g(
+        "partsupp", "ps_supplycost"
     ) * col(i("l_quantity"))  # scale 4
-    o_year = CallUnary(UnaryFunc.EXTRACT_YEAR, col(26))
+    o_year = CallUnary(UnaryFunc.EXTRACT_YEAR, g("orders", "o_orderdate"))
+    n_name = base["nation"] + na.index_of("n_name")
     return (
-        joined.map([amount, o_year])  # -> cols 31, 32
-        .project([30, 32, 31])  # n_name, o_year, amount
+        joined.map([amount, o_year])  # -> cols `at`, `at + 1`
+        .project([n_name, at + 1, at])  # n_name, o_year, amount
         .reduce(
             (0, 1), (AggregateExpr(AggregateFunc.SUM_INT, col(2)),)
         )

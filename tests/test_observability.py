@@ -928,6 +928,21 @@ class TestMaintenancePhases:
         span, kids = spans[0]
         assert span.attrs["upper"] == view.upper
         assert span.attrs["prefetched_ticks"] == 0
+        # what the view reserves on the device (PR 30): the bytes of
+        # its operator state and output spine, from their shapes, on
+        # the span and on the gauge of /metrics
+        from materialize_tpu.arrangement.spine import device_nbytes
+        from materialize_tpu.utils.metrics import REGISTRY
+
+        reserved = span.attrs["state_capacity_bytes"]
+        assert reserved == device_nbytes((view.df.states, view.df.output))
+        assert reserved > 0
+        gauge = REGISTRY.get("mz_dataflow_state_capacity_bytes")
+        assert gauge.value(path) == reserved
+        assert (
+            f'mz_dataflow_state_capacity_bytes{{dataflow="{path}"}} '
+            in REGISTRY.expose_text()
+        )
         # an index view appends nothing; every other phase is there
         want = PHASES_SINKED - ({"span.append"} if sink is None else set())
         assert set(kids) == want

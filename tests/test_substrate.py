@@ -113,6 +113,46 @@ class TestSortConsolidate:
         assert len(rows) == 100
 
 
+    @pytest.mark.parametrize(
+        "capacity,count",
+        [(8192, 8000), (12288, 11511), (13192, 13192), (49152, 1),
+         (49152, 2048), (49152, 2049), (65536, 0), (65536, 64759)],
+    )
+    def test_sort_above_the_direct_size_is_the_compilers_sort(
+        self, capacity, count
+    ):
+        """Above ``SORT_DIRECT_MAX`` rows ``sort_perm`` sorts blocks
+        and merges them on a TPU (ROADMAP A3: that compiler's own sort
+        takes minutes to compile from 2^15 rows up): the same permutation,
+        ties and padding rows included, at sizes that are no power of
+        two as well (``concat_batches`` makes them). A batch whose
+        valid rows fit one block sorts that block alone."""
+        import jax
+        import jax.numpy as jnp
+
+        from materialize_tpu.ops.sort import _large_sort_perm
+        rng = np.random.default_rng(capacity + count)
+        lanes = [
+            jnp.asarray(rng.integers(0, hi, capacity).astype(np.uint64))
+            for hi in (50, 3)
+        ]
+        idx = jnp.arange(capacity, dtype=jnp.int32)
+        invalid = (idx >= count).astype(jnp.uint64)
+        want = jax.lax.sort(
+            [invalid] + lanes + [idx], num_keys=3, is_stable=True
+        )[-1]
+        # on a TPU ``sort_perm`` takes this path above 8,192 rows
+        got = jax.jit(
+            lambda a, b, c: _large_sort_perm(
+                [(idx >= c).astype(jnp.uint64), a, b], idx, c, capacity
+            )
+        )(lanes[0], lanes[1], jnp.asarray(count, jnp.int32))
+        got, want = np.asarray(got), np.asarray(want)
+        assert (got[:count] == want[:count]).all()
+        # padding rows last, each once, in whatever order
+        assert sorted(got[count:]) == sorted(want[count:])
+
+
 class TestSearch:
     def test_searchsorted_matches_numpy(self):
         rng = np.random.default_rng(3)
