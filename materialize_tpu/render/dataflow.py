@@ -1087,6 +1087,11 @@ class _DataflowBase:
         # dataflow state sequence against its span boundaries).
         self._readbacks = 0
         self._span_exec = None
+        # What presize_for_snapshot grew, until release_snapshot_tiers:
+        # (slot, part) -> the ingest tier's capacity before the hop,
+        # join site -> its tier before
+        self._presized: dict = {}
+        self._presized_sites: dict = {}
 
     # Back-compat shim for callers that poked the old counter directly.
     @property
@@ -1190,16 +1195,98 @@ class _DataflowBase:
                 runs if spine.slots else runs[1:]
             )
             small = [w for w, b in tiers if b.capacity < target]
+            if "tail" in small:
+                # release_snapshot_tiers gives the ingest tier back
+                self._presized[(slot, part)] = tiers[0][1].capacity
             for which in small:
                 self._grow_for(("state", slot, (part, which)), target)
             grown += bool(small)
             self._ctx.sort_in_blocks |= bool(small)
             probe = min(target, self._ctx.out_delta_cap)
             if self._ctx.join_caps[jsite] < probe:
+                self._presized_sites.setdefault(
+                    jsite, self._ctx.join_caps[jsite]
+                )
                 self._grow_for(("join", jsite), probe)
         if grown:
             self._remake_jit()  # the programs' key says how they sort
         return grown
+
+    def release_snapshot_tiers(self) -> dict:
+        """After the one hydration step has committed and
+        ``_compact_now`` has folded every run into its base: what
+        ``presize_for_snapshot`` grew for the snapshot's sake goes
+        back to what a tick needs, and the programs are remade for it.
+
+        Each ingest tier it grew is EMPTY reserved capacity at the
+        snapshot's tier now, and a merge-ingest tick would search,
+        gather and rewrite all of it to absorb one batch. The runs a
+        fold targets keep the snapshot tier: they hold the rows. No
+        row moves. The size is derived: a ring slot holds one tick's
+        batch, run 0 every batch between two folds
+        (``_compact_every``), a batch being the smallest tier a source
+        hands a tick; never below the tier the spine was rendered
+        with, never above what presizing gave it. A tier that still
+        holds rows is left as it is.
+
+        Each join site it grew pads every probe's output, and all
+        that the outputs feed, to the snapshot's matches: it goes back
+        to the tier it was rendered with.
+
+        A source whose ticks outgrow either overflows it and the
+        ladder doubles it, as it would have from the render's tiers.
+
+        What was released: arrangements, their rows and bytes, join
+        sites."""
+        from ..plan.decisions import quantize_cap
+
+        before = self.state_capacity_bytes()
+        shards = getattr(self, "num_shards", 1)  # capacities are global
+        spines = {
+            (slot, part): self.states[slot][part]
+            for slot, part in sorted(self._presized)
+        }
+        # one small read: hydration is off the hot path and the
+        # caller's result_batch() has synchronised already
+        counts = jax.device_get(
+            {
+                at: [b.count for b in sp.slots or sp.runs_b[:1]]
+                for at, sp in spines.items()
+            }
+        )
+        arrangements = rows = 0
+        for (slot, part), spine in spines.items():
+            ticks = 1 if spine.slots else self._compact_every
+            have = (spine.slots or spine.runs_b)[0].capacity
+            cap = min(
+                max(
+                    quantize_cap(ticks * capacity_tier(1)) * shards,
+                    self._presized[slot, part],
+                ),
+                have,
+            )
+            if cap == have or any(
+                np.any(c) for c in counts[slot, part]
+            ):
+                continue
+            parts = list(self.states[slot])
+            parts[part] = self._release_spine(spine, cap)
+            self.states[slot] = tuple(parts)
+            arrangements += 1
+            rows += (have - cap) * max(len(spine.slots), 1)
+        for jsite, rendered in self._presized_sites.items():
+            self._ctx.join_caps[jsite] = rendered
+        sites = len(self._presized_sites)
+        self._presized, self._presized_sites = {}, {}
+        if arrangements or sites:
+            self._reserved_bytes = None
+            self._remake_jit()  # the tick's programs, for its tiers
+        return {
+            "arrangements": arrangements,
+            "rows_released": rows,
+            "bytes_released": before - self.state_capacity_bytes(),
+            "join_sites": sites,
+        }
 
     def _static_tiers(self) -> str:
         """The capacity tiers this dataflow's programs bake in at trace
@@ -1321,6 +1408,44 @@ class _DataflowBase:
         if spine.lanes:
             lanes = self._pad_lanes(spine.lanes[which], grown.capacity)
         return spine.with_run(which, grown, lanes)
+
+    def _release_spine(self, spine: Spine, cap: int) -> Spine:
+        """``_grow_spine(spine, "tail", ...)`` undone: the ingest tier
+        (the slot ring when present, else run 0) as its own first
+        ``cap`` rows, cached lanes alongside. The tier must be EMPTY
+        (release_snapshot_tiers reads the counts): structure, dtypes
+        and null-mask layout stay the run's own."""
+        if spine.slots:
+            return Spine(
+                spine.runs_b,
+                spine.key,
+                spine.order,
+                tuple(self._release_batch(s, cap) for s in spine.slots),
+                spine.cursor,
+                spine.lanes,
+                tuple(self._first_rows(l, cap) for l in spine.slot_lanes),
+            )
+        lanes = None
+        if spine.lanes:
+            lanes = self._first_rows(spine.lanes[0], cap)
+        return spine.with_run(
+            0, self._release_batch(spine.runs_b[0], cap), lanes
+        )
+
+    def _release_batch(self, b: Batch, cap: int) -> Batch:
+        """An EMPTY batch cut to capacity ``cap``. Not
+        ``Batch.with_capacity``, which rightly refuses to shrink a
+        batch whose count is traced: here the caller has read the
+        count on the host, and it is zero."""
+        return b.replace(
+            cols=tuple(self._first_rows(c, cap) for c in b.cols),
+            nulls=tuple(
+                None if n is None else self._first_rows(n, cap)
+                for n in b.nulls
+            ),
+            time=self._first_rows(b.time, cap),
+            diff=self._first_rows(b.diff, cap),
+        )
 
     def _check_slot_ring(self) -> None:
         """The append-slot ring must hold every insert between level-0
@@ -2495,6 +2620,9 @@ class Dataflow(_DataflowBase):
         cap = target if target is not None else b.capacity * 2
         return b.with_capacity(cap) if cap > b.capacity else b
 
+    def _first_rows(self, a, cap: int):
+        return a[:cap]
+
     def _make_compact_jit(self, max_level: int = 10**9):
         from ..utils.compile_ledger import ledger_jit
 
@@ -2815,6 +2943,19 @@ class ShardedDataflow(_DataflowBase):
             diff=grow(b.diff),
             count=b.count,
             schema=b.schema,
+        )
+
+    def _first_rows(self, a, cap: int):
+        """Every shard's first rows ([P, have] -> [P, cap / P]; ``cap``
+        is GLOBAL, as in ``_grow_batch``)."""
+        P_ = self.num_shards
+        h = np.asarray(a)
+        h = h.reshape((P_, h.shape[0] // P_) + h.shape[1:])
+        return jax.device_put(
+            np.ascontiguousarray(h[:, : cap // P_]).reshape(
+                (cap,) + h.shape[2:]
+            ),
+            self._sharding,
         )
 
     # -- the SPMD step ------------------------------------------------------

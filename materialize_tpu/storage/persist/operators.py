@@ -800,6 +800,10 @@ class MaintainedView:
             self.df.time = as_of
             self.df.step(inputs)
             out = self.result_batch()
+            # every run is folded into its base now: what presizing
+            # grew for the snapshot goes back to a tick's size
+            with TRACER.phase("hydrate.release") as ph:
+                ph.add(**self.df.release_snapshot_tiers())
             self._append(out, 0, as_of + 1, as_of)
             self._upper = as_of + 1
             self._since = as_of  # the snapshot collapsed prior history

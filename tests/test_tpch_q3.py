@@ -17,6 +17,7 @@ import os
 import socket
 import threading
 
+import jax
 import numpy as np
 import pytest
 
@@ -93,16 +94,18 @@ def q3_run(tmp_path_factory):
     """A fresh install of the view on a replica of this process: its
     answer after every tick, the compile records and overflow regrows
     of its hydration and of its ticks, its ``span`` records and the
-    install's ``hydrate.presize`` record."""
+    install's ``hydrate.presize`` and ``hydrate.release`` records, and
+    the tiers of the source-fed join arrangements once hydrated."""
     tmp = tmp_path_factory.mktemp("q3")
     loc = PersistLocation(str(tmp / "blob"), str(tmp / "consensus.db"))
     s = socket.socket()
     s.bind(("127.0.0.1", 0))
     port = s.getsockname()[1]
     s.close()
-    ready = threading.Event()
+    ready, replica = threading.Event(), []
     threading.Thread(
-        target=serve_forever, args=(port, loc, "r0", ready), daemon=True
+        target=serve_forever, args=(port, loc, "r0", ready),
+        kwargs={"handle": replica}, daemon=True,
     ).start()
     assert ready.wait(10)
     coord = Coordinator(
@@ -135,6 +138,24 @@ def q3_run(tmp_path_factory):
             coord.sources["t"].tick_once()
         mark, grown = len(LEDGER.records()), regrows.value
         coord.execute(Q3)
+        df = replica[0].dataflows["q3"].view.df
+        tiers = {}
+        for (slot, part), (name, _site) in df._ctx.source_fed.items():
+            spine = df.states[slot][part]
+            tiers[f"{name}@{part}"] = {
+                "runs": [b.capacity for b in spine.runs_b],
+                "lanes": [l.shape[0] for l in spine.lanes],
+                # what one row of run 0 reserves: columns, null masks,
+                # time, diff and cached lanes
+                "row_bytes": sum(
+                    a.dtype.itemsize * int(np.prod(a.shape[1:]))
+                    for a in jax.tree_util.tree_leaves(
+                        (spine.runs_b[0], spine.lanes[0])
+                    )
+                    if a.ndim
+                ),
+            }
+        reserved, join_caps = df.state_capacity_bytes(), df._ctx.join_caps
         answers = {AGED: _plain(coord.execute("SELECT * FROM q3").rows)}
         hydration = {
             "programs": programs(mark),
@@ -162,7 +183,9 @@ def q3_run(tmp_path_factory):
             if r.name == "span" and r.attrs["dataflow"] == "q3"
         ],
         "presize": [r for r in records if r.name == "hydrate.presize"],
-        "gauge": gauge,
+        "release": [r for r in records if r.name == "hydrate.release"],
+        "tiers": tiers, "join_caps": list(join_caps),
+        "reserved": reserved, "gauge": gauge,
     }
 
 
@@ -229,12 +252,59 @@ def test_fresh_install_compiles_one_hydration_program_and_regrows_nothing(
     assert presize.attrs["snapshot_capacity"] == 32768 + 8192 + 512
 
 
+    # the tick's program is the one made after the release, for the
+    # tick's tiers: another key than the hydration program's
+    assert q3_run["ticks"]["programs"] != q3_run["hydration"]["programs"]
+
+
+def test_ingest_tiers_go_back_to_tick_size_after_hydration(q3_run):
+    """PR 31: the hydration step's ``_compact_now`` leaves run 0 of
+    each presized arrangement empty at the snapshot's tier; a tick
+    would merge its few rows into all of it. Run 0 goes back to what
+    the deltas between two folds need (8 ticks x the 256-row batch
+    tier), cached lanes with it; the bases keep the snapshot's tier."""
+    tiers = q3_run["tiers"]
+    assert sorted(tiers) == [
+        "customer@2", "lineitem@1", "orders@0", "orders@3"
+    ]
+    released = {"lineitem@1": 32768, "orders@0": 8192, "orders@3": 8192}
+    for name, snapshot in released.items():
+        assert tiers[name]["runs"] == [2048, snapshot]
+        assert tiers[name]["lanes"] == [2048, snapshot]
+    # 450 customers: the 1,024 rows run 0 was rendered with held the
+    # snapshot, so presizing grew the base alone and nothing is taken
+    # back (at the benchmark's scale all four are)
+    assert tiers["customer@2"]["runs"] == [1024, 512]
+    (release,) = q3_run["release"]
+    assert release.attrs["n"] == 1
+    assert release.attrs["arrangements"] == 3
+    assert release.attrs["rows_released"] == sum(
+        snapshot - 2048 for snapshot in released.values()
+    )
+    assert release.attrs["bytes_released"] == sum(
+        (snapshot - 2048) * tiers[name]["row_bytes"]
+        for name, snapshot in released.items()
+    ) > release.attrs["rows_released"] * 16  # time and diff at least
+    # the join's site, which pads every probe's output (and all the
+    # reduce and the top-k read) to its tier, is back from the 4,096
+    # rows a snapshot-size delta is cut to
+    assert release.attrs["join_sites"] == 1
+    assert q3_run["join_caps"] == [1024]
+    # siblings under the install, the release after the step
+    (presize,) = q3_run["presize"]
+    assert presize.parent_id == release.parent_id
+    assert presize.start < release.start
+
+
 def test_span_records_say_what_the_view_reserves(q3_run):
     sizes = [r.attrs["state_capacity_bytes"] for r in q3_run["spans"]]
     assert len(sizes) >= TICKS - AGED
-    # sized before the first step and never regrown: one value, above
-    # the bytes of the lineitem arrangement's two runs alone
-    assert len(set(sizes)) == 1 and sizes[0] > 2 * 32768 * 13 * 8
+    # released once after hydration and never regrown: one value from
+    # the first span on, what the dataflow reserved when hydrate
+    # returned; above the bytes of ONE snapshot-size lineitem run (the
+    # base), below those of the two it stood at before PR 31
+    assert len(set(sizes)) == 1 and sizes[0] == q3_run["reserved"]
+    assert 32768 * 13 * 8 < sizes[0] < 2 * 32768 * 13 * 8
     assert q3_run["gauge"] == sizes[0]
 
 
