@@ -59,7 +59,6 @@ from .donation import (  # noqa: F401
     donation_lowering_findings,
     guard_read,
     lint_donated_reuse,
-    record_donated,
     view_verdict,
 )
 from .provenance import (  # noqa: F401

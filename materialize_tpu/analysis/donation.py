@@ -66,7 +66,7 @@ STEP_ARGNUM = {
 
 
 class UseAfterDonateError(RuntimeError):
-    """A buffer donated to a span program was read (or re-dispatched)
+    """A buffer donated to a step program was read (or re-dispatched)
     after the dispatch that killed it."""
 
 
@@ -148,13 +148,6 @@ class DonationLedger:
 
 
 LEDGER = DonationLedger()
-
-
-def record_donated(tree, chain: str) -> None:
-    """Ledger write gated on the ``buffer_sanitizer`` dyncfg (no-op —
-    and no leaf walk — when off)."""
-    if sanitizer_enabled():
-        LEDGER.record(tree, chain)
 
 
 def guard_read(tree, who: str) -> None:
@@ -405,7 +398,6 @@ _DISPATCH_NAMES = ("jitfn", "step_fn", "_step_jit")
 # (module, qualname) of every function that performs donated dispatches.
 DONATED_DISPATCH_SITES = (
     ("materialize_tpu.render.dataflow", "_DataflowBase._dispatch_span"),
-    ("materialize_tpu.render.dataflow", "_DataflowBase.run_span"),
 )
 
 

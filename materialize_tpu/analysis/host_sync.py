@@ -48,8 +48,6 @@ _NUMPY_NAMES = frozenset({"np", "numpy", "_np"})
 DEFAULT_HOT_PATH = (
     ("materialize_tpu.render.dataflow", "_DataflowBase._dispatch_span"),
     ("materialize_tpu.render.dataflow", "_DataflowBase._dispatch_compact"),
-    ("materialize_tpu.render.dataflow", "_DataflowBase.run_span"),
-    ("materialize_tpu.render.dataflow", "_DataflowBase._stack_packed"),
     ("materialize_tpu.render.dataflow", "_DataflowBase._pack_flags"),
     ("materialize_tpu.render.dataflow", "_DataflowBase.flags_snapshot"),
     (
@@ -57,8 +55,6 @@ DEFAULT_HOT_PATH = (
         "_DataflowBase.read_flags_snapshot",
     ),
     ("materialize_tpu.render.dataflow", "_DataflowBase._or_acc"),
-    ("materialize_tpu.render.span_exec", "SpanExecutor.submit"),
-    ("materialize_tpu.render.span_exec", "SpanExecutor._stage"),
     (
         "materialize_tpu.storage.persist.operators",
         "MaintainedView._step_span_pipelined",
@@ -133,7 +129,6 @@ RECORDER_PATH = (
         "materialize_tpu.storage.persist.operators",
         "MaintainedView._commit_span",
     ),
-    ("materialize_tpu.render.span_exec", "SpanExecutor._complete"),
     # The freshness plane (ISSUE 15): wallclock-lag recording at every
     # committed span boundary must be pure host bookkeeping — deque
     # appends, a histogram bucket walk, and the SLO comparison.

@@ -51,7 +51,7 @@ PROV_HOST = "host-retained"
 PROV_CACHE = "cache-retained"
 
 # Roots whose class is PROV_CARRY, keyed by carry argnum name. The order
-# mirrors the span program's donated argnums (states, output, err, time)
+# mirrors the step program's donatable argnums (states, output, err, time)
 # — the donation verdict is per entry in this tuple.
 CARRY_PARTS = ("states", "output", "err_output", "time_dev")
 
@@ -142,7 +142,7 @@ class ProvenanceReport:
 
 
 def _carry_tree(df) -> dict:
-    """The span program's donated carry, keyed by argnum name."""
+    """The step program's donatable carry, keyed by argnum name."""
     return {
         "states": tuple(df.states),
         "output": df.output,

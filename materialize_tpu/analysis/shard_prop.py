@@ -48,9 +48,8 @@ shard-local stage fails CI statically, before any multi-chip run.
 
 Surfaces: ``ShardedDataflow.sharding_report()`` (the render-layer
 gate), ``EXPLAIN ANALYSIS``'s ``sharding:`` block, the ``mz_sharding``
-introspection relation, ``bench.py --multichip``, and the
-``comm-budget`` / ``spmd-safety`` gates in ``scripts/check_plans.py
---bench``. See doc/analysis.md §6.
+introspection relation, and the ``comm-budget`` / ``spmd-safety``
+gates in ``scripts/check_plans.py --bench``. See doc/analysis.md §6.
 """
 
 from __future__ import annotations
@@ -603,9 +602,9 @@ def trace_sharded_step(sdf, input_cap: int = 256):
 def sharded_step_report(sdf, input_cap: int = 256) -> dict:
     """Run the prover over a ShardedDataflow's step program and return
     the report dict every surface consumes (``mz_sharding`` rows,
-    EXPLAIN ANALYSIS's ``sharding:`` block, ``bench.py --multichip``,
-    the check_plans gates). ``safe`` is the conjunction over cursor
-    verdicts (vacuously true in merge mode); a trace/analysis failure
+    EXPLAIN ANALYSIS's ``sharding:`` block, the check_plans gates).
+    ``safe`` is the conjunction over cursor verdicts (vacuously true
+    in merge mode); a trace/analysis failure
     reports unsafe with the error recorded — the render layer then
     falls back to merge ingest, never to an unproven slot ring."""
     try:

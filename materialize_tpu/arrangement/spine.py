@@ -706,9 +706,9 @@ def clone_state_tree(tree):
     """Deep-copy every device leaf of a state pytree (arrangements,
     spines, batches, scalars) to FRESH buffers in ONE fused program.
 
-    Donation safety (the pipelined span executor's checkpoint
-    contract): a span program compiled with ``donate_argnums`` hands
-    its carry buffers to XLA — after dispatch they are dead and must
+    Donation safety (the defer window's checkpoint contract): a step
+    program compiled with ``donate_argnums`` hands its carry buffers
+    to XLA — after dispatch they are dead and must
     never be read again. The rollback checkpoint therefore cannot hold
     references into the carry; it holds this clone instead. jit
     outputs never alias un-donated inputs, so every returned leaf is a

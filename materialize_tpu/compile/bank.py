@@ -265,8 +265,8 @@ class ProgramBank:
         return out
 
     def snapshot(self) -> dict:
-        """Counters + entry census: the recovery report / bench
-        surface."""
+        """Counters + entry census: the recovery report's and
+        ``mz_program_bank``'s surface."""
         with self._lock:
             stats = dict(self.stats)
         ents = self.entries()
@@ -288,7 +288,7 @@ _resolved = False
 
 def configure_bank(path: str | None) -> ProgramBank | None:
     """Point this process at a bank directory (None disables). Called
-    by environmentd/replica boot, bench.py --bank, and tests."""
+    by environmentd/replica boot and tests."""
     global BANK, _resolved
     _resolved = True
     BANK = ProgramBank(path) if path else None

@@ -167,8 +167,7 @@ class PeekBatcher:
     flusher thread drains every group each ``peek_batch_window_ms``
     span tick into one ``peek_lookup`` command (the replica pads the
     stacked probes to a pow2 batch lane and runs one gather program).
-    With ``peek_batching`` off, each lookup dispatches on its own —
-    the serial baseline ``bench.py --serve`` compares against."""
+    With ``peek_batching`` off, each lookup dispatches on its own."""
 
     def __init__(self, controller: "ComputeController"):
         from ..utils.lockcheck import tracked_lock
@@ -1112,8 +1111,9 @@ class ComputeController:
         ]
 
     def routing_snapshot(self) -> dict:
-        """Routing observability (bench.py --serve's per-replica
-        distribution + the mz_metrics counters' in-process twin)."""
+        """Routing observability: the per-replica distribution
+        ``mz_cluster_replicas.routed`` serves and the mz_metrics
+        counters' in-process twin."""
         with self._lock:
             out = dict(self.routing_stats)
             out["per_replica"] = dict(self.routed_counts)
@@ -1315,8 +1315,7 @@ class ComputeController:
 
     def peek_stats(self) -> dict:
         """Read-plane observability: lookups, batches, occupancy,
-        shed count, queue depth, and the routing distribution
-        (bench.py --serve reports these)."""
+        shed count, queue depth, and the routing distribution."""
         out = self._peek_batcher.snapshot()
         out["routing"] = self.routing_snapshot()
         return out

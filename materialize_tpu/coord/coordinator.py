@@ -2087,7 +2087,7 @@ class Coordinator:
         self, name: str, values: tuple, bound_cols: tuple | None = None
     ) -> list:
         """Programmatic point lookup over a peekable relation — the
-        serving-plane API bench.py --serve and tests drive (the SQL
+        serving-plane API tests drive (the SQL
         front end reaches the same plane through _sequence_peek; this
         entry point skips parsing/planning, like a prepared statement
         with bound parameters). ``values`` are user-space; ``bound_cols``

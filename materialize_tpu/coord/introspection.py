@@ -206,8 +206,7 @@ INTROSPECTION_SCHEMAS: dict[str, Schema] = {
             # draining replica stays connected but takes no new
             # routed reads).
             Column("state", S),
-            # Reads routed to this replica (the per-replica routing
-            # distribution bench.py --serve reports).
+            # Reads routed to this replica.
             Column("routed", I),
             # The device the replica reported at HelloOk, as its own
             # JAX sees it (empty/0 before the first session): which

@@ -1026,8 +1026,7 @@ class ReplicaWorker:
                 )
 
                 # With peek_batching OFF the plane is per-peek end to
-                # end: every command pays its own gather dispatch (the
-                # serial baseline bench.py --serve measures against).
+                # end: every command pays its own gather dispatch.
                 merge_key = (
                     None
                     if PEEK_BATCHING(COMPUTE_CONFIGS)

@@ -218,7 +218,7 @@ class SubscribeSession:
             except OSError:
                 pass
 
-    # -- consumer side (wire loops, bench, tests) ---------------------------
+    # -- consumer side (wire loops, tests) ----------------------------------
     def wait(self, timeout: float) -> bool:
         """Block until a chunk (or close/error) is ready."""
         return self._event.wait(timeout)
@@ -335,7 +335,7 @@ class _SharedTail:
     and the decoded chunk is fanned out by reference to every
     session's queue. ``readbacks == spans`` is the counted invariant —
     a per-session tail regression multiplies readbacks by the session
-    count and fails the bench/CI gates."""
+    count (tests/test_subscribe.py holds it at one a span)."""
 
     def __init__(self, hub, key, label: str, shard: str, schema,
                  owned_dataflow: str | None, start_frontier: int,
@@ -899,8 +899,8 @@ class SubscribeHub:
     def snapshot(self) -> dict:
         """The push plane's counted state: per-tail readbacks/spans
         (the 1.0 invariant), per-session frontiers/queues/lag, and the
-        sharing counters (bench.py --subscribe, mz_subscriptions, and
-        EXPLAIN ANALYSIS all read this)."""
+        sharing counters (mz_subscriptions and EXPLAIN ANALYSIS read
+        this)."""
         with self._lock:
             tails = list(self._tails.values())
             out = dict(self.stats)

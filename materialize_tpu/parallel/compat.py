@@ -21,8 +21,8 @@ def force_host_devices(env=None, n: int = 8) -> None:
     in place (default ``os.environ``); a pre-existing
     ``xla_force_host_platform_device_count`` flag wins, so an
     operator's own device count is respected. The single copy of the
-    idiom shared by tests/conftest.py, ``scripts/check_plans.py
-    --bench``, and the multichip bench fixture."""
+    idiom shared by tests/conftest.py and ``scripts/check_plans.py
+    --bench``."""
     import os
 
     target = os.environ if env is None else env

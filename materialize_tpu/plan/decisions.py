@@ -117,13 +117,10 @@ def state_ingest_mode(
     """Ingest decision for OPERATOR-STATE spines (join/delta-join
     arrangements). `auto` resolves by the SAME big-state rule as the
     output index (ingest_mode): append-slot once the state tier is
-    >= 8x the ingest tier. The round-6 deferral — auto forced 'merge'
-    because regrowing a per-arrangement slot ring through a delta-join
-    step program blew the CPU tier probe's budget — is paid off:
-    bench_tiers.json was regenerated on this host with slotted
-    operator-state spines (ISSUE 7 satellite; doc/perf.md), so the
-    measuring process compiles only final-tier programs and the probe
-    cost is a one-time CPU pass.
+    >= 8x the ingest tier. What the rule earns for operator state on
+    the chip is not measured (ROADMAP C16): a slot ring per
+    arrangement part multiplies operator memory, and regrowing one
+    through a delta-join step program costs a compile a rung.
 
     SPMD no longer unconditionally forces 'merge' (ISSUE 9): the
     render layer carries a PER-DEVICE slot cursor (a sharded

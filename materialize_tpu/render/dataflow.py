@@ -66,20 +66,6 @@ from ..repr.schema import DIFF_DTYPE, TIME_DTYPE, Schema
 from ..utils.trace import TRACER
 
 
-# The span program nests cumulative scans (reduce-window lowerings)
-# inside lax.scan; at big run capacities the default 16MiB scoped-vmem
-# budget overflows at compile time ("Ran out of memory in memory space
-# vmem ... scoped"). Raise it for span compiles only (v5e has 128MiB
-# VMEM; 64MiB scoped leaves ample room). TPU-only option — CPU/other
-# backends reject it.
-
-
-def _span_compiler_options():
-    if jax.default_backend() == "tpu":
-        return {"xla_tpu_scoped_vmem_limit_kib": 65536}
-    return None
-
-
 def _donation_supported() -> bool:
     """Whether the backend honors ``donate_argnums``. CPU ignores
     donation (warning per buffer), so the argnums are wired only
@@ -89,6 +75,24 @@ def _donation_supported() -> bool:
     donation, the clone always happens, the argnums follow the
     backend."""
     return jax.default_backend() == "tpu"
+
+
+def resolve_donation(mode=None) -> bool:
+    """Resolve the span-carry donation mode: explicit bool wins, then
+    the ``span_donation`` dyncfg ('on'/'off'/'auto'); 'auto' donates
+    only where the backend implements donation (TPU — CPU ignores it
+    with a warning per buffer)."""
+    if isinstance(mode, bool):
+        return mode
+    if mode is None:
+        from ..utils.dyncfg import COMPUTE_CONFIGS, SPAN_DONATION
+
+        mode = SPAN_DONATION(COMPUTE_CONFIGS)
+    if mode in ("on", "true", True):
+        return True
+    if mode in ("off", "false", False):
+        return False
+    return _donation_supported()
 
 
 @dataclass
@@ -1055,8 +1059,7 @@ class _DataflowBase:
         # array regardless of how many steps are deferred. (Keeping a
         # per-step list and stacking at check time built a program with
         # one operand PER DEFERRED STEP; at ~500 steps that program took
-        # tens of minutes to build and run — the actual cause of rounds
-        # 3/4's driver bench timeouts.)
+        # tens of minutes to build and run.)
         self._defer_ck = None
         self._defer_log: list = []
         self._defer_flags = None
@@ -1080,13 +1083,12 @@ class _DataflowBase:
         self._compact_tick = 0
         self._compact_jits: dict = {}
         self._covf_keys = self._compact_keys()
-        # Pipelined-control-plane bookkeeping (ISSUE 7): d2h readback
-        # census (every flags transfer increments it — the trace's
-        # readbacks-per-span counter reads deltas of this), and the
-        # span executor attached to this dataflow, if any (reads of
-        # dataflow state sequence against its span boundaries).
+        # d2h readback census: every flags transfer increments it (a
+        # committed span costs one). And the barrier of the
+        # MaintainedView stepping this dataflow, if any: reads of
+        # dataflow state commit that view's in-flight span first.
         self._readbacks = 0
-        self._span_exec = None
+        self._span_barrier = None
         # What presize_for_snapshot grew, until release_snapshot_tiers:
         # (slot, part) -> the ingest tier's capacity before the hop,
         # join site -> its tier before
@@ -1301,10 +1303,10 @@ class _DataflowBase:
     def _grow_for(self, key, target: int | None = None) -> None:
         """Grow the capacity tier behind an overflowed key — one
         doubling by default, or straight to ``target`` in a single pad
-        (callers applying known steady-state tiers up front skip the
+        (presize_for_snapshot, which knows the size, skips the
         doubling ladder, whose every rung costs a compile and a
         dispatch). Explicit targets snap to
-        the pow2 quantization menu (ISSUE 16) so applied bench tiers
+        the pow2 quantization menu (ISSUE 16) so presized tiers
         land on bankable program keys; doubling from a quantized base
         stays on the menu by construction."""
         if target is not None:
@@ -2083,33 +2085,6 @@ class _DataflowBase:
                 continue
             return deltas
 
-    # -- span-scan execution ------------------------------------------------
-    #
-    # Every dispatch+block round trip has a fixed host cost, paid
-    # serially, so a per-step host loop is bounded by that cost and not
-    # by device speed (the figure is to be re-measured on this machine,
-    # ROADMAP A3). run_span executes K steps as ONE device program —
-    # lax.scan chunks of `_compact_every` steps with the spine
-    # compaction traced BETWEEN chunks — so a span amortises one
-    # dispatch and one readback over K steps. The micro-batch loop is
-    # control flow, and control flow belongs on device (lax.scan), not
-    # in Python.
-
-    def _stack_packed(self, packed_list: list) -> dict:
-        """Stack K per-step input dicts into one dict of batches with
-        [K, ...] leaves (the scan's xs)."""
-        out = {}
-        for name in packed_list[0]:
-            bs = [p[name] for p in packed_list]
-            leaves0, treedef = jax.tree_util.tree_flatten(bs[0])
-            leavess = [jax.tree_util.tree_flatten(b)[0] for b in bs]
-            stacked = [
-                jnp.stack([lv[i] for lv in leavess])
-                for i in range(len(leaves0))
-            ]
-            out[name] = jax.tree_util.tree_unflatten(treedef, stacked)
-        return out
-
     def _max_compact_level(self) -> int:
         """Deepest fold index any spine in this dataflow can take."""
         from ..arrangement.spine import compact_depth
@@ -2120,260 +2095,6 @@ class _DataflowBase:
                 if isinstance(s, Spine):
                     deepest = max(deepest, compact_depth(s) - 1)
         return deepest
-
-    def _make_span_jit(self, with_env: bool, donate: bool = False):
-        """ONE program for every span shape: an outer lax.scan over
-        chunks whose xs carry (chunk inputs, compaction level) — the
-        geometric cadence is RUNTIME DATA dispatched with lax.switch,
-        so the pattern never forces a recompile (the unrolled-chunk
-        form compiled one ~3-minute variant per distinct pattern).
-
-        ``donate`` donates the carry argnums (states, output spine,
-        err arrangement, device time) so XLA writes each span's output
-        state into the input state's buffers instead of allocating and
-        copying state-sized arrays per dispatch (the h2d/HBM traffic
-        saver of the pipelined control plane). Donated inputs are DEAD
-        after the call — see _clone_checkpoint for the rollback
-        contract; backends without donation support (CPU) silently
-        ignore it."""
-        ce = self._compact_every
-        n_branches = self._max_compact_level() + 1
-
-        def span(states, output, err_output, time_dev, chunks, levels,
-                 *env_a):
-            env = env_a[0] if env_a else None
-
-            def chunk_body(carry, xs):
-                chunk, lvl = xs
-                st, o, e, t = carry
-                # Only the spine's INGEST tier rides the inner scan
-                # carry (the slot ring + cursor when present, else run
-                # 0 — each WITH its cached lanes, which the insert
-                # rewrites every step); every other run (and its
-                # lanes) is chunk-invariant (the step never touches
-                # it) and rejoins only for the compaction.
-                if o.slots:
-                    invariant = o.runs_b
-                    inv_lanes = o.lanes
-
-                    if o.lanes:
-
-                        def rebuild(carried):
-                            slots, slot_lanes, cursor = carried
-                            return Spine(
-                                invariant, o.key, o.order, slots,
-                                cursor, inv_lanes, slot_lanes,
-                            )
-
-                        def extract(sp):
-                            return (sp.slots, sp.slot_lanes, sp.cursor)
-
-                        carried0 = (o.slots, o.slot_lanes, o.cursor)
-                    else:
-
-                        def rebuild(carried):
-                            slots, cursor = carried
-                            return Spine(
-                                invariant, o.key, o.order, slots, cursor
-                            )
-
-                        def extract(sp):
-                            return (sp.slots, sp.cursor)
-
-                        carried0 = (o.slots, o.cursor)
-                else:
-                    invariant = o.runs_b[1:]
-                    inv_lanes = o.lanes[1:] if o.lanes else ()
-
-                    if o.lanes:
-
-                        def rebuild(carried):
-                            r0, l0 = carried
-                            return Spine(
-                                (r0,) + invariant, o.key, o.order,
-                                (), None, (l0,) + inv_lanes, (),
-                            )
-
-                        def extract(sp):
-                            return (sp.runs_b[0], sp.lanes[0])
-
-                        carried0 = (o.runs_b[0], o.lanes[0])
-                    else:
-
-                        def rebuild(carried):
-                            return Spine(
-                                (carried,) + invariant, o.key, o.order
-                            )
-
-                        def extract(sp):
-                            return sp.runs_b[0]
-
-                        carried0 = o.runs_b[0]
-
-                def step_body(c2, x):
-                    st2, ingest, e2, t2 = c2
-                    o2 = rebuild(ingest)
-                    if env is not None:
-                        out, ns, no, ne, nt, fl = self._step_core(
-                            st2, o2, e2, x, t2, env
-                        )
-                    else:
-                        out, ns, no, ne, nt, fl = self._step_core(
-                            st2, o2, e2, x, t2
-                        )
-                    return (ns, extract(no), ne, nt), (out, fl)
-
-                (st, ingest, e, t), (deltas, fls) = jax.lax.scan(
-                    step_body, (st, carried0, e, t), chunk
-                )
-                o = rebuild(ingest)
-                branches = [
-                    (lambda s_, o_, m=m: self._compact_core_single(
-                        s_, o_, m
-                    ))
-                    for m in range(n_branches)
-                ]
-                st, o, cfl = jax.lax.switch(lvl, branches, st, o)
-                return (st, o, e, t), (deltas, fls.any(axis=0), cfl)
-
-            carry = (tuple(states), output, err_output, time_dev)
-            carry, (deltas, sfls, cfls) = jax.lax.scan(
-                chunk_body, carry, (chunks, levels)
-            )
-            # deltas leaves: [n_chunks, ce, ...] -> [K, ...]
-            deltas_all = jax.tree_util.tree_map(
-                lambda a: a.reshape((-1,) + a.shape[2:]), deltas
-            )
-            return carry, deltas_all, sfls.any(axis=0), cfls.any(axis=0)
-
-        from ..utils.compile_ledger import ledger_jit
-
-        return ledger_jit(
-            jax.jit(
-                span,
-                compiler_options=_span_compiler_options(),
-                donate_argnums=(0, 1, 2, 3) if donate else (),
-            ),
-            "span_donated" if donate else "span",
-            self.name,
-            getattr(self, "_fingerprint", self.name),
-            static=f"{self._static_tiers()}|{self._compact_every}",
-        )
-
-    def run_span(self, inputs_list: list, donate: bool = False):
-        """Feed a span of micro-batches as ONE device dispatch (deferred
-        overflow checks — see run_steps). The span length must be a
-        multiple of ``_compact_every``; spine compaction runs on device
-        between scan chunks. Returns the stacked per-step output deltas
-        (leaves shaped [K, ...], device-resident, PROVISIONAL until
-        check_flags). ``donate`` hands the carry's buffers to the span
-        program (see _make_span_jit); the defer checkpoint is then a
-        fresh-buffer clone."""
-        from ..utils.lockcheck import device_dispatch
-
-        self.span_barrier()
-        device_dispatch("run_span")
-        ce = self._compact_every
-        if len(inputs_list) % ce != 0:
-            raise ValueError(
-                f"span length {len(inputs_list)} must be a multiple of "
-                f"compact_every={ce}"
-            )
-        if getattr(self, "_first_time", None) is None:
-            self._first_time = int(self.time)
-            self._ctx.first_time = self._first_time
-        self._check_slot_ring()
-        # Checkpoint BEFORE any dispatch (including the flush
-        # compaction below): an overflow discovered at check_flags
-        # time must be able to roll all of it back. Donated spans
-        # clone the checkpoint to fresh buffers — the live carry's
-        # buffers die at dispatch.
-        if self._defer_ck is None:
-            self._defer_ck = (
-                self._clone_checkpoint() if donate else self._checkpoint()
-            )
-            self._defer_ck_cloned = bool(donate)
-        elif donate and not self._defer_ck_cloned:
-            # A window that started with a plain reference checkpoint
-            # cannot start donating mid-window: rollback would
-            # resurrect buffers a donated dispatch killed.
-            donate = False
-        if self._compact_tick % ce:
-            # Flush (full cascade) so the span's internal compaction
-            # schedule starts from a clean counter.
-            cfl = self._dispatch_compact()
-            self._defer_cflags = self._or_acc(self._defer_cflags, cfl)
-            self._compact_tick = 0
-        packed = [self._pack_inputs(i) for i in inputs_list]
-        env = self._build_env()
-        if self._time_dev is None:
-            self._time_dev = jnp.asarray(self.time, dtype=jnp.uint64)
-        n_chunks = len(inputs_list) // ce
-        levels = jnp.asarray(
-            [
-                min(
-                    self._due_levels(self._compact_tick + (j + 1) * ce),
-                    self._max_compact_level(),
-                )
-                for j in range(n_chunks)
-            ],
-            dtype=jnp.int32,
-        )
-        if not hasattr(self, "_span_jits"):
-            self._span_jits = {}
-        requested = bool(donate)
-        donate = donate and _donation_supported()
-        key = (ce, n_chunks, env is not None, donate)
-        jitfn = self._span_jits.get(key)
-        if jitfn is None:
-            jitfn = self._make_span_jit(env is not None, donate=donate)
-            self._span_jits[key] = jitfn
-        stacked = self._stack_packed(packed)
-        chunks = jax.tree_util.tree_map(
-            lambda a: a.reshape((n_chunks, ce) + a.shape[1:]), stacked
-        )
-        args = (
-            tuple(self.states), self.output, self.err_output,
-            self._time_dev, chunks, levels,
-        )
-        if env is not None:
-            args = args + (env,)
-        # No donation-warning suppression needed: `donate` was
-        # narrowed above to backends that honor donate_argnums, so
-        # the CPU "donated buffers were not usable" warning is
-        # unreachable here by construction.
-        carry, deltas, sfl, cfl = jitfn(*args)
-        if requested:
-            # The donation CONTRACT is backend-independent: whenever a
-            # span is dispatched with donation requested, the old
-            # carry is dead — record it so the sanitizer catches any
-            # holder that reads it (even on backends where the argnums
-            # were not wired and the buffers happen to survive).
-            from ..analysis.donation import record_donated
-            from ..analysis.provenance import CARRY_PARTS
-
-            record_donated(
-                args[:4],
-                f"{self.name}.run_span span@t={self.time} (donated "
-                "carry)",
-            )
-            self._defer_donated = tuple(CARRY_PARTS)
-        st, o, e, t = carry
-        self.states = list(st)
-        self.output = o
-        self.err_output = e
-        self._time_dev = t
-        self._time += len(inputs_list)
-        self._compact_tick += len(inputs_list)
-        # Rollback/replay bookkeeping: replays reuse the ordinary
-        # per-step path (compaction timing differs, which is
-        # semantically transparent — compaction never changes content).
-        self._defer_log.append((packed, env))
-        if sfl is not None:
-            self._defer_flags = self._or_acc(self._defer_flags, sfl)
-        if cfl is not None:
-            self._defer_cflags = self._or_acc(self._defer_cflags, cfl)
-        return deltas
 
     def check_flags(self) -> bool:
         """Resolve deferred overflow checks: one flags readback covering
@@ -2420,7 +2141,8 @@ class _DataflowBase:
 
     # -- pipelined span boundaries (ISSUE 7) --------------------------------
     #
-    # The double-buffered executor protocol: dispatch span K+1, THEN
+    # The double-buffered protocol of an index view's span train
+    # (MaintainedView._step_span_pipelined): dispatch span K+1, THEN
     # read span K's accumulated overflow flags — the readback blocks
     # exactly until span K's program finished (all of a dispatch's
     # outputs become ready together), while span K+1 is already queued
@@ -2440,7 +2162,7 @@ class _DataflowBase:
         overflow occurred up to the snapshot point (the caller then
         runs :meth:`check_flags` for the rollback+replay). Blocks
         until the snapshot's producing span has finished executing —
-        this is the pipelined executor's per-span sync point."""
+        this is the pipelined span train's per-span sync point."""
         f, c = snap
         parts = []
         if f is not None:
@@ -2457,13 +2179,13 @@ class _DataflowBase:
 
     def span_barrier(self) -> None:
         """Sequence a state read against span boundaries: when a
-        pipelined span executor is attached, an in-flight span's carry
+        MaintainedView steps this dataflow, its in-flight span's carry
         may hold donated (dead) buffers and a provisional frontier —
         complete and commit it before reading dataflow state. No-op
-        without an executor or from the executor's own dispatch."""
-        ex = self._span_exec
-        if ex is not None and not ex.in_dispatch:
-            ex.sync()
+        without a view or from the view's own dispatch."""
+        barrier = self._span_barrier
+        if barrier is not None and not barrier.in_dispatch:
+            barrier.sync()
 
     def _clone_checkpoint(self):
         """A rollback checkpoint whose device leaves are FRESH buffer
@@ -2556,7 +2278,6 @@ class Dataflow(_DataflowBase):
         from ..utils.compile_ledger import ledger_jit
 
         fp = getattr(self, "_fingerprint", self.name)
-        self._span_jits = {}
         self._donated_step_jits = {}
         if self._str_keys:
             self._step_jit = ledger_jit(
@@ -2865,7 +2586,7 @@ class ShardedDataflow(_DataflowBase):
         SPMD-safety verdicts, resolved ingest mode. Computed eagerly
         when a slot ring was requested (it gates the enablement),
         lazily for merge-mode dataflows; cached — surfaces
-        (mz_sharding, EXPLAIN ANALYSIS, bench --multichip) read it
+        (mz_sharding, EXPLAIN ANALYSIS) read it
         for free after the first call."""
         if self._shard_prop_report is None:
             from ..analysis.shard_prop import sharded_step_report
@@ -3074,18 +2795,6 @@ class ShardedDataflow(_DataflowBase):
             jax.jit(step), "step_spmd", self.name,
             getattr(self, "_fingerprint", self.name),
             static=self._static_tiers(),
-        )
-
-    def run_span(self, inputs_list: list, donate: bool = False):
-        raise NotImplementedError(
-            "span-scan execution is single-device for now; sharded "
-            "dataflows pipeline through run_steps(defer_check=True) + "
-            "flags snapshots instead (the shard_map step is already "
-            "one dispatch per step, and its packed flags ride the "
-            "same deferred logical_or accumulator) — with slot-ring "
-            "ingest now prover-gated under SPMD (ISSUE 9), the "
-            "remaining span work is the scan-over-chunks program, "
-            "see ROADMAP item 2"
         )
 
     def _donated_step_program(self, parts: tuple):

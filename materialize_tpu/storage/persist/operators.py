@@ -462,23 +462,15 @@ class IndexSource:
 
 
 class _ViewSpanBarrier:
-    """Adapter registering a MaintainedView's span pipeline as its
-    dataflow's ``_span_exec`` barrier: df-level state reads
-    (``output_batch``/``output_records``/``run_steps``/
-    ``peek_errors`` call ``span_barrier()``) then commit the view's
-    in-flight span first — the same contract render/span_exec's
-    executor provides — instead of relying only on the view-level
-    ``sync_spans()`` call sites. ``in_dispatch`` is raised around the
-    view's own span dispatch so dispatching never self-syncs (which
-    would serialize the double buffer).
-
-    The view's pipeline intentionally re-implements the boundary
-    protocol rather than wrapping a SpanExecutor: the executor drives
-    ``run_span`` (stacked multiple-of-compact-every spans, one fused
-    program), while the view needs per-tick deltas and frontier
-    bookkeeping from ``run_steps`` trains — the shared pieces
-    (flags snapshots, one-readback commit, window rollback) live in
-    ``_DataflowBase``."""
+    """What a MaintainedView registers as its dataflow's
+    ``_span_barrier``: df-level state reads (``output_batch``/
+    ``run_steps``/``peek_errors`` call ``span_barrier()``) then
+    commit the view's in-flight span first, instead of relying only
+    on the view-level ``sync_spans()`` call sites. ``in_dispatch`` is
+    raised around the view's own span dispatch so dispatching never
+    self-syncs (which would serialize the double buffer). The pieces
+    the view's pipeline stands on (flags snapshots, one-readback
+    commit, window rollback) live in ``_DataflowBase``."""
 
     __slots__ = ("view", "in_dispatch")
 
@@ -583,7 +575,7 @@ class MaintainedView:
         # Register as the dataflow's span barrier: any df-level state
         # read sequences through sync_spans() automatically.
         self._barrier = _ViewSpanBarrier(self)
-        dataflow._span_exec = self._barrier
+        dataflow._span_barrier = self._barrier
         # Donation state (ISSUE 8): the buffer-provenance prover's
         # verdict gates whether this view's run_steps span train
         # donates its carry. Recomputed when the sharing structure
@@ -1146,13 +1138,15 @@ class MaintainedView:
         """Whether donation POLICY asks for a donated carry on this
         view's span train: the ``span_donation`` dyncfg resolved
         through the one shared backend predicate
-        (render/dataflow._donation_supported via
-        span_exec.resolve_donation), restricted to single-device
+        (render/dataflow.resolve_donation, over
+        _donation_supported), restricted to single-device
         dataflows (SPMD carries cannot alias through shard_map
         boundary specs). The provenance PROVER decides whether the
         request is safe — see :meth:`_span_donation`."""
-        from ...render.dataflow import Dataflow as _SingleDevice
-        from ...render.span_exec import resolve_donation
+        from ...render.dataflow import (
+            Dataflow as _SingleDevice,
+            resolve_donation,
+        )
 
         return type(self.df) is _SingleDevice and resolve_donation(None)
 

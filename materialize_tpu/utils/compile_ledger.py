@@ -30,8 +30,8 @@ Replica processes piggyback their records on Frontiers responses (the
 span/verdict pattern); the controller ingests them, deduping by pid so
 in-process replicas (which share this ledger) never double-report.
 Surfaces: the ``mz_compile_log`` introspection relation, the
-``mz_compile_*`` /metrics families, EXPLAIN ANALYSIS's ``compiles:``
-block, and ``bench.py --trace``'s ``compiles`` summary.
+``mz_compile_*`` /metrics families, and EXPLAIN ANALYSIS's
+``compiles:`` block.
 
 With a program bank configured (ISSUE 16, compile/bank.py) every
 ``ledger_jit`` site becomes a bank lookup point. First sight of a
@@ -242,7 +242,7 @@ class CompileLedger:
 
     def summary(self, names: set | None = None) -> dict:
         """Totals (optionally scoped to dataflow ``names``): the
-        EXPLAIN ANALYSIS / bench.py surface. ``bank_hit`` records are
+        EXPLAIN ANALYSIS surface. ``bank_hit`` records are
         NOT compiles — they count separately (``bank_hits``,
         ``bank_seconds_recovered`` = the compile wall they skipped),
         so ``compiles``/``misses``/``hits`` keep their pre-bank

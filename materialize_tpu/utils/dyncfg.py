@@ -270,9 +270,9 @@ SPAN_WINDOW_SPANS = Config(
 
 SPAN_DONATION = Config(
     "span_donation", "auto",
-    "donate the span program's carry (operator states, output spine, "
-    "err arrangement, device time) to XLA so each span's outputs "
-    "reuse the previous span's state buffers instead of allocating + "
+    "donate the step program's carry (operator states, output spine, "
+    "err arrangement, device time) to XLA so each step of a span "
+    "reuses the previous step's state buffers instead of allocating + "
     "copying state-sized arrays per dispatch. 'auto' = on for TPU "
     "backends; 'off' forces off; 'on' forces on WHERE the backend "
     "honors donation (CPU ignores donate_argnums, and jaxlib crashes "

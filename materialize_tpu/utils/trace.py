@@ -100,7 +100,7 @@ class SpanRecord:
     def to_json(self) -> dict:
         """The ``mz_trace_spans`` row as an object, attributes nested:
         one line of the flight recorder's dump, and what
-        ``scripts/trace_export.py --spans`` takes."""
+        ``scripts/trace_export.py`` takes."""
         return {
             "trace_id": self.trace_id,
             "span_id": self.span_id,

@@ -186,8 +186,8 @@ BUDGET_PATH = os.path.join(REPO, "tests", "kernel_budget.json")
 def bench_dataflows() -> dict:
     """name -> Dataflow factory for the budget-gated bench configs —
     pure renders, no generators (CI must not pay TPCH data
-    generation). The index entry reproduces bench.config_index's
-    output-spine geometry (4-level ladder + 4-slot append ring); op
+    generation). The index entry has a deep output spine (4-level
+    ladder + 4-slot append ring); op
     census is capacity-independent, so the init-tier capacities are
     fine."""
     from materialize_tpu.expr import relation as mir
@@ -311,7 +311,6 @@ def run_bench_mode(verbose: bool) -> int:
     rc |= run_failover_smoke_gate(gate)
     rc |= run_compactor_smoke_gate(gate)
     rc |= run_subscribe_smoke(gate, budgets)
-    rc |= run_trace_overhead_gate(gate)
     rc |= run_mz_relations_gate(gate)
     rc |= run_bank_roundtrip_gate(gate)
     rc |= run_tier_quantization_gate(gate)
@@ -569,94 +568,6 @@ def run_tier_quantization_gate(gate) -> int:
         shutil.rmtree(bank_dir, ignore_errors=True)
         shutil.rmtree(xla_cache, ignore_errors=True)
     gate("tier-quantization", None, findings, 0)
-    return 1 if findings else 0
-
-
-def run_trace_overhead_gate(gate) -> int:
-    """Observability-plane overhead gate (ISSUE 12 satellite): the
-    span recorder and compile-ledger wrapper sit on the per-span hot
-    path, so (a) the recorder functions must lint clean under the
-    host-sync rule (no d2h sync can hide in a `record()` call), and
-    (b) running the index smoke config with tracing at DEBUG (every
-    span-commit recorded) must stay within a noise budget of tracing
-    OFF — interleaved best-of-2 windows per mode, same discipline as
-    bench.py --trace. A recorder that grew a sync point or a per-span
-    allocation storm fails here, on CPU, before any hardware run."""
-    from materialize_tpu.analysis import LintFinding
-    from materialize_tpu.analysis.host_sync import (
-        RECORDER_PATH,
-        _resolve,
-        lint_function,
-    )
-    from materialize_tpu.utils.trace import TRACER
-
-    findings = []
-    for mod, qn in RECORDER_PATH:
-        for f in lint_function(_resolve(mod, qn), where=qn):
-            findings.append(f)
-    import bench
-
-    spans, ticks = 3, 8
-    saved = TRACER.level
-
-    def window(level: str) -> float:
-        TRACER.set_level(level)
-        r = bench._trace_window(
-            "pipelined", bench._trace_smoke_config, spans, ticks, None
-        )
-        return r["ups"]
-
-    try:
-        from materialize_tpu.coord.freshness import FRESHNESS
-
-        FRESHNESS.clear()
-        window("off")  # warmup: compiles the span program family
-        ups = {"debug": [], "off": []}
-        for lvl in ("debug", "off", "debug", "off"):
-            ups[lvl].append(window(lvl))
-        traced, off = max(ups["debug"]), max(ups["off"])
-        # Freshness recording (ISSUE 15) rides the same span-commit
-        # path, so the timed windows above exercised it inside the
-        # same noise budget — but only if it actually recorded.
-        recorded = sum(
-            s["samples"] for s in FRESHNESS.summary().values()
-        )
-        if recorded == 0:
-            findings.append(
-                LintFinding(
-                    "trace-overhead", "freshness",
-                    "the timed windows recorded 0 wallclock-lag "
-                    "samples: SpanExecutor._complete no longer feeds "
-                    "the freshness recorder, so the overhead budget "
-                    "no longer covers it",
-                )
-            )
-        # Generous band: the recorder costs microseconds per span;
-        # only a structural regression (sync point, per-tick work)
-        # shows up as tens of percent. 1-core CI hosts are noisy.
-        BUDGET = 1.5
-        if traced * BUDGET < off:
-            findings.append(
-                LintFinding(
-                    "trace-overhead", "smoke",
-                    f"tracing at debug ran {off / traced:.2f}x slower "
-                    f"than off ({traced:.0f} vs {off:.0f} ups, budget "
-                    f"{BUDGET}x): the recorder path grew real per-span "
-                    "cost — look for a sync point or allocation on "
-                    "Tracer.record / LedgeredJit.__call__ / "
-                    "_commit_span",
-                )
-            )
-    except Exception as e:
-        findings.append(
-            LintFinding(
-                "trace-overhead", "driver",
-                f"trace overhead gate failed to run: {e!r}",
-            )
-        )
-    finally:
-        TRACER.set_level(saved)
-    gate("trace-overhead", None, findings, 0)
     return 1 if findings else 0
 
 

@@ -1,25 +1,16 @@
 #!/usr/bin/env python
-"""Export traces to Chrome trace-event JSON (load at perfetto.dev or
-chrome://tracing).
+"""Export span records to Chrome trace-event JSON (load at perfetto.dev
+or chrome://tracing).
 
-Two input shapes (ISSUE 12 tentpole d):
-
-  python scripts/trace_export.py TRACE.json [-o out.chrome.json]
-      Convert a ``bench.py --trace`` emission: each mode's per-span
-      records become complete ("X") events on a host row and a device
-      row, so the pipelined overlap (dispatch of span K+1 riding over
-      span K's readback wait) is VISIBLE as overlapping slices.
-
-  python scripts/trace_export.py --spans SPANS.json [-o out...]
+  python scripts/trace_export.py SPANS.json [-o out.chrome.json]
       Convert a span-record dump (the ``mz_trace_spans`` shape: a
       JSON array of {trace_id, span_id, parent_id, process, name,
       start_us, duration_us, ...}, or one such object a line, as the
       flight recorder writes ``$MZ_TRACE_DUMP_DIR/spans.jsonl``) into
       one row per process.
 
-The conversion functions are importable (bench.py --trace uses
-``bench_trace_to_chrome`` to emit its perfetto file next to the JSON;
-tests schema-check ``validate_chrome_trace``).
+The conversion functions are importable (tests schema-check
+``validate_chrome_trace``).
 """
 
 from __future__ import annotations
@@ -54,66 +45,6 @@ def _meta(pid, tid, what, label) -> dict:
         "pid": pid,
         "tid": tid,
         "args": {"name": label},
-    }
-
-
-def bench_trace_to_chrome(obj: dict) -> dict:
-    """``bench.py --trace`` JSON -> Chrome trace object. Host work
-    (gap + upload + dispatch) and device wait (readback) get separate
-    thread rows per mode; span timelines are reconstructed by
-    accumulating the per-span stage durations (the bench does not
-    record absolute stamps — relative layout preserves every duration
-    and the overlap structure that matters)."""
-    events: list = []
-    for pid, mode in enumerate(("pipelined", "serial")):
-        m = obj.get(mode)
-        if not m:
-            continue
-        events.append(_meta(pid, 0, "process_name", f"{mode} window"))
-        events.append(_meta(pid, 1, "thread_name", "host"))
-        events.append(_meta(pid, 2, "thread_name", "device-wait"))
-        cursor = 0.0
-        for rec in m.get("spans", ()):
-            t0 = cursor + rec.get("host_gap_ms", 0.0) * 1e3
-            up = rec.get("upload_ms", 0.0) * 1e3
-            disp = rec.get("dispatch_ms", 0.0) * 1e3
-            wait = (rec.get("readback_wait_ms") or 0.0) * 1e3
-            sync = rec.get("window_sync_ms", 0.0) * 1e3
-            label = f"span {rec.get('span')}"
-            if up:
-                events.append(
-                    _event(f"{label} upload", t0, up, pid, 1,
-                           ticks=rec.get("ticks"))
-                )
-            events.append(
-                _event(
-                    f"{label} dispatch", t0 + up, disp, pid, 1,
-                    ticks=rec.get("ticks"),
-                    donated=rec.get("donated"),
-                    overflow=rec.get("overflow"),
-                )
-            )
-            events.append(
-                _event(
-                    f"{label} readback-wait", t0 + up + disp, wait,
-                    pid, 2, readbacks=rec.get("readbacks"),
-                )
-            )
-            if sync:
-                events.append(
-                    _event(f"{label} window-sync", t0 + up + disp
-                           + wait, sync, pid, 2)
-                )
-            cursor = t0 + up + disp + wait + sync
-    return {
-        "traceEvents": events,
-        "displayTimeUnit": "ms",
-        "otherData": {
-            "source": "materialize_tpu bench.py --trace",
-            "config": obj.get("config"),
-            "backend": obj.get("backend"),
-            "trace_id": obj.get("trace_id"),
-        },
     }
 
 
@@ -195,19 +126,14 @@ def load_json_or_lines(path: str):
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("input", help="bench --trace JSON (default) or "
-                    "a span-record JSON array (--spans)")
-    ap.add_argument("--spans", action="store_true",
-                    help="input is an mz_trace_spans-shaped array")
+    ap.add_argument("input", help="a span-record JSON array, or one "
+                    "record a line")
     ap.add_argument("-o", "--output", default=None)
     args = ap.parse_args(argv)
     data = load_json_or_lines(args.input)
-    if args.spans and isinstance(data, dict):
+    if isinstance(data, dict):
         data = [data]  # a dump of one line
-    if args.spans or isinstance(data, list):
-        chrome = spans_to_chrome(data)
-    else:
-        chrome = bench_trace_to_chrome(data)
+    chrome = spans_to_chrome(data)
     problems = validate_chrome_trace(chrome)
     if problems:
         for p in problems:

@@ -435,9 +435,9 @@ class TestFailoverStorm:
 
     @pytest.mark.slow
     def test_smoke_two_replicas_in_process(self, tmp_path):
-        # The failover-smoke CI gate (scripts/check_plans.py --bench)
-        # runs this same storm in the tier-1 window; keep the pytest
-        # copy in the slow/chaos lane.
+        # scripts/check_plans.py --bench (`failover-smoke`) runs this
+        # same storm when someone runs that script; tier-1 runs
+        # neither: this copy is in the slow/chaos lane.
         from materialize_tpu.testing.chaos import run_failover_smoke
 
         rep = run_failover_smoke(str(tmp_path / "fo"), seed=1)

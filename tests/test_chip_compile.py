@@ -6,8 +6,11 @@ what the chip's compiler would raise (a kernel it refuses, a program
 that does not fit). These guard every later PR at no chip time; a pass
 is a compile, never a chip run.
 
-The shapes are the tiers ``chip_smoke.py`` reaches at its default scale
-factor (6,005 lineitem rows: run tier 2^13). Everything that touches
+The shapes are the tiers ``chip_smoke.py`` (the ``--chips 4``
+rehearsal) reaches at its default scale factor (6,005 lineitem rows:
+run tier 2^13). The benchmark's cells hydrate at 2^15-2^17 rows, where
+one program compiles for minutes: those are compiled by the benchmark's
+own cold run on the chip, not here. Everything that touches
 the topology lives in the module-scoped fixtures below — never at
 import, never ``autouse`` — and the compiles run in this process with
 the persistent compilation cache off (an entry written for a described

@@ -13,9 +13,10 @@ pytestmark = pytest.mark.analysis
 
 
 def test_hot_path_has_zero_findings():
-    """The registered per-span hot-path functions (dispatch, staging,
-    pipelined commit bookkeeping) lint clean — the CI gate
-    scripts/check_plans.py --bench enforces."""
+    """The registered per-span hot-path functions (dispatch, the
+    sinked span's prefetch, pipelined commit bookkeeping) lint clean.
+    ``scripts/check_plans.py --bench`` runs the same lint when someone
+    runs it; tier-1 runs it here."""
     from materialize_tpu.analysis import lint_hot_path
 
     findings = lint_hot_path()
@@ -88,9 +89,10 @@ def test_seeded_hazards_are_flagged(tmp_path):
 
 
 def test_check_plans_bench_gates_host_sync():
-    """The --bench CI lane includes the host-sync gate (source-level
-    check that the wiring exists; the full --bench run is exercised by
-    its own lane, not per-test — it traces TPCH programs)."""
+    """``scripts/check_plans.py --bench`` includes the host-sync gate:
+    a source-level check that the wiring exists. Nothing in tier-1
+    runs that script (it traces TPCH programs and drives storms); it
+    is run by hand (ROADMAP C18)."""
     path = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "scripts",

@@ -1141,14 +1141,12 @@ class TestFlightRecorder:
             "trace_id", "span_id", "parent_id", "process", "name",
             "level", "start_us", "duration_us", "attrs",
         }
-        # ... in the shape scripts/trace_export.py --spans takes
+        # ... in the shape scripts/trace_export.py takes
         sys.path.insert(0, os.path.join(REPO, "scripts"))
         import trace_export
 
         out = tmp_path / "spans.chrome.json"
-        assert trace_export.main(
-            ["--spans", str(path), "-o", str(out)]
-        ) == 0
+        assert trace_export.main([str(path), "-o", str(out)]) == 0
         chrome = json.loads(out.read_text())
         assert trace_export.validate_chrome_trace(chrome) == []
         events = [e for e in chrome["traceEvents"]

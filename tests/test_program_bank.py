@@ -301,8 +301,9 @@ class TestTierQuantization:
     def test_rung_mates_share_state_shapes(self):
         """Two DDLs whose capacities differ only within one pow2 rung
         render identical state shapes — the precondition for shared
-        bank keys (the end-to-end key-sharing proof runs in
-        scripts/check_plans.py --bench)."""
+        bank keys (the end-to-end key-sharing proof is the
+        `tier-quantization` gate of scripts/check_plans.py --bench,
+        which is run by hand, not by tier-1)."""
         import jax
 
         a = Dataflow(mir.Get("src", SCH), name="qa", state_cap=300)

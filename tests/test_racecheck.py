@@ -209,8 +209,8 @@ class TestServingPathClean:
         """The tier-1 control plane — DDL, ingest, fast/slow peeks,
         SUBSCRIBE delivery and teardown, introspection — produces no
         unsuppressed happens-before findings over the declared
-        shared-state set (the same drive as the check_plans --bench
-        `race-free` gate)."""
+        shared-state set (the same drive as the `race-free` gate of
+        scripts/check_plans.py --bench, which tier-1 does not run)."""
         import socket
         import time
 

@@ -289,46 +289,10 @@ def test_hash_spine_tail_larger_than_base():
         }
 
 
-def test_run_span_matches_run_steps():
-    """The one-dispatch span program (lax.scan chunks + traced
-    compactions) must produce exactly the per-step path's results —
-    same output arrangement, same deltas."""
-    from materialize_tpu.expr import relation as mir
-    from materialize_tpu.render.dataflow import Dataflow
-
-    rng = np.random.default_rng(11)
-    spans = []
-    for t in range(16):
-        n = 120
-        ks = rng.integers(0, 300, n)
-        vs = rng.integers(0, 3, n)
-        ds = rng.integers(-1, 2, n)
-        ds[ds == 0] = 1
-        spans.append({"L": _batch(ks, vs, ds, t=t, cap=256)})
-
-    df_a = Dataflow(mir.Get("L", SCH), state_cap=256)
-    df_a._compact_every = 4
-    df_a.run_steps(spans, defer_check=True)
-    df_a.check_flags()
-    a = sorted(df_a.peek())
-
-    df_b = Dataflow(mir.Get("L", SCH), state_cap=256)
-    df_b._compact_every = 4
-    deltas = df_b.run_span(spans)
-    assert deltas is not None
-    df_b.check_flags()
-    b = sorted(df_b.peek())
-    # Times may differ in compaction leaders? No: content-identical.
-    assert [r[:-2] + (r[-1],) for r in a] == [
-        r[:-2] + (r[-1],) for r in b
-    ]
-    assert df_b.time == df_a.time
-
-
 def test_multilevel_output_spine_oracle():
     """4-level geometric output spine under churn with retractions and
-    growth: peeks (full cascade) stay oracle-exact, and the in-span
-    geometric cadence (run_span) matches the per-step path."""
+    growth: peeks (full cascade) stay oracle-exact through the span
+    train's geometric fold cadence."""
     from materialize_tpu.expr import relation as mir
     from materialize_tpu.render.dataflow import Dataflow
 
@@ -357,16 +321,6 @@ def test_multilevel_output_spine_oracle():
     for r in df.peek():
         got[r[:-2]] = got.get(r[:-2], 0) + r[-1]
     assert {k: d for k, d in got.items() if d} == oracle
-
-    df2 = Dataflow(mir.Get("L", SCH), state_cap=256, out_levels=4)
-    df2._compact_every = 4
-    df2._compact_ratio = 2
-    df2.run_span(spans)
-    df2.check_flags()
-    got2: dict = {}
-    for r in df2.peek():
-        got2[r[:-2]] = got2.get(r[:-2], 0) + r[-1]
-    assert {k: d for k, d in got2.items() if d} == oracle
 
 
 def test_host_presort_matches_device_order():
@@ -411,7 +365,7 @@ def test_host_presort_matches_device_order():
 def test_append_slot_spine_oracle():
     """Append-slot ingest ring: O(delta) per-step inserts into slot
     batches, flushed into run 0 at the level-0 fold. Oracle-exact
-    under churn with retractions and growth, per-step and span paths."""
+    under churn with retractions and growth."""
     from materialize_tpu.expr import relation as mir
     from materialize_tpu.render.dataflow import Dataflow
 
@@ -430,20 +384,15 @@ def test_append_slot_spine_oracle():
         spans.append({"L": _batch(ks, vs, ds, t=t, cap=256)})
     oracle = {k: d for k, d in oracle.items() if d}
 
-    for runner in ("steps", "span"):
-        df = Dataflow(
-            mir.Get("L", SCH), state_cap=256, out_levels=3,
-            out_slots=4,
-        )
-        df._compact_every = 4
-        df._compact_ratio = 2
-        assert df.output.slots and len(df.output.slots) == 4
-        if runner == "steps":
-            df.run_steps(spans, defer_check=True)
-        else:
-            df.run_span(spans)
-        df.check_flags()
-        got: dict = {}
-        for r in df.peek():
-            got[r[:-2]] = got.get(r[:-2], 0) + r[-1]
-        assert {k: d for k, d in got.items() if d} == oracle, runner
+    df = Dataflow(
+        mir.Get("L", SCH), state_cap=256, out_levels=3, out_slots=4,
+    )
+    df._compact_every = 4
+    df._compact_ratio = 2
+    assert df.output.slots and len(df.output.slots) == 4
+    df.run_steps(spans, defer_check=True)
+    df.check_flags()
+    got: dict = {}
+    for r in df.peek():
+        got[r[:-2]] = got.get(r[:-2], 0) + r[-1]
+    assert {k: d for k, d in got.items() if d} == oracle

@@ -1,21 +1,23 @@
-"""Pipelined span execution (ISSUE 7): the double-buffered, donated
-span executor must be row-for-row equal to serial execution under
-duplicate/retraction churn and mid-span peeks, and must never read a
-donated buffer after handoff (the checkpoint-clone contract)."""
+"""A view's span train (``MaintainedView.step_span`` over
+``run_steps(defer_check=True)``): the pipelined index-view path must be
+row-for-row equal to serial stepping under duplicate/retraction churn
+and mid-span peeks, a donated window must never read a donated buffer
+after handoff (the checkpoint-clone contract), and a sinked span's
+prefetch must write the per-tick shard."""
 
 import numpy as np
 import pytest
 
 from materialize_tpu.expr import relation as mir
 from materialize_tpu.render.dataflow import Dataflow
-from materialize_tpu.render.span_exec import SpanExecutor
 from materialize_tpu.repr.batch import Batch
 from materialize_tpu.repr.schema import Column, ColumnType, Schema
+from materialize_tpu.utils.dyncfg import COMPUTE_CONFIGS
 
 SCH = Schema(
     (Column("k", ColumnType.INT64), Column("v", ColumnType.INT64))
 )
-K = 8  # ticks per span (multiple of _compact_every below)
+K = 8  # ticks per span
 
 
 def _mk(state_cap=1 << 14, slots=4, **kw):
@@ -28,134 +30,11 @@ def _mk(state_cap=1 << 14, slots=4, **kw):
     return df
 
 
-def _churn_spans(seed: int, n_spans: int, n_rows=64, keyspace=512):
-    """Deterministic duplicate/retraction churn: ~25% retractions,
-    heavy key reuse (duplicates across and within ticks)."""
-    rng = np.random.default_rng(seed)
-    spans = []
-    t = 0
-    for _s in range(n_spans):
-        sp = []
-        for _i in range(K):
-            k = rng.integers(0, keyspace, n_rows).astype(np.int64)
-            v = rng.integers(0, 16, n_rows).astype(np.int64)
-            d = rng.choice(
-                np.asarray([1, 1, 1, -1]), n_rows
-            ).astype(np.int64)
-            sp.append(
-                {
-                    "src": Batch.from_numpy(
-                        SCH, [k, v], np.uint64(t), d, capacity=256
-                    )
-                }
-            )
-            t += 1
-        spans.append(sp)
-    return spans
-
-
 def _accum(rows):
     acc: dict = {}
     for r in rows:
         acc[r[:-2]] = acc.get(r[:-2], 0) + r[-1]
     return {k: d for k, d in acc.items() if d}
-
-
-def test_pipelined_equals_serial_under_churn():
-    """Row-for-row equivalence: the same churn through (a) serial
-    synchronous run_steps and (b) the pipelined, donated executor."""
-    spans_a = _churn_spans(7, 6)
-    spans_b = _churn_spans(7, 6)
-
-    df_ser = _mk()
-    for sp in spans_a:
-        df_ser.run_steps(sp)
-
-    df_pip = _mk()
-    ex = SpanExecutor(df_pip, donate=True)
-    for sp in spans_b:
-        ex.submit(sp)
-    ex.close()
-
-    assert _accum(df_ser.peek()) == _accum(df_pip.peek())
-    st = ex.stats()
-    assert st["readbacks_per_span"] == 1.0
-    assert st["spans_committed"] == 6
-
-
-def test_mid_span_peeks_see_committed_boundaries():
-    """A peek admitted while a span is in flight sequences to a
-    committed span boundary (the barrier syncs first) and matches the
-    serial result at the same boundary — never a half-applied carry."""
-    spans_a = _churn_spans(11, 4)
-    spans_b = _churn_spans(11, 4)
-
-    df_ser = _mk()
-    serial_at = []
-    for sp in spans_a:
-        df_ser.run_steps(sp)
-        serial_at.append(_accum(df_ser.peek()))
-
-    df_pip = _mk()
-    ex = SpanExecutor(df_pip, donate=True)
-    pipelined_at = {}
-    for i, sp in enumerate(spans_b):
-        ex.submit(sp)
-        if i % 2 == 1:
-            # Mid-pipeline peek: span i is in flight; the barrier
-            # must commit it before the read.
-            pipelined_at[i] = _accum(df_pip.peek())
-            assert df_pip.time == (i + 1) * K
-    ex.close()
-    for i, got in pipelined_at.items():
-        assert got == serial_at[i], f"mismatch at boundary {i}"
-    assert ex.boundary_syncs >= len(pipelined_at)
-
-
-def test_donation_checkpoint_is_cloned():
-    """Donation safety: with donation on, the rollback checkpoint's
-    device leaves are FRESH buffers (clones), never references into
-    the donated carry — reading a donated buffer after handoff would
-    crash on TPU and silently alias on CPU."""
-    import jax
-
-    df = _mk()
-    ex = SpanExecutor(df, donate=True)
-    live_before = jax.tree_util.tree_leaves(
-        (tuple(df.states), df.output, df.err_output)
-    )
-    live_ids = {id(x) for x in live_before}
-    ex.submit(_churn_spans(3, 1)[0])
-    ck = df._defer_ck
-    assert ck is not None
-    ck_leaves = jax.tree_util.tree_leaves((tuple(ck[0]), ck[1], ck[2]))
-    overlap = [x for x in ck_leaves if id(x) in live_ids]
-    assert not overlap, (
-        "checkpoint references the donated carry: "
-        f"{len(overlap)} shared buffers"
-    )
-    ex.close()
-
-
-def test_overflow_rolls_back_and_replays_with_donation():
-    """An overflow mid-window (undersized tiers) must roll back to the
-    CLONED checkpoint, grow, replay, and still match serial — the
-    checkpoint survives donation of the live carry."""
-    spans_a = _churn_spans(23, 4, n_rows=96)
-    spans_b = _churn_spans(23, 4, n_rows=96)
-
-    df_ser = _mk(state_cap=1 << 14)
-    for sp in spans_a:
-        df_ser.run_steps(sp)
-
-    # Deliberately tiny base run: the compaction cascade overflows it
-    # within the window.
-    df_pip = _mk(state_cap=256)
-    ex = SpanExecutor(df_pip, donate=True)
-    for sp in spans_b:
-        ex.submit(sp)
-    ex.close()
-    assert _accum(df_ser.peek()) == _accum(df_pip.peek())
 
 
 def _churn_ticks(seed: int, n: int, n_rows=32, keyspace=64):
@@ -175,6 +54,177 @@ def _feed(w, t, tick):
     w.compare_and_append(
         [k, v], [None, None], np.full(len(d), t, np.uint64), d, t, t + 1
     )
+
+
+@pytest.fixture
+def donation(request):
+    """``span_donation`` for one test: 'on' REQUESTS a donated carry
+    (the cloned window checkpoint and the prover's verdict engage on
+    any backend; the argnums follow the backend), 'off' never does."""
+    before = COMPUTE_CONFIGS.current()["span_donation"]
+    COMPUTE_CONFIGS.update({"span_donation": request.param})
+    yield request.param == "on"
+    COMPUTE_CONFIGS.update({"span_donation": before})
+
+
+both_donations = pytest.mark.parametrize(
+    "donation", ["on", "off"], indirect=True,
+    ids=["donation_requested", "donation_not_requested"],
+)
+donation_requested = pytest.mark.parametrize(
+    "donation", ["on"], indirect=True, ids=["donation_requested"]
+)
+
+
+def _sinked(ticks, name="mv", shard="out", **kw):
+    """A sinked view installed over an empty source shard, which then
+    receives ``ticks``: nothing absorbed, all of it backlog."""
+    from materialize_tpu.storage.persist import (
+        MaintainedView,
+        MemBlob,
+        MemConsensus,
+        PersistClient,
+    )
+
+    client = PersistClient(MemBlob(), MemConsensus())
+    w = client.open_writer("src", SCH)
+    view = MaintainedView(
+        client, _mk(name=name, **kw), {"src": ("src", SCH)}, shard
+    )
+    assert view.upper == 0
+    for t, tick in enumerate(ticks):
+        _feed(w, t, tick)
+    return client, w, view
+
+
+def _index_view(ticks, **kw):
+    """The same with no sink: an index view, which ``step_span``
+    pipelines."""
+    return _sinked(ticks, shard=None, **kw)[2]
+
+
+def _serial(ticks, at=()):
+    """The per-tick ``step`` over the same ticks: the final peek, and
+    the peek at each upper in ``at``."""
+    view = _index_view(ticks)
+    seen = {}
+    for _ in ticks:
+        assert view.step(timeout=0)
+        if view.upper in at:
+            seen[view.upper] = _accum(view.peek())
+    return _accum(view.peek()), seen
+
+
+def _pipeline(view, n, on_span=None):
+    """Dispatch spans of K until ``n`` ticks are in, one in flight."""
+    while view._dispatched < n:
+        assert view.step_span(max_ticks=K, timeout=0)
+        if on_span is not None:
+            on_span()
+
+
+@both_donations
+def test_pipelined_equals_serial_under_churn(donation):
+    """Row-for-row equivalence: the same churn through the per-tick
+    step and through pipelined spans, one flags readback a committed
+    span."""
+    ticks = _churn_ticks(7, 6 * K, n_rows=64, keyspace=512)
+    view = _index_view(ticks)
+    assert bool(view.donated_parts) == donation
+    epoch, readbacks = view.span_epoch, view.df._readbacks
+    _pipeline(view, len(ticks))
+    assert view._inflight_span is not None  # the sixth, uncommitted
+    view.sync_spans()
+    assert view.upper == len(ticks)
+    assert view.span_epoch - epoch == 6
+    assert view.df._readbacks - readbacks == 6
+    assert _accum(view.peek()) == _serial(ticks)[0]
+
+
+@donation_requested
+def test_mid_span_peeks_see_committed_boundaries(donation):
+    """A peek admitted while a span is in flight sequences to a
+    committed span boundary (the barrier commits it first) and matches
+    the serial result at the same boundary, never a half-applied
+    carry: through the view's own peek and through a read of the
+    dataflow behind it (``span_barrier``)."""
+    ticks = _churn_ticks(11, 4 * K, n_rows=64, keyspace=512)
+    view = _index_view(ticks)
+    peeked = {}
+
+    def peek_every_other_span():
+        if view._dispatched % (2 * K):
+            return
+        assert view._inflight_span is not None
+        assert view.upper == view._dispatched - K
+        read = view.peek if view._dispatched == 2 * K else view.df.peek
+        peeked[view._dispatched] = _accum(read())
+        assert view._inflight_span is None
+        assert view.upper == view._dispatched
+
+    _pipeline(view, len(ticks), peek_every_other_span)
+    assert sorted(peeked) == [2 * K, 4 * K]
+    assert peeked == _serial(ticks, at=peeked)[1]
+
+
+def test_donation_checkpoint_is_cloned():
+    """Donation safety: a window dispatched with donation requested
+    rolls back to FRESH buffers (clones), never to references into
+    the donated carry; reading a donated buffer after handoff would
+    crash on TPU and silently alias on CPU. An un-donated window's
+    checkpoint IS the carry it left behind."""
+    import jax
+
+    def carry(df):
+        return jax.tree_util.tree_leaves(
+            (tuple(df.states), df.output, df.err_output)
+        )
+
+    span = [
+        {
+            "src": Batch.from_numpy(
+                SCH, [k, v], np.uint64(t), d, capacity=256
+            )
+        }
+        for t, (k, v, d) in enumerate(_churn_ticks(3, K))
+    ]
+    shared = {}
+    for donate in (True, False):
+        df = _mk()
+        live = {id(x) for x in carry(df)}
+        df.run_steps(span, defer_check=True, donate=donate)
+        ck = df._defer_ck
+        assert ck is not None
+        ck_leaves = jax.tree_util.tree_leaves(
+            (tuple(ck[0]), ck[1], ck[2])
+        )
+        shared[donate] = sum(id(x) in live for x in ck_leaves)
+        assert not df.check_flags()
+    assert shared[True] == 0, "checkpoint references the donated carry"
+    assert shared[False] > 0
+
+
+@both_donations
+def test_overflow_rolls_back_and_replays(donation):
+    """An overflow mid-window (undersized tiers) must roll back to the
+    window's checkpoint (the CLONE where donation is requested: it
+    survives donation of the live carry), grow, replay, and still
+    match serial."""
+    ticks = _churn_ticks(23, 4 * K, n_rows=96, keyspace=512)
+    # Deliberately tiny base run (merge-mode ingest, so it is folded
+    # into every 16 ticks): the compaction cascade overflows it within
+    # the window.
+    view = _index_view(ticks, state_cap=256, slots=0)
+    grown = []
+    grow_for = view.df._grow_for
+    view.df._grow_for = lambda key, *a: (
+        grown.append(key), grow_for(key, *a)
+    )[1]
+    _pipeline(view, len(ticks))
+    view.sync_spans()
+    assert grown, "no tier overflowed: nothing was rolled back"
+    assert view.upper == len(ticks)
+    assert _accum(view.peek()) == _serial(ticks)[0]
 
 
 def test_maintained_view_step_span_matches_step(tmp_path):
@@ -248,27 +298,6 @@ def test_maintained_view_step_span_matches_step(tmp_path):
 # A sinked view (``writer`` set) over a backlog: what ``_step_span_sync``
 # keeps for the span after it is the view's, wherever it steps next,
 # and the sink shard is the one the per-tick path writes.
-
-
-def _sinked(ticks, name="mv", **kw):
-    """A sinked view installed over an empty source shard, which then
-    receives ``ticks``: nothing absorbed, all of it backlog."""
-    from materialize_tpu.storage.persist import (
-        MaintainedView,
-        MemBlob,
-        MemConsensus,
-        PersistClient,
-    )
-
-    client = PersistClient(MemBlob(), MemConsensus())
-    w = client.open_writer("src", SCH)
-    view = MaintainedView(
-        client, _mk(name=name, **kw), {"src": ("src", SCH)}, "out"
-    )
-    assert view.upper == 0
-    for t, tick in enumerate(ticks):
-        _feed(w, t, tick)
-    return client, w, view
 
 
 def _shard(client, shard="out", ordered=True):
