@@ -184,6 +184,12 @@ def scan_view(report: ProvenanceReport, name: str, view) -> None:
             report.add_root(
                 f"{name}/history[t={t}]", PROV_HOST, upd
             )
+    # A sinked span validated and not yet written: its deltas stay on
+    # the device beneath the NEXT span's (donated) dispatch, as the
+    # history's do. Outputs of their own steps, never carry leaves.
+    validated = getattr(view, "_validated_span", None)
+    for t, upd in validated[1] if validated else ():
+        report.add_root(f"{name}/validated[t={t}]", PROV_HOST, upd)
     for si, sub in enumerate(getattr(view, "_subscribers", ())):
         if not getattr(sub, "_device", False):
             continue  # host-path subscribers copy through numpy

@@ -468,7 +468,9 @@ class _ViewSpanBarrier:
     commit the view's in-flight span first, instead of relying only
     on the view-level ``sync_spans()`` call sites. ``in_dispatch`` is
     raised around the view's own span dispatch so dispatching never
-    self-syncs (which would serialize the double buffer). The pieces
+    self-syncs (which would serialize the index view's double buffer,
+    and write a sinked view's validated span before the dispatch it
+    is meant to run beneath). The pieces
     the view's pipeline stands on (flags snapshots, one-readback
     commit, window rollback) live in ``_DataflowBase``."""
 
@@ -565,6 +567,13 @@ class MaintainedView:
         # committed. The sources' frontiers run ahead of `upper` by
         # these, so every stepping entry consumes them first.
         self._kept: list = []
+        # A sinked span whose flags are read and whose deltas are not
+        # yet written (_step_span_sync leaves one only while `_kept`
+        # holds its successor, which the next call dispatches before
+        # writing this one): (span record, [(t, delta)], arrival
+        # stamp, replayed, prefetched). `_dispatched` runs ahead of
+        # `upper` by its ticks; sync_spans() writes it.
+        self._validated_span = None
         self._capacity_gauge = REGISTRY.get_or_create(
             "gauge_vec", "mz_dataflow_state_capacity_bytes",
             "bytes of device memory a maintained view's operator state "
@@ -719,6 +728,11 @@ class MaintainedView:
         """Release this view's shard read holds (must be called when the
         view is dropped or replaced, or the holds pin compaction forever)."""
         self._kept = []
+        if self._validated_span is not None:
+            # validated, never written: whoever resumes from the
+            # durable upper computes it again from the sources
+            self._validated_span = None
+            self._dispatched = self._upper
         for s in self.sources.values():
             try:
                 s.reader.expire()
@@ -1044,16 +1058,19 @@ class MaintainedView:
 
     def _close_span(
         self, span, ticks: int, replayed: bool = False,
-        prefetched: int = 0,
+        prefetched: int = 0, overlapped: int = 0,
     ):
         """``prefetched``: how many of the span's ticks the span before
-        it had gathered (``_kept``) while the device ran. The bytes
-        the view's operator state and output spine reserve on the
-        device ride along (shapes, no device read)."""
+        it had gathered (``_kept``) while the device ran.
+        ``overlapped``: how many were written with the span after it
+        already dispatched. The bytes the view's operator state and
+        output spine reserve on the device ride along (shapes, no
+        device read)."""
         reserved = self.df.state_capacity_bytes()
         TRACER.close(
             span, upper=self._upper, ticks=ticks, epoch=self.span_epoch,
             replayed=replayed, prefetched_ticks=prefetched,
+            overlapped_commit_ticks=overlapped,
             state_capacity_bytes=reserved,
         )
         self._capacity_gauge.set(
@@ -1124,11 +1141,17 @@ class MaintainedView:
     # Index (sink-less) views: span K+1's ingest AND dispatch — the
     # commit readback for span K runs after span K+1 is already queued
     # on device (double buffering, at most one span in flight ahead of
-    # the committed frontier). Sinked views: span K+1's ingest only
-    # (wait, fetch, upload: _prefetch_ticks) — K's flags are read and
-    # its deltas appended before K+1 is dispatched, so commit order
-    # and what a rollback undoes are the per-tick path's. Peeks, AS OF
-    # reads, and subscriber snapshots sequence against COMMITTED span
+    # the committed frontier). Sinked views: span K+1's ingest (wait,
+    # fetch, upload: _prefetch_ticks) and, over a backlog, span K's
+    # copy-out and appends — K's flags are read before K+1 is
+    # dispatched, so K's deltas are final, K+1's rollback window
+    # opens on K's validated carry and what a rollback undoes is the
+    # per-tick path's; with K+1's ticks already kept, K+1 is
+    # dispatched BEFORE K is written and the host writes K while the
+    # device runs K+1 (a validated, unwritten span is all that ever
+    # crosses a call: _validated_span). With nothing kept K is
+    # written at once, as on the per-tick path. Peeks, AS OF reads,
+    # and subscriber snapshots sequence against COMMITTED span
     # boundaries via sync_spans() — they can never observe a
     # half-applied carry.
 
@@ -1196,13 +1219,17 @@ class MaintainedView:
     def step_span(
         self, max_ticks: int | None = None, timeout: float = 0.0
     ) -> bool:
-        """Span-batched stepping. Sinked and SPMD views commit
-        synchronously at the span boundary (durability needs the
-        deltas host-side, SPMD gathers them per tick) and gather the
-        next span's inputs while the device runs this one; index views
-        pipeline (deferred commit). Views the span protocol cannot
-        cover — pure constants, basic-aggregate sinks (per-step
-        multiset captures) — fall back to the per-tick step."""
+        """Span-batched stepping. Sinked and SPMD views validate at
+        the span boundary (durability needs the deltas host-side, SPMD
+        gathers them per tick), gather the next span's inputs while
+        the device runs this one and, when that gather found ticks,
+        leave this span's writes to the next call, which dispatches
+        those ticks first (``upper`` then trails ``_dispatched`` by
+        one validated span until the next call or ``sync_spans()``);
+        index views pipeline (deferred commit). Views the span
+        protocol cannot cover — pure constants, basic-aggregate sinks
+        (per-step multiset captures) — fall back to the per-tick
+        step."""
         from ...render.dataflow import Dataflow as _SingleDevice
         from ...utils.dyncfg import COMPUTE_CONFIGS, SPAN_MAX_TICKS
 
@@ -1283,11 +1310,17 @@ class MaintainedView:
 
     def _step_span_sync(self, max_ticks: int, timeout: float) -> bool:
         """Sinked span: dispatch every ready tick asynchronously,
-        gather the next span's ready ticks while the device works, ONE
-        flags readback (check_flags — replays on overflow), then the
-        per-tick durable appends from validated deltas."""
-        self.sync_spans()
-        lower = self.upper
+        write the span before this one if it is still unwritten,
+        gather the next span's ready ticks, ONE flags readback
+        (check_flags — replays on overflow), then the per-tick durable
+        appends from validated deltas: now if nothing was gathered,
+        else under the next span's dispatch. The span before is
+        unwritten only if its successor's ticks were kept, so the
+        host writes while the device works and a view that keeps up
+        with its sources writes every span in the call that ran it."""
+        if self._validated_span is None or not self._kept:
+            self.sync_spans()
+        lower = self._dispatched
         span = self._open_span(lower)
         with TRACER.within(span):
             ticks, prefetched = self._take_ready_ticks(
@@ -1297,26 +1330,59 @@ class MaintainedView:
                 return False
             if self.df.time != ticks[0][0]:
                 self.df.time = ticks[0][0]
-            deltas = self.df.run_steps(
-                [inp for _, inp, _ in ticks],
-                defer_check=True,
-                donate=self._span_donation(),
-            )
-            # The device runs this span; the host gathers the next.
-            self._prefetch_ticks(ticks[-1][0] + 1, max_ticks)
+            # Our own dispatch must not flush the span before through
+            # the registered span barrier: it is written beneath it.
+            self._barrier.in_dispatch = True
+            try:
+                deltas = self.df.run_steps(
+                    [inp for _, inp, _ in ticks],
+                    defer_check=True,
+                    donate=self._span_donation(),
+                )
+            finally:
+                self._barrier.in_dispatch = False
+            self._dispatched = ticks[-1][0] + 1
+            # The device runs this span; the host writes the one
+            # before it and gathers the one after.
+            if self._validated_span is not None:
+                self._commit_validated(overlapped=True)
+            self._prefetch_ticks(self._dispatched, max_ticks)
             with TRACER.phase("span.readback"):
                 replayed = self.df.check_flags()
             if replayed:
                 deltas = self.df.replayed_deltas
-            lo = lower
-            for (t, _, _), out in zip(ticks, deltas):
+        self._validated_span = (
+            span, [(t, out) for (t, _, _), out in zip(ticks, deltas)],
+            ticks[-1][2], replayed, prefetched,
+        )
+        if not self._kept:
+            self._commit_validated(overlapped=False)
+        return True
+
+    def _commit_validated(self, overlapped: bool) -> None:
+        """Write the validated span, tick by tick, under its own span
+        record. ``overlapped``: the span after it is on the device."""
+        span, entries, arrived, replayed, prefetched = (
+            self._validated_span
+        )
+        self._validated_span = None
+        lo = self._upper
+        if overlapped:
+            from ...analysis.donation import guard_read
+
+            # read beneath a later (donated) dispatch: under the
+            # buffer sanitizer, prove no delta is a donated carry leaf
+            guard_read(entries, "validated span")
+        with TRACER.within(span):
+            for t, out in entries:
                 self._commit_tick(t, out, lo)
                 lo = t + 1
-            self._dispatched = lo
             self.span_epoch += 1
-            self._record_freshness(lo, ticks[-1][2])
-        self._close_span(span, len(ticks), replayed, prefetched)
-        return True
+            self._record_freshness(lo, arrived)
+        self._close_span(
+            span, len(entries), replayed, prefetched,
+            overlapped=len(entries) if overlapped else 0,
+        )
 
     def _step_span_pipelined(
         self, max_ticks: int, timeout: float
@@ -1439,10 +1505,12 @@ class MaintainedView:
         self.span_epoch += 1
 
     def sync_spans(self) -> None:
-        """The read barrier: complete + commit the in-flight span, so
+        """The read barrier: complete + commit the in-flight span (an
+        index view's) or write the validated one (a sinked view's), so
         callers (peeks, AS OF reads, subscriber snapshots, DML)
         observe a committed span boundary — never a half-applied
-        carry. No-op when nothing is in flight, and exactly ONE
+        carry, never a frontier behind the carry. No-op when nothing
+        is in flight, and exactly ONE
         readback otherwise: the boundary commit's clean snapshot
         already proves every span <= it valid (flags OR-accumulate),
         so the serving path never pays a second validation round trip
@@ -1451,6 +1519,10 @@ class MaintainedView:
         df-level reader forces it."""
         if self._inflight_span is not None:
             self._commit_inflight()
+        if self._validated_span is not None:
+            # a sinked span that waited for its successor's dispatch:
+            # a copy-out and appends, never a replay
+            self._commit_validated(overlapped=False)
 
     def _publish(self, t: int, out: Batch) -> None:
         """Push this step's output delta to index-import subscribers

@@ -894,6 +894,7 @@ class TestMaintenancePhases:
         assert span.attrs["epoch"] == view.span_epoch
         assert span.attrs["replayed"] is False
         assert span.attrs["prefetched_ticks"] == 0
+        assert span.attrs["overlapped_commit_ticks"] == 0
         assert set(kids) == PHASES_SINKED
         assert sum(k.duration for k in kids.values()) <= span.duration
         for k in kids.values():
@@ -967,6 +968,20 @@ class TestMaintenancePhases:
         assert kids2["span.wait"].attrs["n"] == 1
         assert kids2["span.append"].attrs["rows"] == 3
         assert view.upper == 8 and view._kept == []
+        # the first span was written in the second call, beneath the
+        # second's dispatch, under its OWN record: that one closes
+        # when its commit is durable, so the two records overlap and
+        # each times its own copy-out, append and publish
+        assert first.attrs["overlapped_commit_ticks"] == 3
+        assert second.attrs["overlapped_commit_ticks"] == 0
+        assert (first.attrs["upper"], second.attrs["upper"]) == (5, 8)
+        assert kids1["span.append"].attrs["rows"] == 3
+        assert kids1["span.append"].attrs["cas_attempts"] == 3
+        assert first.start + first.duration > second.start
+        assert (
+            kids1["span.append"].start
+            > kids2["span.dispatch"].start
+        )
 
     def test_fetch_counts_grow_with_the_shards_age(self, tracer):
         view, w = _kv_view(4)

@@ -3,9 +3,11 @@
 ``_dispatch_span``, through plans with a reduce (TPC-H Q1) and a join
 under two reduces (Q15) on RF1/RF2-style churn, against the per-tick
 ``step`` over the same ticks: prefetch over a backlog, an overflow
-replayed inside a span, the pipelined index path, and what every
-committed span leaves behind (its flags readback, one freshness
-sample, programs of three kinds)."""
+replayed inside a span, the order in which a sinked span over a
+backlog is written (beneath its successor's dispatch) and what that
+order keeps, the pipelined index path, and what every committed span
+leaves behind (its flags readback, one freshness sample, programs of
+three kinds)."""
 
 import functools
 
@@ -140,6 +142,7 @@ def test_sinked_spans_over_a_backlog_write_the_per_tick_shard(
     spans = span_records(name)
     assert [s["ticks"] for s in spans] == [8, 8, 5]
     assert [s["prefetched_ticks"] for s in spans] == [0, 8, 5]
+    assert [s["overlapped_commit_ticks"] for s in spans] == [8, 8, 0]
     assert not any(s["replayed"] for s in spans)
 
 
@@ -157,6 +160,243 @@ def test_an_overflow_inside_a_span_replays_it_with_the_next_one_kept(
     # the replay left what the span had gathered for the next one
     after = spans[spans.index(replayed[0]) + 1]
     assert after["prefetched_ticks"] == after["ticks"]
+
+
+# -- the order in which a sinked span is written ----------------------------
+
+
+def _calls(view):
+    """Every ``run_steps``, ``_prefetch_ticks``, ``check_flags`` and
+    sink ``compare_and_append`` of the view from now on, in order:
+    "run", "gather", "check" or the chunk ``(lower, upper)``; a check
+    that replayed is "replay"."""
+    calls: list = []
+    df, writer = view.df, view.writer
+    run_steps, check_flags = df.run_steps, df.check_flags
+    gather, caa = view._prefetch_ticks, writer.compare_and_append
+
+    def spy_run(*a, **kw):
+        calls.append("run")
+        return run_steps(*a, **kw)
+
+    def spy_check():
+        i = len(calls)
+        calls.append("check")
+        if check_flags():
+            calls[i] = "replay"
+            return True
+        return False
+
+    def spy_gather(*a):
+        calls.append("gather")
+        return gather(*a)
+
+    def spy_caa(cols, nulls, time, diff, lower, upper):
+        # before the call: a chunk that raises was still attempted
+        calls.append((lower, upper))
+        return caa(cols, nulls, time, diff, lower, upper)
+
+    df.run_steps, df.check_flags = spy_run, spy_check
+    view._prefetch_ticks = spy_gather
+    writer.compare_and_append = spy_caa
+    return calls
+
+
+def _chunks(lo, up):
+    return [(t, t + 1) for t in range(lo, up)]
+
+
+def _never_ahead_of_the_shard(client, view, name):
+    """``upper`` and the freshness record trail the durable upper."""
+    durable = client.machine("out").reload().upper
+    assert view.upper <= durable
+    recorded = [
+        frontier
+        for df, _r, frontier, _lag, _at in FRESHNESS.history_rows()
+        if df == name
+    ]
+    assert all(f <= durable for f in recorded)
+    return durable
+
+
+@plans
+def test_a_backlog_span_is_written_beneath_its_successors_dispatch(
+    plan, served, per_tick
+):
+    name = f"{plan}_order"
+    client, view = served(plan, name)
+    calls = _calls(view)
+    uppers = []
+    while view._dispatched < TICKS + 1:
+        assert _step_span(view)
+        assert view.df._defer_ck is None  # validated before it returns
+        uppers.append((view.upper, view._dispatched))
+        assert _never_ahead_of_the_shard(client, view, name) == view.upper
+    # the successor is on the device before the first append of the
+    # span before it; its flags are read after the last; the last
+    # span, with nothing gathered behind it, is written at once
+    assert calls == (
+        ["run", "gather", "check"]
+        + ["run"] + _chunks(0, 8) + ["gather", "check"]
+        + ["run"] + _chunks(8, 16) + ["gather", "check"]
+        + _chunks(16, 21)
+    )
+    assert uppers == [(0, 8), (8, 16), (21, 21)]
+    assert view._validated_span is None and view._kept == []
+    assert not _step_span(view)
+    assert _shard(client, ordered=False) == per_tick(plan)[0]
+
+
+@plans
+def test_an_overflow_in_the_successor_replays_the_successor_alone(
+    plan, served, per_tick
+):
+    name = f"{plan}_late_overflow"
+    client, view = served(plan, name)
+    assert _step_span(view)  # 0-7 validated, 8-15 kept
+    assert (view.upper, view._dispatched) == (0, 8)
+    # the tier is cut with span 0 validated against the full one
+    UNDERSIZED[plan](view.df._ctx)
+    view.df._remake_jit()
+    calls = _calls(view)
+    _step_spans(view)
+    replays = [i for i, c in enumerate(calls) if c == "replay"]
+    assert replays, "no span was replayed"
+    # span 0 was written, once, before the flags that replayed span 1
+    # were read, and the replay made the 8 deltas of span 1 only
+    assert calls[: replays[0] + 1] == (
+        ["run"] + _chunks(0, 8) + ["gather", "replay"]
+    )
+    assert len(view.df.replayed_deltas) in (8, 5)
+    written = [c for c in calls if isinstance(c, tuple)]
+    assert written == _chunks(0, TICKS + 1)  # each chunk once, in order
+    assert _shard(client, ordered=False) == per_tick(plan)[0]
+    assert _accum(view.peek()) == per_tick(plan)[1]
+
+
+@plans
+def test_a_sink_conflict_under_the_successor_ends_in_an_exact_rebuild(
+    plan, served, per_tick
+):
+    from materialize_tpu.storage.persist.operators import SinkConflict
+
+    name = f"{plan}_conflict"
+    client, view = served(plan, name)
+    assert _step_span(view)
+    calls = _calls(view)
+    caa = view.writer.compare_and_append
+
+    def conflict(cols, nulls, time, diff, lower, upper):
+        if lower == 3:
+            raise SinkConflict("a sibling's chunking won")
+        return caa(cols, nulls, time, diff, lower, upper)
+
+    view.writer.compare_and_append = conflict
+    with pytest.raises(SinkConflict):
+        _step_span(view)
+    # raised with span 1 dispatched and unvalidated; 0-2 are durable
+    assert calls == ["run"] + _chunks(0, 3)
+    assert view.df._defer_ck is not None
+    assert _never_ahead_of_the_shard(client, view, name) == 3
+    # what the replica does with it (_rebuild_cascade): the view and
+    # its dataflow go, span 1 with them; a fresh one resumes from the
+    # durable upper
+    view.expire()
+    assert view._validated_span is None and view._kept == []
+    mk, sources = PLANS[plan]
+    fresh = MaintainedView(
+        client, Dataflow(mk(), name=name),
+        {s: (s, SCHEMAS[s]) for s in sources}, "out",
+    )
+    assert fresh.upper == 3
+    _step_spans(fresh)
+    got = _shard(client, ordered=False)
+    assert got[0][:2] == (0, 1)
+    assert got == per_tick(plan)[0]
+    assert _accum(fresh.peek()) == per_tick(plan)[1]
+
+
+@pytest.mark.parametrize("barrier", ["sync_spans", "peek", "step", "expire"])
+def test_every_barrier_leaves_nothing_unwritten(
+    barrier, served, per_tick, span_records
+):
+    name = f"barrier_{barrier}"
+    client, view = served("q1", name)
+    assert _step_span(view) and _step_span(view)
+    assert (view.upper, view._dispatched) == (8, 16)
+    assert view._validated_span is not None
+    calls = _calls(view)
+    if barrier == "step":
+        assert view.step(timeout=0)  # writes 8-15, then tick 16
+        assert calls[:8] == _chunks(8, 16)
+        assert view.upper == view._dispatched == 17
+    else:
+        getattr(view, barrier)()
+        # a copy-out and appends, never a replay; or, on expire, nothing
+        # (a peek's check_flags finds no flags to read)
+        assert [c for c in calls if c != "check"] == (
+            [] if barrier == "expire" else _chunks(8, 16)
+        )
+        assert view.upper == view._dispatched == (
+            8 if barrier == "expire" else 16
+        )
+    assert view._validated_span is None
+    _never_ahead_of_the_shard(client, view, name)
+    if barrier == "expire":
+        return
+    # flushed without a successor on the device: not overlapped
+    assert [s["overlapped_commit_ticks"] for s in span_records(name)][
+        :2
+    ] == [8, 0]
+    _step_spans(view)
+    assert _shard(client, ordered=False) == per_tick("q1")[0]
+
+
+def test_a_call_that_finds_no_tick_leaves_nothing_unwritten(served):
+    client, view = served("q1", "no_tick")
+    assert _step_span(view)
+    assert view._validated_span is not None and view._kept
+    # the kept ticks go (as on expire) but the validated span stays:
+    # the next call finds nothing to dispatch and writes it
+    kept, view._kept = view._kept, []
+    for s_ in view.sources.values():
+        s_.poll = lambda timeout=0.0: None
+    wait = view._wait_for_inputs
+    view._wait_for_inputs = lambda frontier, timeout: None
+    assert not _step_span(view)
+    assert view._validated_span is None
+    assert view.upper == view._dispatched == 8
+    assert client.machine("out").reload().upper == 8
+    view._wait_for_inputs, view._kept = wait, kept
+    _step_spans(view)
+
+
+def test_a_view_that_keeps_up_is_written_in_the_call_that_ran_it(
+    feed, span_records
+):
+    """One tick a call, the successor not yet there: the parent's
+    order of statements, nothing crosses a call."""
+    name = "keeps_up"
+    client = PersistClient(MemBlob(), MemConsensus())
+    w = client.open_writer("lineitem", LINEITEM_SCHEMA)
+    view = MaintainedView(
+        client, Dataflow(q1_mir(), name=name),
+        {"lineitem": ("lineitem", LINEITEM_SCHEMA)}, "out",
+    )
+    calls = _calls(view)
+    for t, tick in enumerate(feed[:6]):
+        w.compare_and_append(*tick["lineitem"], t, t + 1)
+        assert _step_span(view)
+        assert view._validated_span is None
+        assert view.upper == view._dispatched == t + 1
+        assert _never_ahead_of_the_shard(client, view, name) == t + 1
+        assert not _step_span(view)
+    assert calls == [
+        c for t in range(6) for c in ("run", "gather", "check", (t, t + 1))
+    ]
+    assert [s["overlapped_commit_ticks"] for s in span_records(name)] == (
+        [0] * 6
+    )
 
 
 @plans

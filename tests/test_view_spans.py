@@ -76,9 +76,10 @@ donation_requested = pytest.mark.parametrize(
 )
 
 
-def _sinked(ticks, name="mv", shard="out", **kw):
-    """A sinked view installed over an empty source shard, which then
-    receives ``ticks``: nothing absorbed, all of it backlog."""
+def _sinked(ticks, name="mv", shard="out", df=None, **kw):
+    """A sinked view (of ``df``, or a single-device one made from
+    ``kw``) installed over an empty source shard, which then receives
+    ``ticks``: nothing absorbed, all of it backlog."""
     from materialize_tpu.storage.persist import (
         MaintainedView,
         MemBlob,
@@ -89,7 +90,7 @@ def _sinked(ticks, name="mv", shard="out", **kw):
     client = PersistClient(MemBlob(), MemConsensus())
     w = client.open_writer("src", SCH)
     view = MaintainedView(
-        client, _mk(name=name, **kw), {"src": ("src", SCH)}, shard
+        client, df or _mk(name=name, **kw), {"src": ("src", SCH)}, shard
     )
     assert view.upper == 0
     for t, tick in enumerate(ticks):
@@ -328,11 +329,21 @@ def _per_tick_shard(ticks, ordered=True):
 
 
 def _invariant(view):
-    """The sources run ahead of the view by exactly what is kept."""
+    """The sources run ahead of what the view has dispatched by
+    exactly what is kept, and that ahead of ``upper`` by at most one
+    span: validated, unwritten, and only with its successor kept."""
     kept = [t for t, _inp, _at in view._kept]
-    assert kept == list(range(view.upper, view.upper + len(kept)))
+    lo = view._dispatched
+    assert kept == list(range(lo, lo + len(kept)))
     for s in view.sources.values():
-        assert s.frontier == view.upper + len(kept)
+        assert s.frontier == lo + len(kept)
+    assert view.df._defer_ck is None  # nothing unvalidated crosses a call
+    if view._validated_span is None:
+        assert view.upper == lo
+    else:
+        assert kept
+        unwritten = [t for t, _out in view._validated_span[1]]
+        assert unwritten == list(range(view.upper, lo))
 
 
 @pytest.fixture
@@ -368,6 +379,8 @@ def test_sinked_span_prefetch_writes_the_per_tick_shard(span_records):
     assert spans[0]["prefetched_ticks"] == 0
     for s in spans[1:]:
         assert s["prefetched_ticks"] == s["ticks"]
+    # every span but the last was written with its successor dispatched
+    assert [s["overlapped_commit_ticks"] for s in spans] == [8, 8, 8, 0]
 
 
 def test_sinked_span_prefetch_keeps_nothing_when_nothing_is_ready():
@@ -414,13 +427,15 @@ def test_step_and_run_until_consume_kept_ticks_first(span_records):
     ticks = _churn_ticks(34, 20)
     client, _w, view = _sinked(ticks, name="prefetch_d")
     assert view.step_span(max_ticks=4, timeout=0)
-    assert view.upper == 4 and len(view._kept) == 4
+    # 0-3 validated, 4-7 kept: written when the next entry needs them
+    assert view.upper == 0 and view._dispatched == 4
+    assert len(view._kept) == 4
     fetched = []
     fetch_to = view.sources["src"].fetch_to
     view.sources["src"].fetch_to = lambda target: (
         fetched.append(target), fetch_to(target)
     )[1]
-    assert view.step(timeout=0)  # tick 4, kept
+    assert view.step(timeout=0)  # writes 0-3, then tick 4, kept
     assert view.upper == 5 and len(view._kept) == 3
     _invariant(view)
     view.run_until(9, timeout=0)  # 5-7 kept, 8 from the source
@@ -428,9 +443,9 @@ def test_step_and_run_until_consume_kept_ticks_first(span_records):
     assert fetched == [9]
     # three kept and one more from the source make the next span
     assert view.step_span(max_ticks=3, timeout=0)  # 9-11, keeps 12-14
-    assert view.upper == 12 and len(view._kept) == 3
+    assert view._dispatched == 12 and len(view._kept) == 3
     assert view.step_span(max_ticks=4, timeout=0)  # 12-14 kept, 15
-    assert view.upper == 16
+    assert (view.upper, view._dispatched) == (12, 16)
     _invariant(view)
     while view.upper < len(ticks):
         assert view.step_span(max_ticks=4, timeout=0)
@@ -443,6 +458,11 @@ def test_step_and_run_until_consume_kept_ticks_first(span_records):
     ]
     assert [s["prefetched_ticks"] for s in spans] == [
         0, 1, 1, 1, 1, 0, 0, 3, 4,
+    ]
+    # written beneath the successor's dispatch: 9-12 and 12-16 alone
+    # (0-4 was flushed by step(), 16-20 had no successor)
+    assert [s["overlapped_commit_ticks"] for s in spans] == [
+        0, 0, 0, 0, 0, 0, 3, 4, 0,
     ]
 
 
@@ -471,10 +491,15 @@ def test_expire_drops_kept_ticks_and_a_fresh_view_resumes():
     ticks = _churn_ticks(36, 16)
     client, _w, view = _sinked(ticks)
     assert view.step_span(max_ticks=4, timeout=0)
-    assert view.upper == 4 and len(view._kept) == 4
+    assert view.step_span(max_ticks=4, timeout=0)
+    # 0-3 written, 4-7 validated and unwritten, 8-11 kept
+    assert (view.upper, view._dispatched) == (4, 8)
+    assert view._validated_span is not None and len(view._kept) == 4
     view.expire()
-    assert view._kept == []
+    assert view._kept == [] and view._validated_span is None
+    assert view.upper == view._dispatched == 4
     assert client.machine("src").reload().reader_holds == ()
+    assert client.machine("out").reload().upper == 4
     fresh = MaintainedView(client, _mk(), {"src": ("src", SCH)}, "out")
     assert fresh.upper == 4 and fresh._kept == []
     _invariant(fresh)
@@ -484,3 +509,93 @@ def test_expire_drops_kept_ticks_and_a_fresh_view_resumes():
     assert _shard(client, ordered=False) == _per_tick_shard(
         ticks, ordered=False
     )
+
+
+# -- a sinked span is written beneath its successor's dispatch ---------------
+
+
+@donation_requested
+def test_a_validated_spans_deltas_outlive_the_donated_dispatch(donation):
+    """Span K's deltas wait on the device while K+1 is dispatched with
+    its carry donated: the prover sees them as roots and finds none in
+    the carry, and the sanitizer, on, catches no read of a dead one."""
+    from materialize_tpu.analysis.donation import LEDGER, view_verdict
+    from materialize_tpu.analysis.provenance import (
+        ProvenanceReport,
+        scan_view,
+    )
+
+    before = COMPUTE_CONFIGS.current()["buffer_sanitizer"]
+    COMPUTE_CONFIGS.update({"buffer_sanitizer": True})
+    try:
+        ticks = _churn_ticks(41, 20)
+        client, _w, view = _sinked(ticks, name="donated_mv")
+        assert view.step_span(max_ticks=K, timeout=0)
+        assert view._validated_span is not None
+        report = ProvenanceReport()
+        scan_view(report, "donated_mv", view)
+        roots = {
+            root for rec in report.leaves.values()
+            for root, _ in rec.holders
+        }
+        assert {f"donated_mv/validated[t={t}]" for t in range(K)} <= roots
+        verdict = view_verdict("donated_mv", view, report=report)
+        assert all(verdict.donatable.values()), verdict.reasons
+        assert view.donated_parts  # the next dispatch asks for donation
+        recorded = LEDGER.recorded
+        while view.upper < len(ticks):
+            assert view.step_span(max_ticks=K, timeout=0)
+            _invariant(view)
+        assert LEDGER.recorded > recorded  # carries were handed over
+        assert LEDGER.caught == 0
+        assert _shard(client) == _per_tick_shard(ticks)
+    finally:
+        COMPUTE_CONFIGS.update({"buffer_sanitizer": before})
+        LEDGER.clear()
+
+
+@pytest.mark.parametrize("shard", ["out", None], ids=["sinked", "index"])
+def test_an_spmd_view_steps_to_the_same_answers(shard, span_records):
+    """Four virtual devices: an SPMD view, sinked or not, takes the
+    same span path (its commit gathers each delta to the host) and,
+    over a backlog, the same order."""
+    from materialize_tpu.parallel.mesh import make_mesh
+    from materialize_tpu.render.dataflow import ShardedDataflow
+
+    ticks = _churn_ticks(42, 20)
+    name = f"spmd_{shard}"
+    client, _w, view = _sinked(
+        ticks, shard=shard,
+        df=ShardedDataflow(mir.Get("src", SCH), make_mesh(4), name=name),
+    )
+    order = []
+    run_steps, gather = view.df.run_steps, view.df.gather_delta
+    view.df.run_steps = lambda *a, **kw: (
+        order.append("run"), run_steps(*a, **kw)
+    )[1]
+    view.df.gather_delta = lambda out: (
+        order.append("commit"), gather(out)
+    )[1]
+    uppers = []
+    while view._dispatched < len(ticks):
+        assert view.step_span(max_ticks=K, timeout=0)
+        _invariant(view)
+        uppers.append((view.upper, view._dispatched))
+    assert uppers == [(0, 8), (8, 16), (20, 20)]
+    assert order == (
+        ["run", "run"] + ["commit"] * 8 + ["run"] + ["commit"] * 12
+    )
+    assert _accum(view.peek()) == _serial(ticks)[0]
+    spans = span_records(name)
+    assert [s["overlapped_commit_ticks"] for s in spans] == [8, 8, 0]
+    if shard:
+        # chunk for chunk the per-tick shard's rows (workers do not
+        # consolidate across each other: compare the sums)
+        def net(shard_):
+            return [
+                (lo, up, _accum([r[:-1] + (0, r[-1]) for r in rows]))
+                for lo, up, rows in shard_
+            ]
+
+        assert net(_shard(client)) == net(_per_tick_shard(ticks))
+
