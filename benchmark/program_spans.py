@@ -49,6 +49,26 @@ def load(ctx: dict, path: str | None = None):
     return _memo[key]
 
 
+def tick_uppers(path: str | None = None):
+    """``(wall seconds, upper)`` after every ``source.tick`` of the dump,
+    in time order: a tick's record ends once every subsource has been
+    appended at ``t``, so from then on the sources are complete to
+    ``t + 1``. None where there is no dump."""
+    path = path or os.path.join(os.environ[VARIABLE], DUMP)
+    if not os.path.exists(path):
+        return None
+    out = []
+    with open(path) as f:
+        for ln in f:
+            if '"source.tick"' not in ln:
+                continue
+            r = json.loads(ln)
+            if r["name"] == "source.tick":
+                end_us = r["start_us"] + r["duration_us"]
+                out.append((end_us / 1e6, r["attrs"]["t"] + 1))
+    return sorted(out)
+
+
 def _load(ctx: dict, path: str):
     if not os.path.exists(path):
         return None
@@ -126,3 +146,60 @@ def count_per_tick(got, names: tuple, count: str):
         for s in got["spans"] for n in names if n in s["phases"]
     )
     return total / got["ticks"]
+
+
+def window_stalls(wall_open: float, wall_close: float,
+                  path: str | None = None):
+    """Where the window's slowest stretches went, for the record of a
+    run that read low: the generator's widest gap between two
+    ``source.tick`` ends, and the judged view's slowest span from its
+    start to the next span's start, each with its offset from the open
+    and its record's times; and the seconds by which the view's spans
+    ran over twice their median (``excess_s``). None where there is no
+    dump."""
+    path = path or os.path.join(os.environ[VARIABLE], DUMP)
+    if not os.path.exists(path):
+        return None
+    with open(path) as f:
+        records = [json.loads(ln) for ln in f if ln.strip()]
+    lo, hi = wall_open * 1e6, wall_close * 1e6
+    out: dict = {}
+    ticks = sorted(
+        (r for r in records if r["name"] == "source.tick"
+         and lo <= r["start_us"] + r["duration_us"] <= hi),
+        key=lambda r: r["start_us"] + r["duration_us"],
+    )
+    if len(ticks) > 1:
+        ends = [r["start_us"] + r["duration_us"] for r in ticks]
+        i = max(range(1, len(ends)), key=lambda k: ends[k] - ends[k - 1])
+        r = ticks[i]
+        out["tick"] = {
+            "gap_ms": (ends[i] - ends[i - 1]) / 1e3,
+            "at_s": (ends[i - 1] - lo) / 1e6,
+            "duration_ms": r["duration_us"] / 1e3,
+            **{k: v for k, v in r["attrs"].items()
+               if k.endswith("_ms") or k in ("reloads", "rows")},
+        }
+    by_dataflow: dict = {}
+    for r in records:
+        if r["name"] == "span" and lo <= r["start_us"] <= hi:
+            by_dataflow.setdefault(r["attrs"].get("dataflow"), []).append(r)
+    if by_dataflow:
+        spans = sorted(max(by_dataflow.values(), key=len),
+                       key=lambda r: r["start_us"])
+        if len(spans) > 2:
+            walls = [b["start_us"] - a["start_us"]
+                     for a, b in zip(spans, spans[1:])]
+            med = sorted(walls)[len(walls) // 2]
+            i = max(range(len(walls)), key=walls.__getitem__)
+            slow = spans[i]
+            out["span"] = {
+                "wall_ms": walls[i] / 1e3, "median_ms": med / 1e3,
+                "at_s": (slow["start_us"] - lo) / 1e6,
+                "excess_s": sum(max(w - 2 * med, 0) for w in walls) / 1e6,
+                "phases_ms": {
+                    c["name"]: c["duration_us"] / 1e3 for c in records
+                    if c["parent_id"] == slow["span_id"]
+                },
+            }
+    return out

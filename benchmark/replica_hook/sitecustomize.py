@@ -21,6 +21,11 @@ In the replica it starts one daemon thread that watches
 
 Nothing is imported from jax until a command arrives, by which time the
 replica has long since initialised its backend.
+
+In the replica and in ``environmentd`` it also appends every garbage
+collection of the oldest generation, and any other that took 20 ms or
+more, to ``$BENCH_HOOK_DIR/gc_<role>.jsonl`` (wall start, ms,
+generation): a pause of either process holds the view's rate.
 """
 
 from __future__ import annotations
@@ -32,12 +37,39 @@ import threading
 import time
 
 
-def _is_replica() -> bool:
+def _role() -> str | None:
     try:
         with open("/proc/self/cmdline", "rb") as f:
-            return b"materialize_tpu.coord.replica" in f.read()
+            cmd = f.read()
     except OSError:
-        return False
+        return None
+    for role, module in (
+        ("replica", b"materialize_tpu.coord.replica"),
+        ("environmentd", b"materialize_tpu.server.environmentd"),
+    ):
+        if module in cmd:
+            return role
+    return None
+
+
+def _record_gc(hook_dir: str, role: str) -> None:
+    import gc
+
+    path = os.path.join(hook_dir, f"gc_{role}.jsonl")
+    began = [0.0, 0.0]
+
+    def note(phase, info):
+        if phase == "start":
+            began[0], began[1] = time.perf_counter(), time.time()
+            return
+        ms = (time.perf_counter() - began[0]) * 1e3
+        if info["generation"] == 2 or ms >= 20.0:
+            with open(path, "a") as f:
+                f.write(json.dumps(
+                    [began[1], ms, info["generation"], info["collected"]]
+                ) + "\n")
+
+    gc.callbacks.append(note)
 
 
 def _write_json(path: str, obj) -> None:
@@ -144,7 +176,11 @@ def _watch(hook_dir: str) -> None:
 
 def _install() -> None:
     hook_dir = os.environ.get("BENCH_HOOK_DIR")
-    if not hook_dir or not _is_replica():
+    role = _role() if hook_dir else None
+    if role is None:
+        return
+    _record_gc(hook_dir, role)
+    if role != "replica":
         return
     threading.Thread(
         target=_watch, args=(hook_dir,), daemon=True,

@@ -2,17 +2,19 @@
 """One run of one cell of the benchmark, on the served path.
 
     python benchmark/run.py --workload q15_backlog --seed 7 \
-        --seconds 45 --trace 0
+        --seconds 51 --trace 0
 
 Starts ``python -m materialize_tpu.server.environmentd`` (a host
 process) with ONE replica on ``JAX_PLATFORMS=tpu``, installs the
 configuration's SQL over HTTP, waits for hydration, warms up as the
-traffic mix says, opens the window for ``--seconds``, closes it,
-compares what the window produced with the plain reference, prints, and
-shuts everything down. This process is client, load driver and oracle;
-its own JAX is pinned to the CPU, so exactly one process, the replica,
-holds the chip. Where the replica reports a device that is not a TPU
-the run fails: there is no fall-back.
+traffic mix says (and lets the sources run ahead while the replica
+stands, where the mix asks for a backlog), opens the window for
+``--seconds``, closes it, compares what the window produced with the
+plain reference, prints, and shuts everything down. This process is
+client, reader and oracle; its own JAX is pinned to the CPU, so exactly
+one process, the replica, holds the chip. Where the replica
+reports a device that is not a TPU the run fails: there is no
+fall-back.
 
 Everything about a cell is data found by name from ``BENCHMARK.json``:
 ``configs/<config>.json``, ``traffic/<traffic>.json``,
@@ -56,6 +58,7 @@ sys.path.insert(1, REPO)
 
 import compare  # noqa: E402
 import metrics as arithmetic  # noqa: E402
+import program_spans  # noqa: E402
 import roofline  # noqa: E402
 import trace_reduce  # noqa: E402
 import traffic as traffic_mod  # noqa: E402
@@ -176,6 +179,34 @@ def warm_up(server, shards, spec, mix, view_upper_hydrated):
     return time.monotonic() - t0
 
 
+def open_behind(server, shards, sources_upper, ticks: int) -> float:
+    """Stop the replica until its sources are ``ticks`` past the view's
+    durable upper, continue it and wait for its next commit: the window
+    opens on a backlog about that deep however long set-up took and
+    however fast this machine's generator is. The seconds it stood; 0
+    where the backlog was already that deep."""
+    if sources_upper() - shards.upper("view") >= ticks:
+        return 0.0
+    t0 = time.monotonic()
+    pid = server.stop_replica()
+    try:
+        wait_until(
+            lambda: sources_upper() - shards.upper("view") >= ticks,
+            WAIT_S, f"the sources to run {ticks} ticks ahead of the view",
+            server, 0.05,
+        )
+    finally:
+        server.continue_replica(pid)
+    stood = time.monotonic() - t0
+    at = shards.upper("view")
+    wait_until(
+        lambda: shards.upper("view") > at, WAIT_S,
+        "the view to commit a span after the replica continued", server,
+        0.01,
+    )
+    return stood
+
+
 def lag_rows(server, view: str) -> list:
     """Every committed span of the view still in the ring (4,096)."""
     return [
@@ -246,6 +277,14 @@ def run(args) -> int:
 
         for stmt in spec.get("session", []):
             server.sql(stmt)
+        # the most ticks a span of the replica takes: a full span was
+        # waiting while the sources were that far past the view
+        span_ticks = int(server.rows("SHOW span_max_ticks")[0][0])
+
+        def sources_upper() -> int:
+            # complete in EVERY input of the view
+            return min(shards.upper(a) for a in spec["sources"])
+
         t_ddl = time.monotonic()
         # a statement may name any plain number of the configuration
         knobs = {
@@ -285,9 +324,15 @@ def run(args) -> int:
              "source_upper": shards.upper("update"),
              "view_upper": hydrated_upper, "hydrated_at": hydrated_at})
         warm_s = warm_up(server, shards, spec, mix, hydrated_upper)
+        behind_warm = sources_upper() - shards.upper("view")
+        stood_s = open_behind(
+            server, shards, sources_upper, mix.get("backlog_at_open_ticks", 0)
+        )
         compile_before = server.compile_log()
         regrows_before = server.replica_metric("mz_overflow_regrows_total")
         log({"phase": "warmup", "seconds": warm_s,
+             "behind_after_warmup": behind_warm,
+             "replica_stood_s": stood_s,
              "replica_compile_records": len(compile_before),
              "compile_log": [list(c) for c in compile_before],
              "source_upper": shards.upper("update"),
@@ -299,10 +344,9 @@ def run(args) -> int:
         upper_lock = threading.Lock()
 
         def newest_complete() -> int:
-            # the newest time complete in EVERY input of the view: what
-            # a read without AS OF is served at
+            # what a read without AS OF is served at
             with upper_lock:
-                return min(shards.upper(a) for a in spec["sources"]) - 1
+                return sources_upper() - 1
 
         schedule = traffic_mod.read_schedule(mix, args.seed, seconds)
         if schedule:
@@ -314,9 +358,12 @@ def run(args) -> int:
         with upper_lock:
             view_open = shards.upper("view")
             source_open = shards.upper("update")
+            complete_open = sources_upper()
         t_open = time.monotonic()
         wall_open = time.time()
-        setup_s = t_open - T_START
+        # the replica's stand shapes the traffic and is no set-up work:
+        # left in, it would hide up to its length of set-up gained or lost
+        setup_s = t_open - T_START - stood_s
         if readers:
             readers.start(t_open)
         t_close = t_open + seconds
@@ -333,6 +380,7 @@ def run(args) -> int:
         with upper_lock:
             view_close = shards.upper("view")
             source_close = shards.upper("update")
+            complete_close = sources_upper()
         t_closed = time.monotonic()
         wall_close = time.time()
         window_s = t_closed - t_open
@@ -438,6 +486,40 @@ def run(args) -> int:
         shards = None
         down = server.shutdown()  # the program's state is freed
 
+        # where a full span was waiting for the replica: the sources'
+        # upper after each tick, from the spans environmentd wrote at its
+        # stop (none: nothing to read, and the facts say so)
+        ticks = program_spans.tick_uppers()
+        stretch, gap_ms = {}, None
+        if ticks is not None:
+            uppers = (
+                [(wall_open, complete_open)]
+                + [u for u in ticks if wall_open < u[0] < wall_close]
+                + [(wall_close, complete_close)]
+            )
+            gap_ms = 1e3 * max(
+                b[0] - a[0] for a, b in zip(uppers, uppers[1:])
+            )
+            stretch = arithmetic.backlogged_stretch(
+                [(r["frontier"], r["at"]) for r in all_lags],
+                uppers, (view_open, wall_open), (view_close, wall_close),
+                span_ticks,
+            )
+
+        # what held the window where it read low: the slowest tick and
+        # span, and every long garbage collection of either process
+        stalls = program_spans.window_stalls(wall_open, wall_close)
+        gc_pauses = []
+        for role in ("environmentd", "replica"):
+            gpath = os.path.join(server.hook_dir, f"gc_{role}.jsonl")
+            if os.path.exists(gpath):
+                with open(gpath) as f:
+                    gc_pauses += [
+                        [role, round(g[0] - wall_open, 3), g[1], g[2]]
+                        for g in map(json.loads, f)
+                        if wall_open <= g[0] <= wall_close
+                    ]
+
         # -- the comparison ----------------------------------------------
         t1 = time.monotonic()
         judged_reads = [
@@ -513,6 +595,21 @@ def run(args) -> int:
             "backlog_ticks": [source_open - view_open,
                               source_close - view_close],
             "updates_absorbed": absorbed, "updates_generated": generated,
+            # the stretch in which a full span was waiting for the
+            # replica: the whole window unless the backlog ran out
+            "backlogged_s": stretch.get("seconds"),
+            "backlog_at_stretch_end": stretch.get("backlog_at_end"),
+            "span_max_ticks": span_ticks,
+            "replica_stood_s": stood_s,
+            "behind_after_warmup": behind_warm,
+            "source_tick_gap_max_ms": gap_ms,
+            "stalls": stalls,
+            "gc_pauses_in_window": sorted(
+                gc_pauses, key=lambda g: -g[2])[:8],
+            "gc_ms_in_window": {
+                role: sum(g[2] for g in gc_pauses if g[0] == role)
+                for role in ("environmentd", "replica")
+            },
             "compiles_in_window": len(new_compiles),
             "compiled_in_window": [list(c) for c in new_compiles[:8]],
             "overflow_regrows_in_window": regrows_after - regrows_before,

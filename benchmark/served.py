@@ -222,6 +222,75 @@ class Server:
         ).stdout.split()
         return [int(p) for p in out]
 
+    def replica_pid(self) -> int:
+        found = []
+        for pid in self.child_pids():
+            try:
+                with open(f"/proc/{pid}/cmdline", "rb") as f:
+                    if b"materialize_tpu.coord.replica" in f.read():
+                        found.append(pid)
+            except OSError:
+                pass
+        if len(found) != 1:
+            raise BenchFailure(f"expected one replica process: {found}")
+        return found[0]
+
+    def _consensus_writable(self, timeout: float) -> bool:
+        """Whether a write transaction on the deployment's consensus
+        database can begin: no stopped process holds its write lock."""
+        import sqlite3
+
+        conn = sqlite3.connect(
+            os.path.join(self.data_dir, "consensus.db"), timeout=timeout,
+            isolation_level=None,
+        )
+        try:
+            conn.execute("BEGIN IMMEDIATE")
+            conn.execute("ROLLBACK")
+            return True
+        except sqlite3.OperationalError:
+            return False
+        finally:
+            conn.close()
+
+    def stop_replica(self) -> int:
+        """Stop the replica's process (SIGSTOP) at a moment it holds no
+        write lock on consensus, so that the coordinator's generator
+        keeps appending while it stands; ``continue_replica`` resumes
+        it. A replica stopped inside its compare-and-set is continued
+        and stopped again a moment later."""
+        pid = self.replica_pid()
+        for _ in range(200):
+            os.kill(pid, signal.SIGSTOP)
+            deadline = time.monotonic() + 5.0
+            while not self._stopped(pid):
+                if time.monotonic() > deadline:
+                    os.kill(pid, signal.SIGCONT)
+                    raise BenchFailure(f"the replica {pid} did not stop")
+                time.sleep(0.001)
+            if self._consensus_writable(timeout=1.0):
+                return pid
+            os.kill(pid, signal.SIGCONT)
+            time.sleep(0.01)
+        raise BenchFailure("the replica held consensus at every stop")
+
+    @staticmethod
+    def _stopped(pid: int) -> bool:
+        """Every thread of ``pid`` in the stopped state."""
+        for tid in os.listdir(f"/proc/{pid}/task"):
+            try:
+                with open(f"/proc/{pid}/task/{tid}/stat") as f:
+                    state = f.read().rsplit(")", 1)[1].split()[0]
+            except OSError:
+                continue  # a thread that has just exited
+            if state not in ("T", "t"):
+                return False
+        return True
+
+    @staticmethod
+    def continue_replica(pid: int) -> None:
+        os.kill(pid, signal.SIGCONT)
+
     def shutdown(self) -> dict:
         """SIGTERM environmentd (its graceful stop reaps the replica),
         wait for parent and replica, kill what is left."""

@@ -188,6 +188,156 @@ def test_a_stall_moves_both_end_to_end_metrics():
         metrics.percentile([], 95)
 
 
+def backlog_timeline(behind_at_open, made=20.0, span_s=0.3, stall=None,
+                     slow=None, seconds=45.0):
+    """A made-up window: a generator making ``made`` ticks a second of
+    96 updates each (``stall``: (from, to) seconds in which it makes
+    none), a replica committing spans of up to 8 ready ticks every
+    ``span_s`` seconds (``slow``: (from, to, factor) seconds in which a
+    span takes ``factor`` times as long), the view 1,000 at the open,
+    sampled every 10 ms. Returns the rule's arguments."""
+    t0, f0 = 100.0, 1000
+
+    def made_by(t):
+        e = t - t0
+        if stall:
+            e -= min(max(e - stall[0], 0.0), stall[1] - stall[0])
+        return f0 + behind_at_open + int(made * e)
+
+    commits, f, a = [(f0, t0 - 0.1)], f0, t0 - 0.1
+    while a < t0 + seconds:
+        k = min(8, made_by(a) - f)
+        if k <= 0:
+            a += 0.01
+            continue
+        factor = slow[2] if slow and slow[0] <= a - t0 < slow[1] else 1.0
+        f, a = f + k, a + span_s * factor
+        commits.append((f, a))
+    close_at = t0 + seconds
+    close = (max(c for c, at in commits if at <= close_at), close_at)
+    uppers = [(t0 + i * 0.01, made_by(t0 + i * 0.01))
+              for i in range(int(seconds * 100) + 1)]
+    times = [t for t in range(f0, close[0] + 8) for _ in range(96)]
+    return times, commits, uppers, (f0, t0), close
+
+
+def stretch_and_window(behind_at_open, **kw):
+    """The rule's stretch, its rate and the whole window's rate."""
+    times, commits, uppers, open_, close = backlog_timeline(
+        behind_at_open, **kw
+    )
+    got = metrics.backlogged_stretch(
+        commits, uppers, open_, close, span_ticks=8
+    )
+    rate = metrics.updates_per_s(
+        times, open_[0], got["view_upper"], got["seconds"]
+    )
+    window = metrics.updates_per_s(
+        times, open_[0], close[0], close[1] - open_[1]
+    )
+    return got, rate, window
+
+
+def test_the_backlogged_stretch_on_a_made_up_timeline():
+    replica = 8 / 0.3 * 96  # 2,560 updates/s while a full span waits
+    # 208 ticks behind, 26.7 absorbed against 20 made: a full span is
+    # waiting until 30 s
+    got, rate, window = stretch_and_window(208)
+    assert got["seconds"] == pytest.approx(30.0, abs=0.6)
+    assert got["backlog_at_end"] < 8
+    assert rate == pytest.approx(replica, rel=0.01)
+    assert window < 0.93 * replica  # the window reads the generator too
+    # 1,000 behind: a full span waits all through; the rule is the
+    # window's, to within one span
+    got, rate, window = stretch_and_window(1000)
+    assert got["seconds"] == pytest.approx(45.0)
+    assert rate == window
+    assert got["backlog_at_end"] == pytest.approx(1000 - 45 * 6.67, abs=20)
+    assert window == pytest.approx(replica, rel=0.007)
+    # caught up at the open (3 behind, under a span): the whole window
+    got, rate, window = stretch_and_window(3)
+    assert got["seconds"] == pytest.approx(45.0)
+    assert rate == window == pytest.approx(20 * 96, rel=0.02)
+    # the generator stalls 10 s while the spans are still full, and the
+    # replica's spans take twice as long in the same 10 s: the stall stays
+    # in the stretch and the replica is not flattered
+    got, rate, window = stretch_and_window(
+        1000, stall=(10.0, 20.0), slow=(10.0, 20.0, 2.0)
+    )
+    assert got["seconds"] == pytest.approx(45.0) and rate == window
+    assert window == pytest.approx(replica * 40 / 45, rel=0.01)
+    # ... and a stall that empties the backlog ends the stretch for good,
+    # though the generator comes back faster than the replica
+    got, _, _ = stretch_and_window(100, made=40.0, stall=(2.0, 12.0))
+    assert got["seconds"] < 12.0
+
+
+def test_the_recorded_spans_replay_through_the_stretch_rule():
+    with open(os.path.join(BENCH, "testdata",
+                           "q15_backlog_spans_cut.jsonl")) as f:
+        records = [json.loads(ln) for ln in f]
+    with open(os.path.join(BENCH, "testdata",
+                           "q15_backlog_spans_cut.expected.json")) as f:
+        expected = json.load(f)
+    end = {id(r): (r["start_us"] + r["duration_us"]) / 1e6 for r in records}
+    commits = [(r["attrs"]["upper"], end[id(r)])
+               for r in records if r["name"] == "span"]
+    ticks = sorted((end[id(r)], r["attrs"]["t"] + 1)
+                   for r in records if r["name"] == "source.tick")
+    lo, hi = expected["source_upper"]
+    # the window opened after tick lo - 1 was appended: the sources at lo
+    opened = next(at for at, u in ticks if u == lo)
+    open_ = (expected["before"], opened)
+    close = (expected["frontiers"][-1], commits[-1][1])
+    uppers = [t for t in ticks if t[0] >= opened] + [(close[1], hi)]
+    got = metrics.backlogged_stretch(commits, uppers, open_, close, 8)
+    # never ran out: the whole window, up to the last commit
+    assert got["seconds"] == pytest.approx(close[1] - opened)
+    assert got["view_upper"] == close[0] == 649
+    assert got["backlog_at_end"] == hi - 649 == 572
+    # the same spans with the sources 8 ticks short at the second
+    # commit: the stretch ends at it
+    short = [(at, min(u, commits[1][0] + 7)) for at, u in uppers]
+    got = metrics.backlogged_stretch(commits, short, open_, close, 8)
+    assert got["view_upper"] == 633
+    assert got["seconds"] == pytest.approx(commits[1][1] - opened)
+
+
+def test_the_window_stalls_name_the_slowest_tick_and_span(tmp_path):
+    import program_spans
+
+    path = os.path.join(BENCH, "testdata", "q15_backlog_spans_cut.jsonl")
+    with open(path) as f:
+        records = [json.loads(ln) for ln in f]
+    lo = min(r["start_us"] for r in records) / 1e6
+    hi = max(r["start_us"] + r["duration_us"] for r in records) / 1e6
+    got = program_spans.window_stalls(lo, hi, path)
+    # tick 1218 ended 23.448 ms after tick 1217, the widest of four gaps
+    assert got["tick"]["gap_ms"] == pytest.approx(23.448)
+    assert got["tick"]["work_ms"] == pytest.approx(17.877789)
+    assert got["tick"]["encode_ms"] == pytest.approx(1.128229)
+    # the first of four spans ran longest to the next one's start
+    assert got["span"]["wall_ms"] == pytest.approx(427.987)
+    assert got["span"]["median_ms"] == pytest.approx(420.569)
+    assert got["span"]["at_s"] == pytest.approx(
+        (1790917779563977 - lo * 1e6) / 1e6)
+    assert got["span"]["excess_s"] == 0
+    assert set(got["span"]["phases_ms"]) >= {
+        "span.upload", "span.dispatch", "span.readback", "span.append"}
+    # a span 1.2 s late: its excess over twice the median is charged
+    late = [dict(r) for r in records]
+    for r in late:
+        if r["name"] == "span" and r["attrs"]["upper"] >= 641:
+            r["start_us"] += 1_200_000
+    moved = str(tmp_path / "late_spans.jsonl")
+    with open(moved, "w") as f:
+        f.writelines(json.dumps(r) + "\n" for r in late)
+    got = program_spans.window_stalls(lo, hi + 2.0, moved)
+    assert got["span"]["wall_ms"] == pytest.approx(1620.569)
+    assert got["span"]["excess_s"] == pytest.approx(1.620569 - 2 * 0.427987)
+    assert program_spans.window_stalls(lo, hi, path + ".missing") is None
+
+
 def test_roofline_bytes_from_shapes():
     b = roofline.step_bytes(
         {"lineitem": 6000, "supplier": 10}, {"lineitem": 128},

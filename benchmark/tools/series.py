@@ -55,7 +55,12 @@ def main() -> int:
                     "updates_absorbed", "compiles_in_window",
                     "overflow_regrows_in_window", "warmup_s",
                     "reference_s", "reads_due", "reads_sent_late_p95_ms",
-                    "read_p50_ms", "spans_committed")
+                    "read_p50_ms", "spans_committed", "setup_s",
+                    "hydrated_at_tick", "behind_after_warmup",
+                    "replica_stood_s", "backlogged_s",
+                    "backlog_at_stretch_end",
+                    "source_tick_gap_max_ms", "window_s", "stalls",
+                    "gc_pauses_in_window", "gc_ms_in_window")
             }
             if "control" in f_:
                 line["control_correct"] = f_["control"]["correct"]

@@ -23,6 +23,17 @@ Fields of a mix:
     has advanced that many ticks since hydration, the last
     ``quiet_ticks`` of them with no new compile record; ``warm_reads``:
     reads sent before the window so that their programs exist.
+``backlog_at_open_ticks``
+    where warm-up leaves the view fewer ticks behind the sources than
+    this, the replica's process stands (``run.open_behind``) until it
+    is this far behind; the window opens a few seconds later, the
+    replica's first commits after the stand taken off. 0 or absent:
+    the window opens where warm-up leaves it. The stand assumes that
+    SQLite's write lock on the deployment's ``consensus.db`` is the
+    only lock across processes that the replica can hold: a stopped
+    replica holding any other would stall the generator and show only
+    as a timeout of the wait. A pause of the replica that the program
+    offers would replace the signals.
 ``end_to_end``
     the end-to-end metrics the mix reports.
 """
