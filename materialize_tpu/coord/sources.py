@@ -32,17 +32,7 @@ from ..storage.generator.auction import (
     USERS_SCHEMA,
     AuctionGenerator,
 )
-from ..storage.generator.tpch import (
-    CUSTOMER_SCHEMA,
-    LINEITEM_SCHEMA,
-    NATION_SCHEMA,
-    ORDERS_SCHEMA,
-    PART_SCHEMA,
-    PARTSUPP_SCHEMA,
-    REGION_SCHEMA,
-    SUPPLIER_SCHEMA,
-    TpchGenerator,
-)
+from ..storage.generator.tpch import TpchGenerator
 from ..storage.persist import PersistClient, WriteHandle
 from ..storage.persist.machine import TALLY
 from ..utils.trace import TRACER
@@ -68,16 +58,15 @@ class TpchAdapter(GeneratorAdapter):
         sf = float(options.get("scale_factor", 0.01))
         seed = int(options.get("seed", 1))
         self.churn_orders = int(options.get("churn_orders", 16))
-        self.gen = TpchGenerator(sf=sf, seed=seed)
+        self.gen = TpchGenerator(
+            sf=sf, seed=seed, spec_parts=bool(options.get("spec_parts"))
+        )
         self.subsources = {
-            "lineitem": LINEITEM_SCHEMA,
-            "orders": ORDERS_SCHEMA,
-            "supplier": SUPPLIER_SCHEMA,
-            "part": PART_SCHEMA,
-            "partsupp": PARTSUPP_SCHEMA,
-            "customer": CUSTOMER_SCHEMA,
-            "nation": NATION_SCHEMA,
-            "region": REGION_SCHEMA,
+            name: self.gen.schema(name)
+            for name in (
+                "lineitem", "orders", "supplier", "part", "partsupp",
+                "customer", "nation", "region",
+            )
         }
 
     def snapshot(self) -> dict:
@@ -93,7 +82,7 @@ class TpchAdapter(GeneratorAdapter):
         keys = np.arange(1, self.gen.n_orders + 1)
         ocols = self.gen.orders_rows(keys)
         out["orders"] = Batch.from_numpy(
-            ORDERS_SCHEMA,
+            self.subsources["orders"],
             ocols,
             np.zeros(len(ocols[0]), np.uint64),
             np.ones(len(ocols[0]), np.int64),

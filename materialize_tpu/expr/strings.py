@@ -194,6 +194,9 @@ class _EnvCache:
         # O(new strings) Python work per step, not O(dict)).
         self._done: dict[str, dict] = {}
         self._epoch = 0
+        # tables computed (not served from the cache) since start: a
+        # build after hydration means the dictionary moved
+        self.built = 0
 
     def table(self, key: str) -> tuple:
         # Optimistic epoch validation: the build runs UNLOCKED (it can
@@ -267,10 +270,16 @@ class _EnvCache:
         values[:n] = [done[c] for c, _ in pairs]
         self._tables[key] = (labels, values)
         self._version[key] = pre_version
+        self.built += 1
         return self._tables[key]
 
 
 _CACHE = _EnvCache()
+
+
+def tables_built() -> int:
+    """Side-tables this process has computed (cache misses) so far."""
+    return _CACHE.built
 
 
 def build_env(keys, depth: int = 1) -> dict:

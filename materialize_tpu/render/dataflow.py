@@ -157,6 +157,15 @@ class _RenderContext:
         # rows bound the arrangement's, and the site its rows are
         # probed at (presize_for_snapshot).
         self.source_fed: dict = {}
+        # The (slot, part)s of source_fed whose input reaches the join
+        # through no filter: all of its snapshot can meet the site.
+        self.unfiltered_fed: set = set()
+        # One entry per delta-join pipeline: the inputs (Gets, with
+        # let bindings resolved through ``let_inputs``) whose rows reach
+        # the pipeline's own delta; the span records count the
+        # pipelines whose inputs had rows (doc/observability.md).
+        self.delta_pipelines: list[frozenset] = []
+        self.let_inputs: dict = {}
         # Set once presize_for_snapshot has grown an arrangement: the
         # step's large sorts are then traced in blocks (ops/sort.py).
         self.sort_in_blocks = False
@@ -228,6 +237,32 @@ def _sole_get(expr: mir.RelationExpr) -> str | None:
     while isinstance(expr, (mir.Filter, mir.Map, mir.Project)):
         expr = expr.input
     return expr.name if isinstance(expr, mir.Get) else None
+
+
+def _note_source_fed(ctx, expr, slot: int, part: int, jsite: int) -> None:
+    """Record (``source_fed``) a join arrangement that one input feeds
+    through stateless operators, and whether through no Filter."""
+    fed_by = _sole_get(expr)
+    if fed_by is None:
+        return
+    ctx.source_fed[(slot, part)] = (fed_by, jsite)
+    while isinstance(expr, (mir.Map, mir.Project)):
+        expr = expr.input
+    if isinstance(expr, mir.Get):
+        ctx.unfiltered_fed.add((slot, part))
+
+
+def _inputs_under(
+    expr: mir.RelationExpr, ctx: _RenderContext
+) -> frozenset:
+    """The dataflow inputs whose rows can reach ``expr``'s delta: its
+    Gets, a let binding's resolved to what its value reads."""
+    if isinstance(expr, mir.Get):
+        return ctx.let_inputs.get(expr.name, frozenset((expr.name,)))
+    out: frozenset = frozenset()
+    for ch in expr.children():
+        out |= _inputs_under(ch, ctx)
+    return out
 
 
 def _build(expr: mir.RelationExpr, ctx: _RenderContext):
@@ -418,6 +453,7 @@ def _build(expr: mir.RelationExpr, ctx: _RenderContext):
 
     if isinstance(expr, mir.Let):
         val = _build(expr.value, ctx)
+        ctx.let_inputs[expr.name] = _inputs_under(expr.value, ctx)
         body = _build(expr.body, ctx)
         name = expr.name
 
@@ -533,10 +569,9 @@ def _build_join_delta(expr: mir.Join, ctx: _RenderContext):
     )
     jsite = ctx.new_join_site()
     inners = [_build(i, ctx) for i in expr.inputs]
+    ctx.delta_pipelines += [_inputs_under(i, ctx) for i in expr.inputs]
     for p, (j, _key) in enumerate(op.arr_specs):
-        fed_by = _sole_get(expr.inputs[j])
-        if fed_by is not None:
-            ctx.source_fed[(slot, p)] = (fed_by, jsite)
+        _note_source_fed(ctx, expr.inputs[j], slot, p, jsite)
     ex_sites = {}
     for p in range(len(op.arr_specs)):
         ex_sites[("ins", p)] = ctx.new_exchange_site()
@@ -610,9 +645,7 @@ def _build_join_linear(expr: mir.Join, ctx: _RenderContext):
         # part 0 arranges the accumulated left side (an input only at
         # the first stage), part 1 the input this stage brings in
         for p, j in ((0, 0), (1, i)) if i == 1 else ((1, i),):
-            fed_by = _sole_get(expr.inputs[j])
-            if fed_by is not None:
-                ctx.source_fed[(slot, p)] = (fed_by, jsite)
+            _note_source_fed(ctx, expr.inputs[j], slot, p, jsite)
         acc_schema = op.out_schema
     if len(all_consumed) != len(expr.equivalences):
         # An intra-input equality (all members in one input) would be
@@ -1145,6 +1178,22 @@ class _DataflowBase:
             [jnp.asarray(ovf[k]).astype(jnp.bool_).reshape(()) for k in keys]
         )
 
+    def delta_pipelines_fed(self, ticks) -> tuple:
+        """``(dispatched, fed)`` over one input dict a tick: the
+        delta-join pipelines each step dispatches, and of those the
+        ones one of whose inputs had rows in that tick. Row counts are
+        the host's (``Batch._host_count``); a batch without one counts
+        as holding rows. No device read."""
+        pipes = self._ctx.delta_pipelines
+        fed = 0
+        for inputs in ticks:
+            had = {
+                name for name, b in inputs.items()
+                if getattr(b, "_host_count", 1)
+            }
+            fed += sum(1 for p in pipes if p & had)
+        return len(pipes) * len(ticks), fed
+
     # state_capacity_bytes()'s value, until a tier is regrown
     _reserved_bytes: int | None = None
 
@@ -1177,7 +1226,12 @@ class _DataflowBase:
         every snapshot-size delta is cut before it meets a sort
         (``out_delta_cap``), if the input is that large, and keeps the
         ladder from there; as do the states that a join or a reduce
-        feeds.
+        feeds. An input that reaches the join through no filter starts
+        the site at its whole snapshot's tier instead: a probe that
+        matches each of its rows on a foreign key (TPC-H Q9's nation ->
+        supplier -> lineitem) puts all of them there in the hydration
+        step, five doublings and five compiles up a ladder from
+        ``out_delta_cap``.
 
         How many arrangements were grown."""
         from ..plan.decisions import quantize_cap
@@ -1204,7 +1258,10 @@ class _DataflowBase:
                 self._grow_for(("state", slot, (part, which)), target)
             grown += bool(small)
             self._ctx.sort_in_blocks |= bool(small)
-            probe = min(target, self._ctx.out_delta_cap)
+            probe = (
+                target if (slot, part) in self._ctx.unfiltered_fed
+                else min(target, self._ctx.out_delta_cap)
+            )
             if self._ctx.join_caps[jsite] < probe:
                 self._presized_sites.setdefault(
                     jsite, self._ctx.join_caps[jsite]

@@ -135,6 +135,69 @@ class DictExhausted(RuntimeError):
     strings, storage/persist/codec.py)."""
 
 
+class _SortedStrings:
+    """The dictionary's strings in lexicographic order: one long sorted
+    list and a short sorted list of the newest, merged into it once the
+    short one has ``RECENT`` strings or a reader asks for every string.
+    An insert into one list of millions moves all of it, and a text
+    column gains strings every tick."""
+
+    RECENT = 8192
+
+    def __init__(self, strings=()):
+        import threading
+
+        self._main: list[str] = list(strings)
+        self._recent: list[str] = []
+        self._lock = threading.Lock()
+
+    def __len__(self) -> int:
+        return len(self._main) + len(self._recent)
+
+    def neighbors(self, s: str) -> tuple:
+        """The strings just below and just above ``s`` (None at an
+        end)."""
+        import bisect
+
+        lo = hi = None
+        with self._lock:
+            for run in (self._main, self._recent):
+                i = bisect.bisect_left(run, s)
+                if i > 0 and (lo is None or run[i - 1] > lo):
+                    lo = run[i - 1]
+                if i < len(run) and (hi is None or run[i] < hi):
+                    hi = run[i]
+        return lo, hi
+
+    def add(self, s: str) -> None:
+        import bisect
+
+        with self._lock:
+            bisect.insort(self._recent, s)
+            if len(self._recent) >= self.RECENT:
+                self._merge()
+
+    def add_many(self, strings: list) -> None:
+        """Add sorted ``strings``: one merge, however many."""
+        with self._lock:
+            self._recent = sorted(self._recent + strings)
+            if len(self._recent) >= self.RECENT:
+                self._merge()
+
+    def all(self) -> list[str]:
+        """Every string, sorted; the caller must not change the list."""
+        with self._lock:
+            self._merge()
+            return self._main
+
+    def _merge(self) -> None:
+        if self._recent:
+            # two sorted runs: timsort merges them in linear time, and
+            # rebinds, so a list handed out by all() never changes
+            self._main = sorted(self._main + self._recent)
+            self._recent = []
+
+
 class DictSnapshot:
     """An immutable epoch-coherent read view of the dictionary (see
     StringDictionary.snapshot). Decodes codes of ITS generation even
@@ -155,7 +218,7 @@ class DictSnapshot:
         return [self._by_code[int(c)] for c in np.asarray(codes)]
 
     def items_sorted(self) -> list[tuple[int, str]]:
-        return [(self._codes[s], s) for s in self._sorted]
+        return [(self._codes[s], s) for s in self._sorted.all()]
 
 
 class StringDictionary:
@@ -188,7 +251,7 @@ class StringDictionary:
     def __init__(self):
         import threading
 
-        self._sorted: list[str] = []  # lexicographically sorted
+        self._sorted = _SortedStrings()
         self._codes: dict[str, int] = {}
         self._by_code: dict[int, str] = {}
         self.version = 0  # bumped on every insert (env-cache key)
@@ -244,12 +307,13 @@ class StringDictionary:
         replica path keeps its own dictionary and must hit its own
         exhaustion independently (documented limitation, L7)."""
         with self._lock:
-            n = len(self._sorted)
+            strings = self._sorted.all()
+            n = len(strings)
             span = self.MAX_LABEL - self.MIN_LABEL
             remap: dict[int, int] = {}
             new_codes: dict[str, int] = {}
             new_by_code: dict[int, str] = {}
-            for i, s in enumerate(self._sorted):
+            for i, s in enumerate(strings):
                 new = self.MIN_LABEL + (i + 1) * span // (n + 1)
                 remap[self._codes[s]] = new
                 new_codes[s] = new
@@ -259,7 +323,7 @@ class StringDictionary:
             # rebound too because encode() inserts into it in place.
             self._codes = new_codes
             self._by_code = new_by_code
-            self._sorted = list(self._sorted)
+            self._sorted = _SortedStrings(strings)
             self.version += 1
             self.epoch += 1
             for fn in list(self._listeners):
@@ -296,15 +360,11 @@ class StringDictionary:
         code = self._codes.get(s)
         if code is not None:
             return code
-        import bisect
-
         with self._lock:
             code = self._codes.get(s)
             if code is not None:
                 return code
-            i = bisect.bisect_left(self._sorted, s)
-            lo_s = self._sorted[i - 1] if i > 0 else None
-            hi_s = self._sorted[i] if i < len(self._sorted) else None
+            lo_s, hi_s = self._sorted.neighbors(s)
             lo = self._codes[lo_s] if lo_s is not None else self.MIN_LABEL
             hi = self._codes[hi_s] if hi_s is not None else self.MAX_LABEL
             gap = hi - lo
@@ -315,7 +375,7 @@ class StringDictionary:
                 )
             f = self._frac(lo_s, hi_s, s)
             code = lo + max(1, min(gap - 1, int(gap * f)))
-            self._sorted.insert(i, s)
+            self._sorted.add(s)
             self._codes[s] = code
             self._by_code[code] = s
             self.version += 1
@@ -335,9 +395,10 @@ class StringDictionary:
         insert knows every member up front, so each run of new strings
         falling between two existing neighbors divides that gap EVENLY
         by position — 10^6 strings in one gap get even spacing. Env
-        table builds (the dominant dense-insert source) use this."""
-        import bisect
-
+        table builds, parts of many strings and generated text columns
+        use this: a string that falls alone into a gap takes its middle,
+        which random text (clustered on common words, where content
+        interpolation places it beside a neighbor) needs."""
         with self._lock:
             new = sorted(
                 {s for s in strings if s not in self._codes}
@@ -346,50 +407,54 @@ class StringDictionary:
                 return
             # Group the new strings into runs per existing-neighbor gap.
             runs: list[tuple[int, int, list[str]]] = []
-            k = 0
-            while k < len(new):
-                i = bisect.bisect_left(self._sorted, new[k])
-                hi_s = (
-                    self._sorted[i] if i < len(self._sorted) else None
-                )
-                lo = (
-                    self._codes[self._sorted[i - 1]]
-                    if i > 0
-                    else self.MIN_LABEL
-                )
-                hi = (
-                    self._codes[hi_s]
-                    if hi_s is not None
-                    else self.MAX_LABEL
-                )
-                run = [new[k]]
-                k += 1
-                while k < len(new) and (
-                    hi_s is None or new[k] < hi_s
-                ):
-                    run.append(new[k])
-                    k += 1
-                runs.append((lo, hi, run))
+            gap = None
+            for s in new:
+                lo_s, hi_s = self._sorted.neighbors(s)
+                if (lo_s, hi_s) == gap:
+                    runs[-1][2].append(s)
+                    continue
+                gap = (lo_s, hi_s)
+                runs.append((
+                    self._codes[lo_s] if lo_s is not None
+                    else self.MIN_LABEL,
+                    self._codes[hi_s] if hi_s is not None
+                    else self.MAX_LABEL,
+                    [s],
+                ))
             # Validate EVERY run before mutating anything: a partial
             # insert would leave _sorted stale against _codes, and a
             # concurrent encode() could then hand out an already-taken
             # label (exception atomicity).
+            # A run past either end of a non-empty dictionary is spaced
+            # like the whole dictionary, not spread over the rest of the
+            # label space: the next run there finds room again.
+            density = (self.MAX_LABEL - self.MIN_LABEL) // (
+                len(self._codes) + len(new) + 1
+            )
+            placed = []
             for lo, hi, run in runs:
-                if hi - lo <= len(run):
+                step = (hi - lo) // (len(run) + 1)
+                if self._codes and (
+                    lo == self.MIN_LABEL or hi == self.MAX_LABEL
+                ):
+                    step = min(step, density)
+                if step < 1:
                     raise DictExhausted(
                         f"bulk insert of {len(run)} strings does not "
                         f"fit in gap {hi - lo} at {run[0]!r}"
                     )
-            for lo, hi, run in runs:
-                gap = hi - lo
-                for j, s in enumerate(run, 1):
-                    # Even division guarantees uniqueness within the
-                    # run; lo/hi are exclusive.
-                    code = lo + j * gap // (len(run) + 1)
+                if lo == self.MIN_LABEL and hi != self.MAX_LABEL:
+                    first = hi - len(run) * step
+                else:
+                    first = lo + step
+                placed.append((first, step, run))
+            for first, step, run in placed:
+                for j, s in enumerate(run):
+                    # distinct and inside (lo, hi): lo/hi exclusive
+                    code = first + j * step
                     self._codes[s] = code
                     self._by_code[code] = s
-            # One sorted rebuild instead of n insorts.
-            self._sorted = sorted(self._codes)
+            self._sorted.add_many(new)
             self.version += 1
 
     def decode(self, code: int) -> str:
@@ -400,7 +465,7 @@ class StringDictionary:
 
     def items_sorted(self) -> list[tuple[int, str]]:
         """(code, string) pairs in lexicographic (== code) order."""
-        return [(self._codes[s], s) for s in self._sorted]
+        return [(self._codes[s], s) for s in self._sorted.all()]
 
     def __len__(self) -> int:
         return len(self._sorted)

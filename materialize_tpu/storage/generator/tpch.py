@@ -10,12 +10,14 @@ keys/quantities/discounts, date ranges over 1992-1998.
 
 All columns that the north-star workloads touch are generated with correct
 types (DECIMAL as scaled int64, DATE as days-since-epoch, flags as
-dictionary-coded strings); long text columns (comments) are omitted — they
-are dead weight for every benchmark query.
+dictionary-coded strings). By default the long text columns (comments,
+addresses, phones) are omitted; ``SPEC PARTS`` makes every table's TPC-H
+columns (``SPEC_SCHEMAS``), comments drawn from clause 4.2.2.10's text pool.
 """
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import numpy as np
@@ -151,6 +153,47 @@ REGION_SCHEMA = Schema(
     ]
 )
 
+
+def _widen(schema: Schema, *columns: Column) -> Schema:
+    return Schema(list(schema.columns) + list(columns))
+
+
+def _text(name: str) -> Column:
+    return Column(name, ColumnType.STRING)
+
+
+# Under ``SPEC PARTS`` every table has TPC-H's columns (clause 1.4.1):
+# the ones above, then the ones they leave out, appended so that every
+# column keeps its position.
+SPEC_SCHEMAS = {
+    "lineitem": _widen(
+        LINEITEM_SCHEMA, _text("l_shipinstruct"), _text("l_shipmode"),
+        _text("l_comment"),
+    ),
+    "orders": _widen(ORDERS_SCHEMA, _text("o_clerk"), _text("o_comment")),
+    "supplier": _widen(
+        SUPPLIER_SCHEMA, _text("s_address"), _text("s_phone"),
+        Column("s_acctbal", ColumnType.DECIMAL, scale=2),
+        _text("s_comment"),
+    ),
+    "part": _widen(
+        PART_SCHEMA, _text("p_mfgr"), _text("p_brand"), _text("p_type"),
+        Column("p_size", ColumnType.INT32), _text("p_container"),
+        _text("p_comment"),
+    ),
+    "partsupp": _widen(
+        PARTSUPP_SCHEMA, Column("ps_availqty", ColumnType.INT32),
+        _text("ps_comment"),
+    ),
+    "customer": _widen(
+        CUSTOMER_SCHEMA, _text("c_address"), _text("c_phone"),
+        Column("c_acctbal", ColumnType.DECIMAL, scale=2),
+        _text("c_comment"),
+    ),
+    "nation": _widen(NATION_SCHEMA, _text("n_comment")),
+    "region": _widen(REGION_SCHEMA, _text("r_comment")),
+}
+
 _NATIONS = [
     "ALGERIA", "ARGENTINA", "BRAZIL", "CANADA", "EGYPT", "ETHIOPIA",
     "FRANCE", "GERMANY", "INDIA", "INDONESIA", "IRAN", "IRAQ", "JAPAN",
@@ -163,6 +206,149 @@ _REGIONS = ["AFRICA", "AMERICA", "ASIA", "EUROPE", "MIDDLE EAST"]
 _SEGMENTS = ["AUTOMOBILE", "BUILDING", "FURNITURE", "MACHINERY", "HOUSEHOLD"]
 _NATION_REGION = [0, 1, 1, 1, 4, 0, 3, 3, 2, 2, 4, 4, 2, 4, 0, 0, 0, 1,
                   2, 3, 4, 2, 3, 3, 1]
+# TPC-H clause 4.2.3 (dbgen's `colors`): P_NAME is five distinct words
+# of these 92, joined by single spaces.
+_COLORS = (
+    "almond antique aquamarine azure beige bisque black blanched blue "
+    "blush brown burlywood burnished chartreuse chiffon chocolate coral "
+    "cornflower cornsilk cream cyan dark deep dim dodger drab firebrick "
+    "floral forest frosted gainsboro ghost goldenrod green grey honeydew "
+    "hot indian ivory khaki lace lavender lawn lemon light lime linen "
+    "magenta maroon medium metallic midnight mint misty moccasin navajo "
+    "navy olive orange orchid pale papaya peach peru pink plum powder "
+    "puff purple red rose rosy royal saddle salmon sandy seashell sienna "
+    "sky slate smoke snow spring steel tan thistle tomato turquoise "
+    "violet wheat white yellow"
+).split()
+# The words of TPC-H's text grammar (clauses 4.2.2.13-4.2.2.14; dbgen's
+# `nouns`, `verbs`, ... lists), drawn uniformly here.
+_NOUNS = (
+    "foxes ideas theodolites pinto_beans instructions dependencies "
+    "excuses platelets asymptotes courts dolphins multipliers sauternes "
+    "warthogs frets dinos attainments somas Tiresias' patterns forges "
+    "braids hockey_players frays warhorses dugouts notornis epitaphs "
+    "pearls tithes waters orbits gifts sheaves depths sentiments decoys "
+    "realms pains grouches escapades packages requests accounts deposits"
+).split()
+_NOUNS = [w.replace("_", " ") for w in _NOUNS]
+_VERBS = (
+    "sleep wake are cajole haggle nag use boost affix detect integrate "
+    "maintain nod was lose sublate solve thrash promise engage hinder "
+    "print x-ray breach eat grow impress mold poach serve run dazzle "
+    "snooze doze unwind kindle play hang believe doubt"
+).split()
+_ADJECTIVES = (
+    "furious sly careful blithe quick fluffy slow quiet ruthless thin "
+    "close dogged daring brave stealthy permanent enticing idle busy "
+    "regular final ironic even bold silent special pending unusual "
+    "express"
+).split()
+_ADVERBS = (
+    "sometimes always never furiously slyly carefully blithely quickly "
+    "fluffily slowly quietly ruthlessly thinly closely doggedly daringly "
+    "bravely stealthily permanently enticingly idly busily regularly "
+    "finally ironically evenly boldly silently"
+).split()
+_PREPOSITIONS = (
+    "about|above|according to|across|after|against|along|alongside of|"
+    "among|around|at|atop|before|behind|beneath|beside|besides|between|"
+    "beyond|by|despite|during|except|for|from|in place of|inside|"
+    "instead of|into|near|of|on|outside|over|past|since|through|"
+    "throughout|to|toward|under|until|up|upon|without|with|within"
+).split("|")
+_AUXILIARIES = (
+    "do|may|might|shall|will|would|can|could|should|ought to|must|"
+    "will have to|shall have to|could have to|should have to|"
+    "must have to|need to|try to"
+).split("|")
+_TERMINATORS = [".", ";", ":", "?", "!", "--"]
+# dbgen's pool is 300 MB; 1 MiB keeps a comment's (offset, length)
+# distinct in practice at the scale factors one host generates
+_TEXT_POOL_BYTES = 1 << 20
+_TEXT_POOL_SEED = 4_242_242
+_INSTRUCTIONS = ["DELIVER IN PERSON", "COLLECT COD", "NONE",
+                 "TAKE BACK RETURN"]
+_MODES = ["REG AIR", "AIR", "RAIL", "SHIP", "TRUCK", "MAIL", "FOB"]
+_TYPE_SYLLABLES = (
+    ["STANDARD", "SMALL", "MEDIUM", "LARGE", "ECONOMY", "PROMO"],
+    ["ANODIZED", "BURNISHED", "PLATED", "POLISHED", "BRUSHED"],
+    ["TIN", "NICKEL", "BRASS", "STEEL", "COPPER"],
+)
+_CONTAINER_SYLLABLES = (
+    ["SM", "LG", "MED", "JUMBO", "WRAP"],
+    ["CASE", "BOX", "BAG", "JAR", "PKG", "PACK", "CAN", "DRUM"],
+)
+# clause 4.2.2.7's v-string: at least 64 symbols
+_VSTRING_CHARS = np.asarray(list(
+    "0123456789abcdefghijklmnopqrstuvwxyzABCDEFGHIJKLMNOPQRSTUVWXYZ, "
+))
+
+
+def _sentence(r: list) -> str:
+    """One sentence of clause 4.2.2.14's grammar from 20 draws ``r``:
+    a noun phrase, then a verb phrase and what may follow it, or a
+    prepositional phrase, a verb phrase and a noun or prepositional
+    phrase; then a terminator."""
+
+    def pick(words, x):
+        return words[x % len(words)]
+
+    def noun_phrase(a):
+        form = r[a] % 4
+        noun = pick(_NOUNS, r[a + 3])
+        if form == 0:
+            return [noun]
+        if form == 1:
+            return [pick(_ADJECTIVES, r[a + 2]), noun]
+        if form == 2:
+            return [pick(_ADJECTIVES, r[a + 1]) + ",",
+                    pick(_ADJECTIVES, r[a + 2]), noun]
+        return [pick(_ADVERBS, r[a + 1]), pick(_ADJECTIVES, r[a + 2]),
+                noun]
+
+    def verb_phrase(a):
+        form = r[a] % 4
+        verb = [pick(_VERBS, r[a + 2])]
+        if form in (1, 3):
+            verb = [pick(_AUXILIARIES, r[a + 1])] + verb
+        if form in (2, 3):
+            verb = verb + [pick(_ADVERBS, r[a + 3])]
+        return verb
+
+    def prep_phrase(a):
+        return [pick(_PREPOSITIONS, r[a]), "the"] + noun_phrase(a + 1)
+
+    form = r[0] % 5
+    if form < 3:
+        words = noun_phrase(1) + verb_phrase(5)
+        if form == 1:
+            words += prep_phrase(9)
+        elif form == 2:
+            words += noun_phrase(9)
+    else:
+        words = noun_phrase(1) + prep_phrase(5) + verb_phrase(10)
+        words += noun_phrase(14) if form == 3 else prep_phrase(14)
+    return " ".join(words) + pick(_TERMINATORS, r[19])
+
+
+@functools.lru_cache(maxsize=1)
+def text_pool() -> str:
+    """Clause 4.2.2.10's text pool: sentences of the grammar joined by
+    single spaces, ``_TEXT_POOL_BYTES`` long, the same in every process
+    (dbgen's is not seeded by the data's seed either)."""
+    out, size, j = [], 0, 0
+    while size < _TEXT_POOL_BYTES:
+        draws = TpchGenerator._mix64(
+            np.uint64(_TEXT_POOL_SEED),
+            np.arange(j, j + 4096, dtype=np.uint64)[:, None],
+            np.arange(20, dtype=np.uint64)[None, :],
+        ).tolist()
+        for r in draws:
+            s = _sentence(r)
+            out.append(s)
+            size += len(s) + 1
+        j += 4096
+    return " ".join(out)[:_TEXT_POOL_BYTES]
 
 
 @dataclass
@@ -171,10 +357,20 @@ class TpchGenerator:
 
     Row counts follow the spec: orders = 1.5M * sf, lineitem ~ 4 per
     order, part = 200k * sf, supplier = 10k * sf, customer = 150k * sf.
+
+    ``spec_parts`` (``SPEC PARTS``) takes TPC-H clause 4.2.3's rules for
+    what ties parts to suppliers and names them: P_NAME is five distinct
+    colour words, PS_SUPPKEY is the clause's formula for i = 0..3, and
+    L_SUPPKEY the same formula with i drawn from 0..3, so that every
+    lineitem matches a partsupp row. It also gives every table all of
+    TPC-H's columns (``SPEC_SCHEMAS``): comments are clause 4.2.2.10's
+    text strings, substrings of ``text_pool()``. Unset, every table and
+    the string dictionary are what they were.
     """
 
     sf: float = 0.01
     seed: int = 1
+    spec_parts: bool = False
 
     def __post_init__(self):
         self.n_orders = max(int(1_500_000 * self.sf), 16)
@@ -186,6 +382,20 @@ class TpchGenerator:
         self._prio_codes = GLOBAL_DICT.encode_many(
             ["1-URGENT", "2-HIGH", "3-MEDIUM", "4-NOT SPECIFIED", "5-LOW"]
         )
+        if self.spec_parts:
+            self._instruction_codes = GLOBAL_DICT.encode_many(_INSTRUCTIONS)
+            self._mode_codes = GLOBAL_DICT.encode_many(_MODES)
+
+    def schema(self, table: str) -> Schema:
+        """The table's schema: TPC-H's columns under ``spec_parts``."""
+        if self.spec_parts:
+            return SPEC_SCHEMAS[table]
+        return {
+            "lineitem": LINEITEM_SCHEMA, "orders": ORDERS_SCHEMA,
+            "supplier": SUPPLIER_SCHEMA, "part": PART_SCHEMA,
+            "partsupp": PARTSUPP_SCHEMA, "customer": CUSTOMER_SCHEMA,
+            "nation": NATION_SCHEMA, "region": REGION_SCHEMA,
+        }[table]
 
     # -- per-order generation (deterministic in orderkey) -------------------
     #
@@ -198,7 +408,8 @@ class TpchGenerator:
     # break EXISTS/DISTINCT/Threshold semantics (negative multiplicities
     # are outside the differential contract; the reference's tpch.rs tick
     # loop deletes exactly the rows it inserted).
-    def _mix64(self, *vals):
+    @staticmethod
+    def _mix64(*vals):
         with np.errstate(over="ignore"):
             h = np.uint64(0x9E3779B97F4A7C15)
             for v in vals:
@@ -217,6 +428,47 @@ class TpchGenerator:
         """Uniform ints in [lo, hi), elementwise over broadcast keys."""
         span = np.uint64(hi - lo)
         return (self._mix64(*keys) % span).astype(np.int64) + lo
+
+    def _texts(self, lo: int, hi: int, tag: int, *keys) -> list:
+        """Clause 4.2.2.10's text string [lo, hi]: the substring of the
+        text pool at a drawn offset, of a drawn length; draws ``tag``
+        and ``tag + 1`` of ``keys``."""
+        pool = text_pool()
+        off = self._draw(0, len(pool) - hi, *keys, tag).tolist()
+        n = self._draw(lo, hi + 1, *keys, tag + 1).tolist()
+        return [pool[a:a + b] for a, b in zip(off, n)]
+
+    @staticmethod
+    def _encode(strings: list) -> np.ndarray:
+        # new strings in bulk, spaced in their label gaps: one at a
+        # time, random text runs a gap dry (StringDictionary.encode_bulk)
+        GLOBAL_DICT.encode_bulk(strings)
+        return GLOBAL_DICT.encode_many(strings)
+
+    def _vstrings(self, lo: int, hi: int, tag: int, keys) -> list:
+        """Clause 4.2.2.7's v-string [lo, hi] of each key: draws ``tag``
+        (the length) and ``tag + 1`` (the characters)."""
+        sd = np.uint64(self.seed * 1_000_003)
+        k = np.asarray(keys, np.uint64)
+        n = self._draw(lo, hi + 1, sd, k, tag).tolist()
+        chars = _VSTRING_CHARS[self._draw(
+            0, len(_VSTRING_CHARS), sd, k[:, None],
+            np.arange(hi, dtype=np.uint64)[None, :], tag + 1,
+        )]
+        return ["".join(row[:m]) for row, m in zip(chars.tolist(), n)]
+
+    def _phones(self, nation, tag: int, keys) -> list:
+        """Clause 4.2.2.9: country code (nation key + 10), then three
+        local numbers; draws ``tag`` to ``tag + 2``."""
+        sd = np.uint64(self.seed * 1_000_003)
+        k = np.asarray(keys, np.uint64)
+        a = self._draw(100, 1000, sd, k, tag).tolist()
+        b = self._draw(100, 1000, sd, k, tag + 1).tolist()
+        c = self._draw(1000, 10000, sd, k, tag + 2).tolist()
+        return [
+            f"{n + 10}-{x}-{y}-{z}"
+            for n, x, y, z in zip(np.asarray(nation).tolist(), a, b, c)
+        ]
 
     def lineitems_for_orders(
         self, orderkeys: np.ndarray, version: int = 0
@@ -238,7 +490,12 @@ class TpchGenerator:
         u = okeys.astype(np.uint64)
         li = linenumber.astype(np.uint64)
         partkey = self._draw(1, self.n_part + 1, sd, u, li, 1)
-        suppkey = self._draw(1, self.n_supplier + 1, sd, u, li, 2)
+        if self.spec_parts:
+            suppkey = self.spec_suppkey(
+                partkey, self._draw(0, 4, sd, u, li, 12)
+            )
+        else:
+            suppkey = self._draw(1, self.n_supplier + 1, sd, u, li, 2)
         quantity = self._draw(1, 51, sd, u, li, 3) * 100  # 1..50, scale 2
         retail = 90_000 + (partkey * 100) % 200_000 + (partkey % 1000) * 100
         extendedprice = (quantity // 100) * retail
@@ -274,6 +531,16 @@ class TpchGenerator:
             commitdate.astype(np.int32),
             receiptdate.astype(np.int32),
         ]
+        if self.spec_parts:
+            cols += [
+                self._instruction_codes[
+                    self._draw(0, len(_INSTRUCTIONS), sd, u, li, 13)
+                ].astype(np.int64),
+                self._mode_codes[
+                    self._draw(0, len(_MODES), sd, u, li, 14)
+                ].astype(np.int64),
+                self._encode(self._texts(10, 43, 15, sd, u, li)),
+            ]
         return cols
 
     def orders_rows(self, orderkeys: np.ndarray):
@@ -290,7 +557,7 @@ class TpchGenerator:
         prio = self._prio_codes[self._draw(0, 5, sd, u, 24)].astype(
             np.int64
         )
-        return [
+        cols = [
             orderkeys,
             custkey,
             status,
@@ -300,6 +567,15 @@ class TpchGenerator:
             # TPC-H clause 4.2.3: o_shippriority is 0 on every order
             np.zeros(len(orderkeys), np.int32),
         ]
+        if self.spec_parts:
+            clerks = self._draw(
+                1, max(int(self.sf * 1000), 1) + 1, sd, u, 25
+            ).tolist()
+            cols += [
+                self._encode([f"Clerk#{c:09d}" for c in clerks]),
+                self._encode(self._texts(19, 78, 26, sd, u)),
+            ]
+        return cols
 
     # -- static dimension tables -------------------------------------------
     def supplier_table(self):
@@ -309,26 +585,103 @@ class TpchGenerator:
         names = GLOBAL_DICT.encode_many(
             [f"Supplier#{k:09d}" for k in keys]
         )
-        return [keys, nation.astype(np.int64), names]
+        cols = [keys, nation.astype(np.int64), names]
+        if self.spec_parts:
+            sd, u = np.uint64(self.seed * 1_000_003), keys.astype(np.uint64)
+            cols += [
+                self._encode(self._vstrings(10, 40, 55, keys)),
+                self._encode(self._phones(nation, 57, keys)),
+                self._draw(-99_999, 1_000_000, sd, u, 60),
+                self._encode(self._texts(25, 100, 61, sd, u)),
+            ]
+        return cols
+
+    def spec_suppkey(self, partkey, i):
+        """TPC-H clause 4.2.3: the i-th supplier of a part, i in 0..3,
+        (partkey + i * (S/4 + (partkey - 1)/S)) mod S + 1, integer
+        division. Where 2 or 3 times S/4 + (partkey - 1)/S is a multiple
+        of S (some parts at S = 20, SF 0.002, among other small scale
+        factors), it repeats a supplier for that part."""
+        s = self.n_supplier
+        partkey = np.asarray(partkey, np.int64)
+        return (partkey + i * (s // 4 + (partkey - 1) // s)) % s + 1
+
+    def spec_part_names(self, keys) -> list:
+        """Five distinct words of ``_COLORS`` a part, a pure function of
+        (seed, p_partkey): the words of least counter hash."""
+        sd = np.uint64(self.seed * 1_000_003)
+        word = np.arange(len(_COLORS), dtype=np.uint64)
+        words = np.asarray(_COLORS)
+        names = []
+        for lo in range(0, len(keys), 4096):
+            k = np.asarray(keys[lo:lo + 4096], np.uint64)
+            h = self._mix64(sd, k[:, None], word[None, :], 41)
+            picked = words[np.argsort(h, axis=1, kind="stable")[:, :5]]
+            names += [" ".join(row) for row in picked.tolist()]
+        return names
 
     def part_table(self):
         keys = np.arange(1, self.n_part + 1)
-        names = GLOBAL_DICT.encode_many([f"part {k % 92}" for k in keys])
+        if not self.spec_parts:
+            names = GLOBAL_DICT.encode_many(
+                [f"part {k % 92}" for k in keys]
+            )
+        else:
+            names = self._encode(self.spec_part_names(keys))
         retail = (
             90_000 + (keys * 100) % 200_000 + (keys % 1000) * 100
         ).astype(np.int64)
-        return [keys, names, retail]
+        cols = [keys, names, retail]
+        if self.spec_parts:
+            sd, u = np.uint64(self.seed * 1_000_003), keys.astype(np.uint64)
+            mfgr = self._draw(1, 6, sd, u, 42)
+            brand = mfgr * 10 + self._draw(1, 6, sd, u, 43)
+            kinds = [
+                np.asarray(words)[self._draw(0, len(words), sd, u, tag)]
+                for words, tag in zip(_TYPE_SYLLABLES, (44, 45, 46))
+            ]
+            box = [
+                np.asarray(words)[self._draw(0, len(words), sd, u, tag)]
+                for words, tag in zip(_CONTAINER_SYLLABLES, (48, 49))
+            ]
+            cols += [
+                self._encode([f"Manufacturer#{m}" for m in mfgr.tolist()]),
+                self._encode([f"Brand#{b}" for b in brand.tolist()]),
+                self._encode(
+                    [" ".join(t) for t in zip(*(k.tolist() for k in kinds))]
+                ),
+                self._draw(1, 51, sd, u, 47).astype(np.int32),
+                self._encode(
+                    [" ".join(t) for t in zip(*(k.tolist() for k in box))]
+                ),
+                self._encode(self._texts(5, 22, 50, sd, u)),
+            ]
+        return cols
 
     def partsupp_table(self):
         rng = np.random.default_rng(self.seed + 11)
         pkeys = np.repeat(np.arange(1, self.n_part + 1), 4)
-        skeys = (
-            (pkeys + np.tile(np.arange(4), self.n_part) * (
-                self.n_supplier // 4 + 1
-            )) % self.n_supplier
-        ) + 1
+        if self.spec_parts:
+            skeys = self.spec_suppkey(
+                pkeys, np.tile(np.arange(4), self.n_part)
+            )
+        else:
+            skeys = (
+                (pkeys + np.tile(np.arange(4), self.n_part) * (
+                    self.n_supplier // 4 + 1
+                )) % self.n_supplier
+            ) + 1
         cost = rng.integers(100, 1000_00, size=len(pkeys)).astype(np.int64)
-        return [pkeys, skeys, cost]
+        cols = [pkeys, skeys, cost]
+        if self.spec_parts:
+            sd = np.uint64(self.seed * 1_000_003)
+            u = pkeys.astype(np.uint64)
+            i = np.tile(np.arange(4, dtype=np.uint64), self.n_part)
+            cols += [
+                self._draw(1, 10_000, sd, u, i, 52).astype(np.int32),
+                self._encode(self._texts(49, 198, 53, sd, u, i)),
+            ]
+        return cols
 
     def customer_table(self):
         rng = np.random.default_rng(self.seed + 13)
@@ -345,31 +698,52 @@ class TpchGenerator:
                 keys.astype(np.uint64), 31,
             )
         ].astype(np.int64)
-        return [keys, nation.astype(np.int64), names, segment]
+        cols = [keys, nation.astype(np.int64), names, segment]
+        if self.spec_parts:
+            sd, u = np.uint64(self.seed * 1_000_003), keys.astype(np.uint64)
+            cols += [
+                self._encode(self._vstrings(10, 40, 63, keys)),
+                self._encode(self._phones(nation, 65, keys)),
+                self._draw(-99_999, 1_000_000, sd, u, 68),
+                self._encode(self._texts(29, 116, 69, sd, u)),
+            ]
+        return cols
 
     def nation_table(self):
         names = GLOBAL_DICT.encode_many(_NATIONS)
-        return [
+        cols = [
             np.arange(25, dtype=np.int64),
             np.asarray(_NATION_REGION, dtype=np.int64),
             names,
         ]
+        if self.spec_parts:
+            sd = np.uint64(self.seed * 1_000_003)
+            cols.append(self._encode(self._texts(
+                31, 114, 71, sd, np.arange(25, dtype=np.uint64)
+            )))
+        return cols
 
     def region_table(self):
         names = GLOBAL_DICT.encode_many(_REGIONS)
-        return [np.arange(5, dtype=np.int64), names]
+        cols = [np.arange(5, dtype=np.int64), names]
+        if self.spec_parts:
+            sd = np.uint64(self.seed * 1_000_003)
+            cols.append(self._encode(self._texts(
+                31, 115, 73, sd, np.arange(5, dtype=np.uint64)
+            )))
+        return cols
 
     def table_batch(self, name: str, time: int = 0) -> Batch:
         """A static table as one insert batch (dimension-table snapshot)."""
-        schema, cols = {
-            "supplier": (SUPPLIER_SCHEMA, self.supplier_table),
-            "part": (PART_SCHEMA, self.part_table),
-            "partsupp": (PARTSUPP_SCHEMA, self.partsupp_table),
-            "customer": (CUSTOMER_SCHEMA, self.customer_table),
-            "nation": (NATION_SCHEMA, self.nation_table),
-            "region": (REGION_SCHEMA, self.region_table),
-        }[name]
-        cols = cols()
+        cols = {
+            "supplier": self.supplier_table,
+            "part": self.part_table,
+            "partsupp": self.partsupp_table,
+            "customer": self.customer_table,
+            "nation": self.nation_table,
+            "region": self.region_table,
+        }[name]()
+        schema = self.schema(name)
         n = len(cols[0])
         return Batch.from_numpy(
             schema,
@@ -392,11 +766,12 @@ class TpchGenerator:
             )
             cols = self.lineitems_for_orders(keys)
             n = len(cols[0])
+            schema = self.schema("lineitem")
             cols, diffs, n = presort_hash(
-                LINEITEM_SCHEMA, cols, np.ones(n, np.int64)
+                schema, cols, np.ones(n, np.int64)
             )
             yield Batch.from_numpy(
-                LINEITEM_SCHEMA,
+                schema,
                 cols,
                 np.full(n, time, np.uint64),
                 diffs,
@@ -440,9 +815,10 @@ class TpchGenerator:
         diffs = np.concatenate(
             [np.full(n_old, -1, np.int64), np.ones(n_new, np.int64)]
         )
-        cols, diffs, n = presort_hash(LINEITEM_SCHEMA, cols, diffs)
+        schema = self.schema("lineitem")
+        cols, diffs, n = presort_hash(schema, cols, diffs)
         times = np.full(n, time, np.uint64)
         return Batch.from_numpy(
-            LINEITEM_SCHEMA, cols, times, diffs, capacity=capacity,
+            schema, cols, times, diffs, capacity=capacity,
             hints=("hash_consolidated",),
         )

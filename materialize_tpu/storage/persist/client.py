@@ -93,7 +93,11 @@ class PartCache:
         self, key, schema, cols, nulls, time, diff, nbytes,
         rehydrated: bool = False,
         dict_epoch: int | None = None,
+        skip: frozenset = frozenset(),
     ) -> None:
+        """``skip``: the string columns decoded as code 0
+        (codec.decode_part); only a reader that skips them too is
+        served the entry."""
         mode, budget = self._config()
         if mode == "all_cold":
             return
@@ -111,7 +115,9 @@ class PartCache:
                 self.rehydrations += 1
             if key in self._parts:
                 self.hot_bytes -= self._parts.pop(key)[5]
-            self._parts[key] = (schema, cols, nulls, time, diff, nbytes)
+            self._parts[key] = (
+                schema, cols, nulls, time, diff, nbytes, skip
+            )
             self.hot_bytes += nbytes
             if mode == "auto":
                 while self.hot_bytes > budget and len(self._parts) > 1:
@@ -120,11 +126,13 @@ class PartCache:
                     self.hot_bytes -= ent[5]
                     self.evictions += 1
 
-    def get(self, key):
+    def get(self, key, skip: frozenset = frozenset()):
         with self._lock:
             self._check_epoch_locked()
             ent = self._parts.pop(key, None)
-            if ent is None:
+            if ent is None or not ent[6] <= skip:
+                if ent is not None:
+                    self._parts[key] = ent
                 self.misses += 1
                 return None
             self._parts[key] = ent  # move to MRU end
@@ -296,19 +304,21 @@ class ReadHandle:
     def expire(self) -> None:
         self.machine.expire_reader(self.reader_id)
 
-    def _read_parts(self, batches):
+    def _read_parts(self, batches, skip: frozenset = frozenset()):
         """Fetch parts, hot tier first. A part key that is GONE from
         blob was swapped out by a concurrent compaction between our
         state load and this fetch: raise CompactionRace — the caller
         reloads and re-reads (the merged part has identical content),
         and ONLY that exception retries (a decode failure is a real
-        codec bug and must surface, operators.py AsOfError note)."""
+        codec bug and must surface, operators.py AsOfError note).
+        ``skip``: string columns the caller never reads
+        (codec.decode_part)."""
         schema = None
         out = []
         cache = getattr(self.machine, "part_cache", None)
         for b in batches:
             for k in b.keys:
-                ent = cache.get(k) if cache is not None else None
+                ent = cache.get(k, skip) if cache is not None else None
                 if ent is not None:
                     TALLY.cache_hits += 1
                     sch, cols, nulls, time, diff = ent[:5]
@@ -324,7 +334,7 @@ class ReadHandle:
                     from ...repr.schema import GLOBAL_DICT
 
                     dict_epoch = GLOBAL_DICT.epoch
-                    sch, cols, nulls, time, diff = decode_part(data)
+                    sch, cols, nulls, time, diff = decode_part(data, skip)
                     if cache is not None:
                         # Cold part's first read: rehydrate into the
                         # hot tier (counted; doc/perf.md cost model).
@@ -332,12 +342,13 @@ class ReadHandle:
                             k, sch, cols, nulls, time, diff, len(data),
                             rehydrated=True,
                             dict_epoch=dict_epoch,
+                            skip=skip,
                         )
                 schema = schema or sch
                 out.append((cols, nulls, time, diff))
         return schema, out
 
-    def snapshot(self, as_of: int):
+    def snapshot(self, as_of: int, skip: frozenset = frozenset()):
         """All updates with time <= as_of, times forwarded to as_of —
         the definite collection at as_of (ASOF semantics,
         doc/developer/overview.md:114-120). Requires since <= as_of <
@@ -364,7 +375,7 @@ class ReadHandle:
                 # Batches entirely above as_of cannot contribute: skip
                 # the fetch.
                 schema, parts = self._read_parts(
-                    [b for b in st.batches if b.lower <= as_of]
+                    [b for b in st.batches if b.lower <= as_of], skip
                 )
                 break
             except CompactionRace:
@@ -407,7 +418,7 @@ class ReadHandle:
             # consensus.
             PUBSUB.wait(self.machine.shard, min(remaining, 0.002))
 
-    def fetch(self, lo: int, hi: int):
+    def fetch(self, lo: int, hi: int, skip: frozenset = frozenset()):
         """Updates with lo <= time < hi. Caller must ensure hi <= upper
         (completeness) and lo >= since (not compacted away). Retries
         the part fetch when racing a compaction swap, like snapshot."""
@@ -422,7 +433,7 @@ class ReadHandle:
                 b for b in st.batches if b.upper > lo and b.lower < hi
             ]
             try:
-                schema, parts = self._read_parts(batches)
+                schema, parts = self._read_parts(batches, skip)
                 break
             except CompactionRace:
                 self.race_retries += 1
@@ -443,13 +454,16 @@ class ReadHandle:
         cols, nulls, time, diff = concat_update_parts(sel, arity)
         return schema, cols, nulls, time, diff
 
-    def listen_next(self, frontier: int, timeout: float = 5.0):
+    def listen_next(
+        self, frontier: int, timeout: float = 5.0,
+        skip: frozenset = frozenset(),
+    ):
         """Block for the upper to pass ``frontier``; returns (updates in
         [frontier, new_upper), new_upper) or None on timeout."""
         upper = self.wait_for_upper(frontier, timeout)
         if upper is None:
             return None
-        return self.fetch(frontier, upper), upper
+        return self.fetch(frontier, upper, skip), upper
 
 
 class PersistClient:

@@ -155,9 +155,11 @@ def encode_part(
     return body + struct.pack("<I", zlib.crc32(body))
 
 
-def decode_part(data: bytes):
+def decode_part(data: bytes, skip: frozenset = frozenset()):
     """Decode a part -> (schema, cols, nulls, time, diff) host arrays.
-    String columns come back as process-global dictionary codes."""
+    String columns come back as process-global dictionary codes, but for
+    the column positions in ``skip``: those come back as code 0 and add
+    nothing to the dictionary (a reader that never looks at them)."""
     if len(data) < 12 or data[:4] != MAGIC:
         raise PartCorruptError("bad magic")
     (crc,) = struct.unpack("<I", data[-4:])
@@ -180,7 +182,13 @@ def decode_part(data: bytes):
         columns.append(Column(m["name"], ctype, m["nullable"], m["scale"]))
         a = _dec_buffer(bufs[bi], m.get("enc", "raw"), ctype.dtype, n)
         bi += 1
-        if m["strings"] is not None:
+        if m["strings"] is not None and len(columns) - 1 in skip:
+            a = np.zeros(n, np.int64)
+        elif m["strings"] is not None:
+            if len(m["strings"]) >= 64:
+                # many strings at once (a snapshot's, a text column's):
+                # spaced in their label gaps (encode_bulk)
+                GLOBAL_DICT.encode_bulk(m["strings"])
             remap = GLOBAL_DICT.encode_many(m["strings"])
             a = (
                 remap[a]

@@ -25,9 +25,11 @@ from contextlib import contextmanager
 import numpy as np
 
 from ...arrangement.spine import device_nbytes
+from ...expr import relation as mir
+from ...expr.strings import tables_built
 from ...render.dataflow import Dataflow
 from ...repr.batch import Batch, capacity_tier
-from ...repr.schema import Schema
+from ...repr.schema import ColumnType, Schema
 from ...utils.metrics import REGISTRY
 from ...utils.trace import TRACER
 from .client import PersistClient, ReadHandle, WriteHandle
@@ -85,17 +87,51 @@ def updates_to_batch(
     )
 
 
+def unread_string_columns(
+    expr: mir.RelationExpr, name: str, schema: Schema
+) -> frozenset:
+    """The STRING columns of input ``name`` that ``expr`` never reads:
+    every Get of it sits directly under a Project that drops them (what
+    projection pushdown leaves). Their values reach no operator, so a
+    source import may leave them undecoded."""
+    read: set = set()
+
+    def walk(e, parent) -> None:
+        if isinstance(e, mir.Get) and e.name == name:
+            if isinstance(parent, mir.Project):
+                read.update(parent.outputs)
+            else:
+                read.update(range(schema.arity))
+            return
+        for ch in e.children():
+            walk(ch, e)
+
+    walk(expr, None)
+    return frozenset(
+        i for i, c in enumerate(schema.columns)
+        if c.ctype is ColumnType.STRING and i not in read
+    )
+
+
 class ShardSource:
     """Import one shard into a dataflow: snapshot + listen chunks
-    (persist_source analog)."""
+    (persist_source analog). ``skip``: string columns the dataflow never
+    reads, imported as code 0 without a dictionary lookup
+    (``unread_string_columns``)."""
 
-    def __init__(self, reader: ReadHandle, schema: Schema):
+    def __init__(
+        self, reader: ReadHandle, schema: Schema,
+        skip: frozenset = frozenset(),
+    ):
         self.reader = reader
         self.schema = schema
+        self.skip = skip
         self.frontier: int | None = None  # set by snapshot()/resume_at()
 
     def snapshot(self, as_of: int) -> "tuple[Batch, int]":
-        _sch, cols, nulls, time, diff = self.reader.snapshot(as_of)
+        _sch, cols, nulls, time, diff = self.reader.snapshot(
+            as_of, self.skip
+        )
         self.frontier = as_of + 1
         return (
             updates_to_batch(self.schema, cols, nulls, time, diff, as_of),
@@ -109,7 +145,7 @@ class ShardSource:
         """Next chunk beyond the frontier, forwarded to the chunk's last
         time. Returns (batch, chunk_time, new_frontier) or None."""
         assert self.frontier is not None, "snapshot()/resume_at() first"
-        got = self.reader.listen_next(self.frontier, timeout)
+        got = self.reader.listen_next(self.frontier, timeout, self.skip)
         if got is None:
             return None
         (_sch, cols, nulls, time, diff), new_upper = got
@@ -124,7 +160,7 @@ class ShardSource:
         assert self.frontier is not None and target > self.frontier - 1
         with persist_phase("span.fetch", "source") as ph:
             _sch, cols, nulls, time, diff = self.reader.fetch(
-                self.frontier, target
+                self.frontier, target, self.skip
             )
             ph.add(rows=len(diff))
         with TRACER.phase("span.upload") as ph:
@@ -532,7 +568,10 @@ class MaintainedView:
         self._pre_step_multisets = None
         self._subscribers: list = []
         self.sources = {
-            name: ShardSource(client.open_reader(shard), schema)
+            name: ShardSource(
+                client.open_reader(shard), schema,
+                unread_string_columns(dataflow.expr, name, schema),
+            )
             for name, (shard, schema) in source_shards.items()
         }
         if index_sources:
@@ -581,6 +620,10 @@ class MaintainedView:
             "as of its last committed span",
             label="dataflow",
         )
+        # the span records count the string side-tables built since
+        # the view's previous record, the first since it opened: a
+        # hydration's builds are not counted (doc/observability.md)
+        self._tables_built_seen: int | None = None
         # Register as the dataflow's span barrier: any df-level state
         # read sequences through sync_spans() automatically.
         self._barrier = _ViewSpanBarrier(self)
@@ -1042,7 +1085,7 @@ class MaintainedView:
         prefetched = 1 if self._kept else 0
         span = self._open_span(self.upper)
         with TRACER.within(span):
-            if not self._step_tick(timeout):
+            if not self._step_tick(timeout, span):
                 return False
         self._close_span(span, 1, prefetched=prefetched)
         return True
@@ -1051,6 +1094,8 @@ class MaintainedView:
         """The record of one committed span (doc/observability.md):
         the phases timed while it is the context are its children. A
         span that commits nothing is never closed and records nothing."""
+        if self._tables_built_seen is None:
+            self._tables_built_seen = tables_built()
         return TRACER.open(
             "span", dataflow=getattr(self.df, "name", "") or "df",
             lower=lower,
@@ -1067,12 +1112,15 @@ class MaintainedView:
         output spine reserve on the device ride along (shapes, no
         device read)."""
         reserved = self.df.state_capacity_bytes()
+        built = tables_built()
         TRACER.close(
             span, upper=self._upper, ticks=ticks, epoch=self.span_epoch,
             replayed=replayed, prefetched_ticks=prefetched,
             overlapped_commit_ticks=overlapped,
             state_capacity_bytes=reserved,
+            string_tables_built=built - self._tables_built_seen,
         )
+        self._tables_built_seen = built
         self._capacity_gauge.set(
             getattr(self.df, "name", "") or "df", reserved
         )
@@ -1099,7 +1147,16 @@ class MaintainedView:
             self._record_history(t, out)
         self._upper = t + 1
 
-    def _step_tick(self, timeout: float) -> bool:
+    def _count_pipelines(self, span, ticks) -> None:
+        """The delta-join pipelines the span's steps dispatch, and
+        those fed by rows, onto its record (doc/observability.md)."""
+        if span is not None:
+            n, fed = self.df.delta_pipelines_fed(
+                [inp for _, inp, _ in ticks]
+            )
+            span.attrs.update(delta_pipelines=n, fed_delta_pipelines=fed)
+
+    def _step_tick(self, timeout: float, span=None) -> bool:
         lower = self.upper
         if not self.sources:
             # A source-less (pure constant) dataflow: one step at time 0
@@ -1119,6 +1176,7 @@ class MaintainedView:
         ticks, _ = self._take_ready_ticks(lower, 1, timeout)
         if not ticks:
             return False
+        self._count_pipelines(span, ticks)
         ((t, polled, arrived),) = ticks
         if self._sink_finalizes:
             self._pre_step_multisets = (
@@ -1328,6 +1386,7 @@ class MaintainedView:
             )
             if not ticks:
                 return False
+            self._count_pipelines(span, ticks)
             if self.df.time != ticks[0][0]:
                 self.df.time = ticks[0][0]
             # Our own dispatch must not flush the span before through
@@ -1401,6 +1460,7 @@ class MaintainedView:
             # No new input: drain the in-flight span so the committed
             # frontier (and peeks waiting on it) still progresses.
             return self._commit_inflight()
+        self._count_pipelines(span, ticks)
         arrived = _time.monotonic()
         if (
             len(self.df._defer_log)

@@ -108,9 +108,12 @@ def q9_mir() -> mir.RelationExpr:
 
     Exercises JoinPlan::Delta — one update pipeline per input over shared
     arrangements (render/join/delta_join.rs:51; BASELINE.json config 3).
-    The reference's ``p_name LIKE '%green%'`` filter is omitted
-    (dictionary-coded strings have no device substring search yet); the
-    join/aggregate plan shape is identical.
+    The query's ``p_name LIKE '%green%'`` filter is left out of this
+    hand-built plan, so every part joins; the join/aggregate plan shape
+    is the query's. SQL ``LIKE`` runs on the device as a dictionary
+    side-table lookup (``expr/strings.py``): TPC-H Q9 as SQL, with the
+    filter, is the ``tpch_q9`` benchmark configuration
+    (``tests/test_tpch_q9.py``).
 
     Output: (n_name, o_year, sum_profit scale-4 decimal).
     """

@@ -286,3 +286,55 @@ class TestSnapshotCoherence:
         # KeyError from post-rebalance insertions into _sorted).
         for k, s in snap.items_sorted():
             assert snap.decode(k) == s
+
+
+def test_concurrent_inserts_keep_codes_in_string_order():
+    """Threads inserting one at a time and in bulk into one dictionary
+    (the replica decodes parts while env tables are built): every string
+    gets one code, and codes ascend with the strings."""
+    import random
+    import sys
+    import threading
+
+    from materialize_tpu.repr.schema import StringDictionary
+
+    d = StringDictionary()
+    errors: list = []
+
+    def work(k: int) -> None:
+        rng = random.Random(k)
+        try:
+            for j in range(40):
+                batch = [
+                    f"t{k % 4} {rng.random():.12f}"
+                    for _ in range(rng.choice((1, 5, 20)))
+                ]
+                if j % 2:
+                    d.encode_bulk(batch)
+                for s in batch:
+                    d.encode(s)
+                if j % 10 == 0:
+                    d.items_sorted()
+        except Exception as e:  # reported by the assertion below
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [
+            threading.Thread(target=work, args=(k,)) for k in range(16)
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors[:3]
+    items = d.items_sorted()
+    strings = [s for _, s in items]
+    codes = [c for c, _ in items]
+    assert strings == sorted(set(strings)) and len(strings) == len(d)
+    assert all(a < b for a, b in zip(codes, codes[1:]))
+    assert all(d.decode(c) == s for c, s in items)

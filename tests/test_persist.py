@@ -73,6 +73,70 @@ class TestCodec:
         np.testing.assert_array_equal(t2, time)
         np.testing.assert_array_equal(d2, diff)
 
+    def test_skipped_string_columns_stay_out_of_the_dictionary(
+        self, monkeypatch
+    ):
+        """A reader that never looks at a string column gets code 0 for
+        it, and its strings never enter this process's dictionary."""
+        from materialize_tpu.repr.schema import StringDictionary
+        from materialize_tpu.storage.persist import codec
+
+        schema = Schema(
+            [
+                Column("name", ColumnType.STRING),
+                Column("note", ColumnType.STRING),
+            ]
+        )
+        notes = [f"skipped note {i}" for i in range(100)]
+        writer = StringDictionary()  # another process's dictionary
+        monkeypatch.setattr(codec, "GLOBAL_DICT", writer)
+        data = encode_part(
+            schema,
+            [writer.encode_many(["a", "b"] * 50), writer.encode_many(notes)],
+            [None, None],
+            np.zeros(100, np.uint64),
+            np.ones(100, np.int64),
+        )
+        monkeypatch.undo()
+        before = len(GLOBAL_DICT)
+        _sch, cols, _n, _t, _d = decode_part(data, frozenset({1}))
+        assert GLOBAL_DICT.decode_many(cols[0][:2]) == ["a", "b"]
+        assert (cols[1] == 0).all()
+        assert len(GLOBAL_DICT) <= before + 2
+        _sch, cols, _n, _t, _d = decode_part(data)
+        assert GLOBAL_DICT.decode_many(cols[1]) == notes
+
+    def test_unread_string_columns(self):
+        from materialize_tpu.storage.persist.operators import (
+            unread_string_columns,
+        )
+
+        sch = Schema(
+            [
+                Column("k", ColumnType.INT64),
+                Column("name", ColumnType.STRING),
+                Column("note", ColumnType.STRING),
+            ]
+        )
+        get = mir.Get("t", sch)
+        assert unread_string_columns(
+            mir.Project(get, (0, 1)), "t", sch
+        ) == {2}
+        # under anything but a Project, every column reaches it
+        assert unread_string_columns(
+            mir.Project(mir.Filter(get, (col(0).gte(col(0)),)), (1,)),
+            "t", sch,
+        ) == set()
+        # two reads of one input keep what either keeps
+        assert unread_string_columns(
+            mir.Union((mir.Project(get, (1,)), mir.Project(get, (2,)))),
+            "t", sch,
+        ) == set()
+        # another input's reads are not this one's
+        assert unread_string_columns(
+            mir.Project(get, (0,)), "u", sch
+        ) == {1, 2}
+
     def test_stats_and_corruption(self):
         cols, nulls, time, diff = _updates([(5, 50, 1), (9, 90, 1)])
         data = encode_part(KV, cols, nulls, time, diff)

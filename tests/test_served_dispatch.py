@@ -469,12 +469,15 @@ def test_every_committed_span_feeds_the_freshness_recorder_once(
 def test_a_span_train_compiles_step_and_compact_programs_only(
     served, span_records
 ):
-    mark = len(LEDGER.records())
+    # by identity, not by index: the ledger's two bounded deques are
+    # full after a long run of other tests in this process
+    before = LEDGER.records()
+    old = {id(r) for r in before}
     _drive(served, span_records, "sync", "program_kinds")
     kinds = {
         r.kind
-        for r in LEDGER.records()[mark:]
-        if r.name == "program_kinds"
+        for r in LEDGER.records()
+        if id(r) not in old and r.name == "program_kinds"
     }
     assert {"step", "compact"} <= kinds
     assert kinds <= {"step", "step_donated", "compact"}

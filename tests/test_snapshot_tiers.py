@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from materialize_tpu.expr import relation as mir
-from materialize_tpu.expr.scalar import col
+from materialize_tpu.expr.scalar import col, lit
 from materialize_tpu.parallel.mesh import make_mesh
 from materialize_tpu.render.dataflow import Dataflow, ShardedDataflow
 from materialize_tpu.repr.batch import Batch
@@ -50,12 +50,33 @@ def _hydrate(df, fold: bool = True, snapshot: int = SNAPSHOT) -> dict:
     assert df.presize_for_snapshot(
         {"r": r.capacity, "s": s.capacity}
     ) == 1
-    # the site holds what a snapshot-size delta is cut to
-    assert df._ctx.join_caps == [4096]
+    # ``r`` reaches the join through no filter, and each of its rows
+    # matches one of ``s``: the site holds r's whole snapshot
+    assert df._ctx.join_caps == [r.capacity]
     df.step({"r": r, "s": s})
+    assert df._ctx.join_caps == [r.capacity]  # and overflowed nothing
     if fold:
         df.output_batch()
     return df.release_snapshot_tiers()
+
+
+def test_a_filtered_input_presizes_the_site_to_a_cut_delta():
+    """Through a Filter, not every snapshot row need reach the join: the
+    site holds what a snapshot-size delta is cut to (``out_delta_cap``),
+    not ``r``'s whole snapshot."""
+    df = Dataflow(mir.Join(
+        (
+            mir.Filter(mir.Get("r", R_SCHEMA), (col(1).gte(lit(0)),)),
+            mir.Get("s", S_SCHEMA),
+        ),
+        equivalences=((col(0), col(2)),),
+    ))
+    r, s = _r(SNAPSHOT, 0, 0), _s()
+    assert df._ctx.join_caps == [1024]
+    assert df.presize_for_snapshot({"r": r.capacity, "s": s.capacity}) == 1
+    assert df._ctx.join_caps == [4096]
+    df.step({"r": r, "s": s})
+    assert _net(df) == join_oracle(r.to_rows(), s.to_rows())
 
 
 def _tiers(df) -> dict:
